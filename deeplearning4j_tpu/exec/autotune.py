@@ -5,16 +5,16 @@ generation, CPU interpret runs) inherits routing decisions measured on
 hardware it is not running on. This module closes that gap: on first
 use per (kernel, shape, dtype) — gated behind ``DL4JTPU_AUTOTUNE=1`` so
 CPU test runs never benchmark — it measures kernel-vs-reference for
-BOTH phases on the actual backend, persists the rows next to the
-persistent compile cache (``<cache_dir>/autotune_<backend>.json``, same
-resolution as util/compile_cache.py), and merges them into the
-exec/routing.py measured tables, where they override the shipped file.
+BOTH phases on the actual backend and merges the rows into this
+process's exec/routing.py measured tables, where they override the
+shipped file.
 
 The measurement contract matches bench_kernels exactly — rows use the
-KERNELS_TPU.json ``results`` schema, so ``routing.load_measurements``
-absorbs a persisted autotune table and the shipped file identically,
-and ``tools/autotune.py`` can sweep shapes offline and pre-warm the
-table for a fleet.
+KERNELS_TPU.json ``results`` schema. ``tools/autotune.py`` sweeps shapes
+offline and writes them to a table file the caller names, and
+``routing.load_measurements_file(path)`` absorbs such a table exactly
+like the shipped file. No table is ever read from a path the caller did
+not name: a routing decision must not depend on a file git does not hold.
 
 Timing: jitted closures per side, one warmup dispatch, then
 min-over-iters of ``block_until_ready`` wall time (min is robust to
@@ -42,7 +42,7 @@ def _metrics():
                         "(first use per kernel/shape/dtype/backend).",
                         ("kernel",)),
             reg.gauge("dl4jtpu_autotune_table_rows",
-                      "Rows in the persisted per-backend autotune table."))
+                      "Rows in the autotune table last written."))
 
 
 def backend_name() -> str:
@@ -50,18 +50,7 @@ def backend_name() -> str:
     return jax.default_backend()
 
 
-def table_path(backend: Optional[str] = None) -> str:
-    """The persisted table for ``backend``, next to the persistent
-    compile cache (same resolution: ``DL4JTPU_JAX_CACHE`` env else
-    ``.jax_cache`` at the repo root)."""
-    from pathlib import Path
-    d = (os.environ.get("DL4JTPU_JAX_CACHE")
-         or str(Path(__file__).resolve().parents[2] / ".jax_cache"))
-    return os.path.join(d, f"autotune_{backend or backend_name()}.json")
-
-
-def load_table(path: Optional[str] = None) -> list:
-    path = path or table_path()
+def load_table(path: str) -> list:
     if not os.path.exists(path):
         return []
     with open(path) as f:
@@ -76,11 +65,11 @@ def _row_key(row) -> tuple:
             row.get("dtype"))
 
 
-def save_rows(rows, path: Optional[str] = None) -> str:
-    """Merge ``rows`` into the persisted table (by shape identity, new
+def save_rows(rows, path: str) -> str:
+    """Merge ``rows`` into the table at ``path`` (by shape identity, new
     rows win) with an atomic replace — concurrent processes lose an
     update at worst, never corrupt the file."""
-    path = path or table_path()
+    path = os.path.abspath(path)
     os.makedirs(os.path.dirname(path), exist_ok=True)
     merged = {_row_key(r): r for r in load_table(path)}
     for r in rows:
@@ -99,22 +88,8 @@ def save_rows(rows, path: Optional[str] = None) -> str:
         except OSError:
             pass
         raise
-    try:
-        _, rows_gauge = _metrics()
-        rows_gauge.set(len(out))
-    except Exception:
-        pass
+    _metrics()[1].set(len(out))
     return path
-
-
-def load_persisted_into_routing(path: Optional[str] = None) -> int:
-    """Feed the persisted table into exec/routing.py's measured tables.
-    Called lazily by routing's first lookup; returns rows absorbed."""
-    from deeplearning4j_tpu.exec import routing
-    rows = load_table(path)
-    kernels = {r.get("kernel") for r in rows} - {None}
-    return sum(routing.load_measurements(rows, kernel=k)
-               for k in sorted(kernels))
 
 
 # ------------------------------------------------------------- measurement
@@ -239,8 +214,8 @@ def measure_flash_attention(bh: int, t: int, dh: int, causal: bool = False,
 
 def ensure_measured(kernel: str, shape_key: tuple) -> Optional[str]:
     """Routing's first-use hook (DL4JTPU_AUTOTUNE=1): measure this shape
-    on the actual backend, persist + merge the row, and return the
-    fresh route for the asked phase — or None when the shape was
+    on the actual backend, merge the row into this process's tables, and
+    return the fresh route for the asked phase — or None when the shape was
     already attempted, is unsupported, or a measurement is running
     (re-entrance: the measurement itself calls the kernels)."""
     global _in_progress
@@ -255,7 +230,6 @@ def ensure_measured(kernel: str, shape_key: tuple) -> Optional[str]:
             row = measure_fused_lstm(b, t, h, dtype)
             if row is None:
                 return None
-            save_rows([row])
             routing.load_measurements([row], kernel="fused_lstm")
             table = (routing._MEASURED if kernel == "fused_lstm_fwd"
                      else routing._MEASURED_GRAD)
@@ -265,7 +239,6 @@ def ensure_measured(kernel: str, shape_key: tuple) -> Optional[str]:
             row = measure_flash_attention(bh, t, dh, causal)
             if row is None:
                 return None
-            save_rows([row])
             routing.load_measurements([row], kernel="flash_attention")
             phases = ("fwd", "grad") if train else ("fwd",)
             hits = [routing._FLASH_MEASURED.get((ph, bh, t, dh,
@@ -276,21 +249,16 @@ def ensure_measured(kernel: str, shape_key: tuple) -> Optional[str]:
                      else None)
         else:
             return None
-        try:
-            meas, _ = _metrics()
-            meas.labels(kernel=kernel).inc()
-        except Exception:
-            pass
+        _metrics()[0].labels(kernel=kernel).inc()
         return route
     finally:
         _in_progress = False
 
 
-def sweep(lstm_shapes=(), flash_shapes=(), iters: int = 3,
-          interpret: Optional[bool] = None,
-          path: Optional[str] = None) -> list:
-    """Measure a batch of shapes and persist them in one table write
-    (the tools/autotune.py CLI entry point). ``lstm_shapes``: iterable
+def sweep(path: str, lstm_shapes=(), flash_shapes=(), iters: int = 3,
+          interpret: Optional[bool] = None) -> list:
+    """Measure a batch of shapes and write them to the table at ``path``
+    in one write (the tools/autotune.py CLI entry point). ``lstm_shapes``: iterable
     of (B, T, H, dtype); ``flash_shapes``: (BH, T, Dh, causal)."""
     rows = []
     for b, t, h, dtype in lstm_shapes:
@@ -304,5 +272,5 @@ def sweep(lstm_shapes=(), flash_shapes=(), iters: int = 3,
         if row is not None:
             rows.append(row)
     if rows:
-        save_rows(rows, path=path)
+        save_rows(rows, path)
     return rows

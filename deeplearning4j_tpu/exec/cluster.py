@@ -50,6 +50,10 @@ class WorkerProcess:
 
     The port-file carries the child's PID once it has JOINED the
     coordinator — the spawn handshake ``wait_joined`` blocks on.
+
+    Workers are PINNED TO THE CPU (``host_device_env``): one process
+    holds an accelerator at a time. Each worker logs its device when it
+    starts; no figure from this cluster is a chip figure.
     """
 
     def __init__(self, workdir: str, coordinator_url: str, worker_id: str,

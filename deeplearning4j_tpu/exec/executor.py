@@ -29,6 +29,7 @@ to the pre-executor code and compiles zero new programs.
 """
 
 import os
+import threading
 from typing import Optional, Sequence
 
 import jax
@@ -49,6 +50,18 @@ AUX = "aux"                # small replicated side-outputs (telemetry):
                            # never donated, never sharded — a fused
                            # (L, C) stats array rides the step program
                            # without perturbing its main-output layout
+
+# set while the body of a mesh program is being traced: what it traces
+# goes to the SPMD partitioner, which exec/routing.py needs to know
+_PARTITIONED = threading.local()
+
+
+def tracing_partitioned() -> bool:
+    """True inside the traced body of a program :meth:`Executor.jit`
+    compiles with mesh shardings (mesh > 1), whoever triggers the trace —
+    the step's first call or a registry relower."""
+    return getattr(_PARTITIONED, "on", False)
+
 
 _ROW_TOKENS = ("Wo", "ff2", "down")
 _COL_TOKENS = ("Wq", "Wk", "Wv", "ff1", "up")
@@ -89,6 +102,12 @@ def _slot_spec(leaf, data_ok: bool, model_size: int) -> P:
     if nd == 0:
         return P()
     return P(*([lead] + [None] * (nd - 1)))
+
+
+def _row_counts(tree, axis: int) -> set:
+    """The distinct sizes along ``axis`` over a tree's array leaves."""
+    return {leaf.shape[axis] for leaf in jax.tree_util.tree_leaves(tree)
+            if getattr(leaf, "ndim", 0) > axis}
 
 
 class Executor:
@@ -208,6 +227,20 @@ class Executor:
         return (self.data_size > 1 and n % self.data_size == 0
                 and n // self.data_size >= mr)
 
+    def batch_sharding(self, tree, *, step_axis: bool = False):
+        """Where :meth:`jit` will want a batch-like argument, known ahead
+        of the call (the input prefetcher stages batches there, so a
+        batch never lands whole on the default device to be resharded):
+        the ``data``-axis sharding a BATCH — or with ``step_axis`` a
+        (steps, batch, ...) STEP_BATCH — spec resolves to when the rows
+        of ``tree``'s leaves shard, else None for the default device,
+        where the single-device program runs."""
+        rows = _row_counts(tree, 1 if step_axis else 0)
+        if len(rows) == 1 and self.shardable_rows(next(iter(rows))):
+            return self._named(P(None, DATA_AXIS) if step_axis
+                               else P(DATA_AXIS))
+        return self.replicated() if self.model_size > 1 else None
+
     # ------------------------------------------------------------------ jit
     def jit(self, fn, *, in_specs: Optional[Sequence] = None,
             out_specs: Optional[Sequence] = None, donate_argnums=(),
@@ -229,6 +262,7 @@ class Executor:
                              "single-device path")
         in_specs = tuple(in_specs)
         cache = {}
+        placement = {}      # shard_data -> in_shardings of a mesh program
 
         def _rows(args):
             """Leading batch rows seen by the data-sharded args; None when
@@ -237,10 +271,7 @@ class Executor:
             for spec, a in zip(in_specs, args):
                 if spec not in (BATCH, STEP_BATCH, SLOTS):
                     continue
-                axis = 1 if spec == STEP_BATCH else 0
-                for leaf in jax.tree_util.tree_leaves(a):
-                    if getattr(leaf, "ndim", 0) > axis:
-                        dims.add(leaf.shape[axis])
+                dims |= _row_counts(a, 1 if spec == STEP_BATCH else 0)
             if len(dims) != 1:
                 return None
             return next(iter(dims))
@@ -271,7 +302,8 @@ class Executor:
                             leaf, shard_data, self.model_size)), arg)
                 return self.replicated()
 
-            in_sh = tuple(resolve(s, a) for s, a in zip(in_specs, args))
+            in_sh = placement[shard_data] = tuple(
+                resolve(s, a) for s, a in zip(in_specs, args))
             out_sh = None
             if out_specs is not None:
                 # outputs resolve against the input trees they mirror
@@ -285,7 +317,16 @@ class Executor:
                 # (a 1-tuple would claim a tuple-shaped output pytree)
                 out_sh = resolved[0] if len(resolved) == 1 \
                     else tuple(resolved)
-            return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
+            def partitioned(*a):
+                prev = tracing_partitioned()
+                _PARTITIONED.on = True
+                try:
+                    return fn(*a)
+                finally:
+                    _PARTITIONED.on = prev
+
+            return jax.jit(partitioned, in_shardings=in_sh,
+                           out_shardings=out_sh,
                            donate_argnums=donate_argnums)
 
         slot_specs = any(s == SLOTS for s in in_specs)
@@ -298,6 +339,14 @@ class Executor:
             jf = cache.get(shard)
             if jf is None:
                 jf = cache[shard] = _build(shard, args)
+            in_sh = placement.get(shard)
+            if in_sh is not None:
+                # commit every argument to its mesh placement BEFORE the
+                # call: an array's aval carries the mesh it lives on, so
+                # host batches and fresh (single-device) params would
+                # trace one program and the step's own mesh-resident
+                # outputs a second, identical one
+                args = jax.device_put(args, in_sh)
             return jf(*args)
 
         wrapped._dl4jtpu_exec_wrapper = True   # introspection for tests

@@ -115,12 +115,25 @@ def set_default_mesh(mesh: Optional[Mesh]) -> None:
     _ex._invalidate_default()
 
 
+def device_info() -> dict:
+    """The device this process computes on, as JAX reports it — what a
+    ready line, ``/stats`` or a result names so that no figure is read as
+    coming from a device it did not run on."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def host_device_env(n: int = 8, base=None) -> dict:
     """Environment for a SUBPROCESS that should see ``n`` virtual CPU
     devices. The host-device-count flag only takes effect before jax
     initializes, so it cannot be flipped in-process — composing it into
     a child environment is the subprocess-safe way (the parent's device
-    state is untouched)."""
+    state is untouched).
+
+    The child is PINNED TO THE CPU (``JAX_PLATFORMS=cpu``): an
+    accelerator belongs to one process at a time, and a parent that has
+    touched JAX holds it. Nothing such a child times is a chip figure."""
     env = dict(os.environ if base is None else base)
     flags = [t for t in env.get("XLA_FLAGS", "").split()
              if not t.startswith(_HOST_COUNT_FLAG)]

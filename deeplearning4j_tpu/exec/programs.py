@@ -26,6 +26,7 @@ and skip their increment while a registration lowering is in flight.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from typing import Optional
@@ -65,39 +66,44 @@ def _lowerable(fn):
     return None
 
 
-def _analyze(jitted, args):
-    """(flops, bytes_accessed, memory_bytes, aot_compile_seconds) via the
-    AOT path; any missing analysis comes back None."""
+def _analyze(jitted, args) -> dict:
+    """Record fields read off the AOT-compiled program; an analysis XLA
+    does not offer comes back None. ``mosaic_calls`` counts the Mosaic
+    (Pallas TPU) custom calls in the compiled program: 0 means no
+    hand-written kernel made it in — the interpreter lowers a kernel to
+    plain XLA ops, and so counts 0. ``all_reduces`` counts the all-reduce
+    collectives the partitioner put in: 0 on one device, the gradient
+    reduction of a data-parallel step on a mesh."""
     t0 = time.perf_counter()
     compiled = jitted.lower(*args).compile()
-    aot_s = time.perf_counter() - t0
-    flops = bytes_accessed = memory_bytes = None
+    text = compiled.as_text()
+    out = {"flops": None, "bytes": None, "memory_bytes": None,
+           "aot_seconds": time.perf_counter() - t0,
+           "mosaic_calls": text.count('custom_call_target="tpu_custom_call"'),
+           "all_reduces": len(re.findall(r"\ball-reduce(?:-start)?\(",
+                                         text))}
     try:
         an = compiled.cost_analysis()
         if isinstance(an, (list, tuple)):
             an = an[0] if an else {}
         if an:
             f = an.get("flops")
-            flops = float(f) if f is not None else None
+            out["flops"] = float(f) if f is not None else None
             b = an.get("bytes accessed")
-            bytes_accessed = float(b) if b is not None else None
+            out["bytes"] = float(b) if b is not None else None
     except Exception:
         pass
     try:
         mem = compiled.memory_analysis()
-        total = 0.0
-        found = False
-        for attr in ("temp_size_in_bytes", "argument_size_in_bytes",
-                     "output_size_in_bytes", "generated_code_size_in_bytes"):
-            v = getattr(mem, attr, None)
-            if v is not None:
-                total += float(v)
-                found = True
-        if found:
-            memory_bytes = total
+        sizes = [getattr(mem, attr, None) for attr in (
+            "temp_size_in_bytes", "argument_size_in_bytes",
+            "output_size_in_bytes", "generated_code_size_in_bytes")]
+        if any(v is not None for v in sizes):
+            out["memory_bytes"] = float(sum(v for v in sizes
+                                            if v is not None))
     except Exception:
         pass
-    return flops, bytes_accessed, memory_bytes, aot_s
+    return out
 
 
 class ProgramRegistry:
@@ -152,20 +158,19 @@ class ProgramRegistry:
         jitted = _lowerable(fn)
         if jitted is None:
             return None
-        flops = bytes_accessed = memory_bytes = None
-        aot_s = None
+        fields = {"flops": None, "bytes": None, "memory_bytes": None,
+                  "aot_seconds": None, "mosaic_calls": None,
+                  "all_reduces": None}
         try:
             with _Registering():
-                flops, bytes_accessed, memory_bytes, aot_s = _analyze(
-                    jitted, args)
+                fields = _analyze(jitted, args)
         except Exception:
             pass
+        aot_s = fields.pop("aot_seconds")
         record = {
             "caller": caller,
             "key": key,
-            "flops": flops,
-            "bytes": bytes_accessed,
-            "memory_bytes": memory_bytes,
+            **fields,
             "compile_seconds": (compile_seconds if compile_seconds is not None
                                 else aot_s),
         }

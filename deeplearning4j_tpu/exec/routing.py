@@ -18,10 +18,11 @@ row with a measured ``grad_route``/``grad_speedup`` routes the BACKWARD
 the same way (the backward kernel wins at most validated shapes, but
 two measured bf16 rows lose — (4,16,8) 0.24x, (8,32,120) 0.4x — so the
 backward is measurement-routed exactly like the forward, with pallas as
-the no-data default). Tables produced by the per-backend autotune
-harness (exec/autotune.py — persisted next to the compile cache) merge
-on top of the shipped file, so first-use measurements on the actual
-backend override v5e numbers.
+the no-data default). Rows measured by the per-backend autotune harness
+(exec/autotune.py) merge on top of the shipped file, so first-use
+measurements on the actual backend override v5e numbers. The shipped
+file is the only one read unasked: any other table reaches routing
+through ``load_measurements_file(path)``, called by whoever names it.
 
 Overrides, strongest first:
 
@@ -31,10 +32,13 @@ Overrides, strongest first:
 2. ``DL4JTPU_LSTM_FWD_ROUTE`` / ``DL4JTPU_LSTM_GRAD_ROUTE`` /
    ``DL4JTPU_DECODE_ATTN_ROUTE`` / ``DL4JTPU_FLASH_ATTN_ROUTE`` —
    environment pins
-3. measured per-shape table (exact (B, T, H, dtype) match, seeded from
+3. under a mesh of more than one device, 'scan' for every compiled
+   kernel (``_mosaic_cannot_partition``: XLA will not auto-partition a
+   Mosaic call)
+4. measured per-shape table (exact (B, T, H, dtype) match, seeded from
    the shipped KERNELS_TPU.json via ``load_measurements`` plus any
-   persisted autotune table)
-4. heuristic: scan when ``B*H < 2048``; f32 additionally needs
+   table the caller loaded by name)
+5. heuristic: scan when ``B*H < 2048``; f32 additionally needs
    ``B*H > 2048`` and ``T < 128`` (both measured f32 losses above sit
    on those boundaries); otherwise pallas.  The backward defaults to
    pallas (it wins at every validated shape the heuristic covers).
@@ -106,6 +110,21 @@ def set_route(kernel: str, route: Optional[str]) -> None:
         _forced[kernel] = route
 
 
+def _mosaic_cannot_partition() -> bool:
+    """True while tracing a program the executor hands to the SPMD
+    partitioner (a mesh of more than one device) with compiled kernels.
+    XLA refuses such a program outright — "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"
+    (compiled for v5e:2x2 with the fused-LSTM kernel inside a
+    batch-sharded step, PR 21) — so under a mesh > 1 every kernel route is
+    'scan' until a kernel is wrapped in a shard_map of its own. The
+    interpreter lowers a kernel to plain XLA ops, which partition like
+    any other, so interpret-mode callers are not held to this."""
+    from deeplearning4j_tpu import ops
+    from deeplearning4j_tpu.exec.executor import tracing_partitioned
+    return tracing_partitioned() and not ops.interpret_mode()
+
+
 def _grad_decision(row) -> Optional[str]:
     """A row's backward route: explicit ``grad_route`` wins, else the
     measured ``grad_speedup`` decides (pallas iff it beat the scan)."""
@@ -173,23 +192,16 @@ def load_measurements_file(path: Optional[str] = None) -> int:
 
 
 def _ensure_file_measurements() -> None:
-    """Lazy one-shot load of the shipped measurement file PLUS any
-    persisted autotune table for the current backend (the autotune rows
-    merge last, so first-use measurements on the actual hardware
-    override the shipped v5e numbers)."""
+    """Lazy one-shot load of the shipped measurement file — a tracked
+    file, so a missing or malformed one is a defect and raises."""
     global _file_loaded
     if not _file_loaded:
         _file_loaded = True
         load_measurements_file()
-        try:
-            from deeplearning4j_tpu.exec import autotune
-            autotune.load_persisted_into_routing()
-        except Exception:
-            pass        # a corrupt table must never take down routing
 
 
 def _reset_measurement_cache() -> None:
-    """Forget the lazy-load latch (tests re-point the autotune table)."""
+    """Forget the lazy-load latch (tests reload the shipped file)."""
     global _file_loaded
     _file_loaded = False
 
@@ -197,16 +209,14 @@ def _reset_measurement_cache() -> None:
 def _maybe_autotune(kernel: str, shape_key: tuple) -> Optional[str]:
     """First-use measurement hook: when DL4JTPU_AUTOTUNE is on and the
     tables have no row for this shape, measure kernel-vs-reference on
-    the actual backend, persist, and return the fresh route (None when
-    autotuning is off or the measurement could not run)."""
+    the actual backend and return the fresh route (None when autotuning
+    is off or the kernel does not support the shape). A measurement that
+    fails raises: it ran the same kernel the route would have chosen."""
     if os.environ.get("DL4JTPU_AUTOTUNE", "").strip().lower() \
             not in ("1", "true", "on", "yes"):
         return None
-    try:
-        from deeplearning4j_tpu.exec import autotune
-        return autotune.ensure_measured(kernel, shape_key)
-    except Exception:
-        return None
+    from deeplearning4j_tpu.exec import autotune
+    return autotune.ensure_measured(kernel, shape_key)
 
 
 def lstm_fwd_route(b: int, h: int, t: Optional[int] = None,
@@ -222,6 +232,8 @@ def lstm_fwd_route(b: int, h: int, t: Optional[int] = None,
     env = os.environ.get("DL4JTPU_LSTM_FWD_ROUTE", "").strip().lower()
     if env in ("pallas", "scan"):
         return env
+    if _mosaic_cannot_partition():
+        return "scan"
     if backend is not None and backend != "tpu":
         return "scan"
     if t is not None and dtype is not None:
@@ -255,6 +267,8 @@ def lstm_grad_route(b: int, h: int, t: Optional[int] = None,
     env = os.environ.get("DL4JTPU_LSTM_GRAD_ROUTE", "").strip().lower()
     if env in ("pallas", "scan"):
         return env
+    if _mosaic_cannot_partition():
+        return "scan"
     if backend is not None and backend != "tpu":
         return "scan"
     if t is not None and dtype is not None:
@@ -287,6 +301,8 @@ def flash_attn_route(bh: int, t: int, dh: int, causal: bool,
     env = os.environ.get("DL4JTPU_FLASH_ATTN_ROUTE", "").strip().lower()
     if env in ("pallas", "scan"):
         return env
+    if _mosaic_cannot_partition():
+        return "scan"
     if backend is not None and backend != "tpu":
         return "scan"
     if backend == "tpu":
@@ -326,13 +342,15 @@ def decode_attn_route(c: Optional[int] = None, dh: Optional[int] = None,
     is HBM-bound on the KV cache and the kernel stops reading at the
     cache position, so it wins by construction once the cache is larger
     than one block (the caller screens ``supported(c, dh)`` /
-    ``supported_paged(block_size, dh)`` first)."""
+    ``supported_paged(block_size, dh, n_heads)`` first)."""
     forced = _forced.get("decode_attn")
     if forced is not None:
         return forced
     env = os.environ.get("DL4JTPU_DECODE_ATTN_ROUTE", "").strip().lower()
     if env in ("pallas", "scan"):
         return env
+    if _mosaic_cannot_partition():
+        return "scan"
     if backend is not None and backend != "tpu":
         return "scan"
     return "pallas"

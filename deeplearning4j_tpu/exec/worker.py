@@ -762,8 +762,12 @@ class ElasticWorker:
     # -- lifecycle ---------------------------------------------------------
     def run(self) -> int:
         from deeplearning4j_tpu.resilience.faults import WorkerChaos
+        from deeplearning4j_tpu.exec.mesh import device_info
         from deeplearning4j_tpu.util.compile_cache import setup_compile_cache
         setup_compile_cache()
+        dev = device_info()
+        self._log(f"device platform={dev['platform']} kind={dev['kind']!r} "
+                  f"devices={dev['count']}")
         try:
             joined = self.client.join(data_port=self.comms.data_port)
         except ClusterFullError as e:

@@ -7,13 +7,15 @@ jit); this package holds the framework's OWN native pieces: the ETL record
 readers + async batcher (recordreader.cpp).
 
 Compilation happens lazily on first use with g++ (cached .so next to the
-source, keyed on source mtime); every caller has a pure-Python fallback, so
-a host without a toolchain still works (set DL4J_TPU_NO_NATIVE=1 to force
-the fallback)."""
+source, named by the source's content hash — a copy or a checkout does not
+keep mtimes, and a stale library must never be trusted); every caller has a
+pure-Python fallback, so a host without a toolchain still works (set
+DL4J_TPU_NO_NATIVE=1 to force the fallback)."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from pathlib import Path
@@ -21,7 +23,6 @@ from typing import Optional
 
 _DIR = Path(__file__).parent
 _SRC = _DIR / "recordreader.cpp"
-_SO = _DIR / "_librecordreader.so"
 
 _lib = None
 _tried = False
@@ -33,15 +34,23 @@ def _disabled() -> bool:
 
 
 def _build() -> Optional[Path]:
-    if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _SO
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    so = _DIR / f"_librecordreader-{digest}.so"
+    if so.exists():
+        return so
+    # build under a private name, then rename: concurrent first users
+    # (test workers) never load a half-written library
+    tmp = _DIR / f".{so.name}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           str(_SRC), "-o", str(_SO)]
+           str(_SRC), "-o", str(tmp)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _SO
-    except Exception:
-        return None
+        os.replace(tmp, so)
+        return so
+    except (OSError, subprocess.SubprocessError):
+        return None         # no toolchain: callers take the Python path
+    finally:
+        tmp.unlink(missing_ok=True)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning4j_tpu.util.shmap import shard_map
+from jax import shard_map
 
 from deeplearning4j_tpu.nlp.word2vec import Word2Vec, _sg_neg_batch
 

@@ -58,9 +58,10 @@ class GlobalConf:
     # the loss). True/'full' recomputes everything; 'save_convs' (alias
     # 'selective') keeps conv outputs and recomputes only BN/activations.
     # On TPU the conv-net backward is HBM-bound on stored activations: full
-    # remat measures up to 5x faster at CIFAR shapes, 'save_convs' wins at
-    # 224 where conv recompute costs real FLOPs (docs/PERF_R05.md) — the
-    # role cudnn workspace tuning plays in the reference's helper seam
+    # remat measured up to 5x faster at CIFAR shapes, 'save_convs' won at
+    # 224 where conv recompute costs real FLOPs (round-5 ablation, before
+    # PR 1; not measured since) — the role cudnn workspace tuning plays in
+    # the reference's helper seam
     remat: object = False   # False | True | 'full' | 'save_convs' | 'selective'
     weight_noise: Optional[object] = None  # IWeightNoise (DropConnect/...)
 

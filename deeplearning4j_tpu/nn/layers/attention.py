@@ -253,13 +253,14 @@ class MultiHeadAttention(Layer):
             from deeplearning4j_tpu.exec import decode_attn_route
             from deeplearning4j_tpu.ops import flash_decode
             Dh = q.shape[-1]
-            backend = None if ops.interpret_mode() else jax.default_backend()
-            if (flash_decode.supported_paged(bs, Dh)
+            interp = ops.interpret_mode()
+            backend = None if interp else jax.default_backend()
+            if (flash_decode.supported_paged(bs, Dh, self.n_heads,
+                                             interpret=interp)
                     and decode_attn_route(C, Dh, backend=backend,
                                           paged=True) == "pallas"):
                 o = ops.flash_decode_step_paged(
-                    q[:, 0], pk, pv, pos, block_tables,
-                    interpret=ops.interpret_mode())
+                    q[:, 0], pk, pv, pos, block_tables, interpret=interp)
                 return (self._project_out(params, o, B, 1, q.dtype),
                         {"pk": pk, "pv": pv})
         kc = pk[block_tables].reshape(B, C, *pk.shape[2:])

@@ -39,10 +39,13 @@ def _on_tpu() -> bool:
 def set_helpers_enabled(flag: Optional[bool], *, interpret: bool = False):
     """Force the accelerated path on/off (None = auto: on iff TPU).
     ``interpret=True`` runs kernels through the Pallas interpreter so the
-    accelerated path can be exercised on CPU (tests)."""
+    accelerated path can be exercised on CPU (tests). Returns the
+    ``(flag, interpret)`` it replaced, for a caller that puts it back."""
     global _FORCED, _INTERPRET
+    prev = (_FORCED, _INTERPRET)
     _FORCED = flag
     _INTERPRET = interpret
+    return prev
 
 
 def helpers_enabled() -> bool:

@@ -7,8 +7,10 @@ O(T) memory instead of the O(T^2) scores matrix: the softmax is computed
 online per key block, carrying the running max/denominator in registers,
 and the backward pass recomputes scores blockwise from saved (o, lse).
 
-Supported: no key-padding mask (fall back to the reference path), head_dim
-and sequence length divisible by the block size. f32 accumulation.
+Supported: no key-padding mask (fall back to the reference path), sequence
+length divisible by a block size, head dim a multiple of 8 up to 256, and
+two whole (T, Dh) operands within the VMEM budget (``supported``).
+f32 accumulation.
 """
 
 from __future__ import annotations
@@ -31,11 +33,13 @@ def _pick_block(t):
     return None
 
 
-# Auto-route threshold, measured on TPU v5e: XLA's fused-softmax attention
-# wins below T~4096 (0.1-0.6x at T<=2048); the flash kernel wins above
-# (1.06x @ 4096, 2.1x @ 8192) AND avoids the O(T^2) scores matrix that
-# starts pressuring HBM there. Direct flash_attention() calls are not
-# gated — only the layer seam's silent routing is.
+# Auto-route threshold, from a v5e record that predates PR 1: XLA's
+# fused-softmax attention won below T~4096 (0.1-0.6x at T<=2048); the flash
+# kernel won above (1.06x @ 4096, 2.1x @ 8192) and avoids the O(T^2) scores
+# matrix. Today's compiler refuses T 8192 at Dh 128 (``supported`` below),
+# so at Dh <= 128 the seam reaches the kernel at T 4096 only — ROADMAP S2
+# decides whether to block K/V or delete it. Direct flash_attention() calls
+# are not gated — only the layer seam's silent routing is.
 MIN_SEQ_FOR_AUTO_ROUTE = 4096
 
 
@@ -43,11 +47,18 @@ def supported(t, dh, min_t: int = 0):
     """Shape screen. ``min_t``: minimum sequence length (the layer seam
     passes MIN_SEQ_FOR_AUTO_ROUTE so short sequences stay on the faster
     XLA path; interpret-mode tests pass 0)."""
-    # K and V are held fully in VMEM per (batch*head) row; screen out
-    # shapes whose K/V exceed a conservative VMEM budget, and unaligned
-    # head dims, so the seam's silent-fallback promise holds on real TPUs.
-    return (_pick_block(t) is not None and dh % 8 == 0 and t >= min_t
-            and t * dh * 4 <= 4 * 1024 * 1024)
+    # Each (batch*head) program holds two whole (T, Dh) operands in VMEM
+    # (K and V in the forward and dq passes, q and do in the dk/dv pass),
+    # double-buffered by the pipeline and padded to 128 lanes; the dk/dv
+    # pass adds the lse and delta columns, a 128-lane tile row per
+    # position. Mosaic scopes a kernel to 16 MiB on v5e; the budget
+    # leaves the rest to the q/o blocks and the compiler's temporaries.
+    # tests/test_tpu_compile.py holds this to "accepted means it
+    # compiles" (T 4096 at Dh <= 128 is the largest that does).
+    lanes = -(-dh // 128) * 128
+    vmem = 2 * 2 * t * lanes * 4 + 2 * t * 128 * 4
+    return (_pick_block(t) is not None and dh % 8 == 0 and dh <= 256
+            and t >= min_t and vmem <= 12 * 1024 * 1024)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, blk, t_total, causal,

@@ -18,9 +18,10 @@ f32 (8, 128) tile floor — the 7 duplicate rows are VPU noise next to
 the KV stream, and row 0 is written back. f32 accumulation throughout.
 
 Supported: cache capacity divisible by a block size (8..128), head dim
-a multiple of 8, K+V within a conservative VMEM budget. Callers screen
-with ``supported()`` and fall back to the dense step (which the
-bitwise-parity tests pin on CPU), mirroring the cuDNN-helper seam.
+a multiple of 8 (a multiple of 128 for the paged kernel), K+V within the
+VMEM budget. Callers screen with ``supported()`` / ``supported_paged()``
+and fall back to the dense step (which the bitwise-parity tests pin on
+CPU), mirroring the cuDNN-helper seam.
 """
 
 from __future__ import annotations
@@ -44,24 +45,44 @@ def _pick_block(c):
     return None
 
 
+# Mosaic scopes one kernel to 16 MiB of VMEM on v5e and lays every block
+# out in (8, 128) tiles, so a head dim below 128 still costs 128 lanes.
+# The screens below count that padded footprint against a budget that
+# leaves room for the small blocks and the compiler's own temporaries;
+# tests/test_tpu_compile.py holds them to "accepted means it compiles".
+_LANES = 128
+_VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _pad(n, m):
+    return -(-n // m) * m
+
+
 def supported(c, dh):
-    """Shape screen: blockable capacity, lane-aligned head dim, K+V for
-    one (batch, head) row within a conservative VMEM budget."""
+    """Shape screen: blockable capacity, head dim a sublane multiple, and
+    the K and V rows of one (batch, head) program — each held whole and
+    double-buffered by the pipeline — within the VMEM budget."""
     return (_pick_block(c) is not None and dh % 8 == 0
-            and 2 * c * dh * 4 <= 8 * 1024 * 1024)
+            and 2 * 2 * c * _pad(dh, _LANES) * 4 <= _VMEM_BUDGET)
 
 
-def supported_paged(block_size, dh):
-    """Shape screen for the paged kernel: the KV block is the DMA unit,
-    so it must meet the f32 tile floor on its own; the double-buffered
-    (block_size, Dh) staging pair must fit VMEM comfortably."""
-    return (block_size % 8 == 0 and dh % 8 == 0
-            and 2 * block_size * dh * 4 <= 4 * 1024 * 1024)
+def supported_paged(block_size, dh, n_heads, interpret=False):
+    """Shape screen for the paged kernel. The pools stay in HBM and a KV
+    block (block_size, H, Dh) is the DMA unit, so the slice has to respect
+    the tiling XLA gives the pool there: Mosaic refuses a head dim that is
+    not a whole number of 128-lane tiles (the layout pads the minor dim)
+    and, above 128, a head count that is not a whole number of 8-sublane
+    tiles. Those shapes take the gather path (the interpreter has no
+    tiling, so it takes any). The K and V staging blocks must fit VMEM
+    with room to spare."""
+    tiled = dh == _LANES or (dh % _LANES == 0 and n_heads % 8 == 0)
+    return (block_size % 8 == 0 and (interpret or tiled)
+            and 2 * block_size * _pad(n_heads, 8) * _pad(dh, _LANES) * 4
+            <= 4 * 1024 * 1024)
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, blk, c_total,
-                   scale):
-    p = pos_ref[0, 0]                           # this row's cache position
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, blk, scale):
+    p = pos_ref[pl.program_id(0)]               # this row's cache position
     q = q_ref[0]                                # (_QROWS, Dh) replicated query
 
     def body(j, carry):
@@ -109,22 +130,25 @@ def flash_decode_step(q, kc, vc, pos, *, interpret=False):
     kf, vf = fold(kc), fold(vc)
     qf = jnp.broadcast_to(q.astype(jnp.float32)[:, :, None, :],
                           (B, H, _QROWS, Dh)).reshape(B * H, _QROWS, Dh)
-    posf = jnp.repeat(jnp.asarray(pos, jnp.int32), H).reshape(B * H, 1)
+    # one position per (batch, head) row, scalar-prefetched whole into
+    # SMEM: Mosaic refuses a (1, 1) SMEM block of a (B*H, 1) array
+    posf = jnp.repeat(jnp.asarray(pos, jnp.int32), H)
 
-    kern = functools.partial(_decode_kernel, blk=blk, c_total=C, scale=scale)
+    kern = functools.partial(_decode_kernel, blk=blk, scale=scale)
+    row = lambda i, pos_ref: (i, 0, 0)
     o = pl.pallas_call(
         kern,
-        grid=(B * H,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, _QROWS, Dh), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((1, C, Dh), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((1, C, Dh), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((1, _QROWS, Dh), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * H,),
+            in_specs=[pl.BlockSpec((1, _QROWS, Dh), row,
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((1, C, Dh), row,
+                                   memory_space=pltpu.VMEM),
+                      pl.BlockSpec((1, C, Dh), row,
+                                   memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((1, _QROWS, Dh), row,
+                                   memory_space=pltpu.VMEM)),
         out_shape=jax.ShapeDtypeStruct((B * H, _QROWS, Dh), jnp.float32),
         interpret=interpret,
     )(posf, qf, kf, vf)
@@ -132,45 +156,52 @@ def flash_decode_step(q, kc, vc, pos, *, interpret=False):
 
 
 def _paged_kernel(bt_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
-                  kb_ref, vb_ref, sem_k, sem_v, *, bs, scale):
-    """One grid program per (batch row, head). The pools stay in ``ANY``
-    memory (HBM); the page table rides in SMEM and steers a manual DMA
-    per LIVE block — pos → (block, offset) indexing inside the fori_loop,
-    so only ``pos // bs + 1`` physical blocks are ever pulled to VMEM no
-    matter how fragmented the pool or how large the capacity."""
-    h = pl.program_id(1)
-    p = pos_ref[0]                              # this row's cache position
-    q = q_ref[0, 0]                             # (_QROWS, Dh) replicated
+                  kb_ref, vb_ref, sem_k, sem_v, *, bs, mb, scale):
+    """One grid program per batch row. The pools stay in ``ANY`` memory
+    (HBM); the page table rides in SMEM and steers one manual DMA per
+    LIVE block — pos → (block, offset) indexing inside the fori_loop, so
+    only ``pos // bs + 1`` physical blocks are ever pulled to VMEM no
+    matter how fragmented the pool or how large the capacity. A block
+    is copied whole, (bs, H, Dh), and every head reads its rows from the
+    VMEM copy: a DMA that slices one head out of the pool cuts through
+    the pool's HBM tiling, which Mosaic accepts only for some (H, Dh)."""
+    b = pl.program_id(0)
+    p = pos_ref[b]                              # this row's cache position
+    nh, _, dh = q_ref.shape[1:]
 
     def body(j, carry):
-        m, l, acc = carry
-        phys = bt_ref[0, j]                     # logical block j -> pool
-        ck = pltpu.make_async_copy(kp_ref.at[phys, :, h, :], kb_ref, sem_k)
-        cv = pltpu.make_async_copy(vp_ref.at[phys, :, h, :], vb_ref, sem_v)
+        phys = bt_ref[b * mb + j]               # logical block j -> pool
+        ck = pltpu.make_async_copy(kp_ref.at[phys], kb_ref, sem_k)
+        cv = pltpu.make_async_copy(vp_ref.at[phys], vb_ref, sem_v)
         ck.start()
         cv.start()
         ck.wait()
         cv.wait()
-        kb = kb_ref[...]                        # (bs, Dh)
-        vb = vb_ref[...]
-        s = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
         kpos = j * bs + lax.broadcasted_iota(jnp.int32, (_QROWS, bs), 1)
-        s = jnp.where(kpos <= p, s, _NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        pexp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + pexp.sum(axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.dot(pexp, vb,
-                                    preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        out = []
+        for h, (m, l, acc) in enumerate(carry):
+            q = q_ref[0, h]                     # (_QROWS, Dh) replicated
+            kb = kb_ref[:, h, :]                # (bs, Dh)
+            vb = vb_ref[:, h, :]
+            s = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(kpos <= p, s, _NEG)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            pexp = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + pexp.sum(axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.dot(pexp, vb,
+                                        preferred_element_type=jnp.float32)
+            out.append((m_new, l, acc))
+        return tuple(out)
 
-    m0 = jnp.full((_QROWS, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((_QROWS, 1), jnp.float32)
-    a0 = jnp.zeros((_QROWS, q.shape[-1]), jnp.float32)
+    init = tuple((jnp.full((_QROWS, 1), _NEG, jnp.float32),
+                  jnp.zeros((_QROWS, 1), jnp.float32),
+                  jnp.zeros((_QROWS, dh), jnp.float32)) for _ in range(nh))
     upper = p // bs + 1                 # live blocks only — the paged
-    m, l, acc = lax.fori_loop(0, upper, body, (m0, l0, a0))   # flash win
-    o_ref[0, 0] = acc / l
+    heads = lax.fori_loop(0, upper, body, init)               # flash win
+    for h, (_, l, acc) in enumerate(heads):
+        o_ref[0, h] = acc / l
 
 
 def flash_decode_step_paged(q, pk, pv, pos, block_tables, *,
@@ -188,27 +219,30 @@ def flash_decode_step_paged(q, pk, pv, pos, block_tables, *,
     scale = 1.0 / (Dh ** 0.5)
     qf = jnp.broadcast_to(q.astype(jnp.float32)[:, :, None, :],
                           (B, H, _QROWS, Dh))
-    kern = functools.partial(_paged_kernel, bs=bs, scale=scale)
+    kern = functools.partial(_paged_kernel, bs=bs, mb=MB, scale=scale)
+    row = lambda b, bt_ref, pos_ref: (b, 0, 0, 0)
+    # page tables (flattened: a 2-D SMEM array pads every row to 128
+    # words) and positions are scalar-prefetched whole into SMEM
     o = pl.pallas_call(
         kern,
-        grid=(B, H),
-        in_specs=[
-            pl.BlockSpec((1, MB), lambda b, h: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, _QROWS, Dh), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, _QROWS, Dh),
-                               lambda b, h: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, _QROWS, Dh), row,
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, _QROWS, Dh), row,
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((bs, H, Dh), jnp.float32),
+                            pltpu.VMEM((bs, H, Dh), jnp.float32),
+                            pltpu.SemaphoreType.DMA,
+                            pltpu.SemaphoreType.DMA]),
         out_shape=jax.ShapeDtypeStruct((B, H, _QROWS, Dh), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bs, Dh), jnp.float32),
-                        pltpu.VMEM((bs, Dh), jnp.float32),
-                        pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA],
         interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(pos, jnp.int32),
+    )(jnp.asarray(block_tables, jnp.int32).reshape(B * MB),
+      jnp.asarray(pos, jnp.int32),
       qf, pk.astype(jnp.float32), pv.astype(jnp.float32))
     return o[:, :, 0, :]

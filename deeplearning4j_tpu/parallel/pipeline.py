@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.util.shmap import shard_map
+from jax import shard_map
 
 
 def stack_stage_params(per_stage_params):

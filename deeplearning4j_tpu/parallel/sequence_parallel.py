@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.util.shmap import shard_map
+from jax import shard_map
 
 
 def _ring_attention_local(q, k, v, axis_name, causal):

@@ -207,7 +207,7 @@ class SharedTrainingMaster(TrainingMaster):
         residual and threshold remain per-worker state, as in the
         reference's per-executor EncodingHandler."""
         from functools import partial as _partial
-        from deeplearning4j_tpu.util.shmap import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from deeplearning4j_tpu.parallel.compression import (
             adapt_threshold_jnp, threshold_encode, threshold_decode)

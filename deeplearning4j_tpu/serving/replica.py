@@ -261,11 +261,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                              "replaces --spec-draft, no extra checkpoint")
     args = parser.parse_args(argv)
 
-    # CPU platform before anything touches a backend: replicas are test
-    # and bench workers, never the training accelerator's tenant
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    # the process runs on whatever backend its environment gives it, and
+    # says which in its ready line and in /stats
     from deeplearning4j_tpu.util.compile_cache import setup_compile_cache
     setup_compile_cache()       # restart-in-place must not recompile
 
@@ -320,8 +317,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         os.replace(tmp, args.port_file)      # atomic: parent never reads ""
     # name this process's track in merged fleet traces
     _trace.set_process_name(f"replica:{args.model}@{srv.port}")
+    from deeplearning4j_tpu.exec.mesh import device_info
+    dev = device_info()
     print(f"REPLICA_READY port={srv.port} pid={os.getpid()} "
-          f"model={args.model}", flush=True)
+          f"model={args.model} platform={dev['platform']} "
+          f"kind={dev['kind']!r} devices={dev['count']}", flush=True)
 
     try:
         while not stopping:
@@ -392,8 +392,7 @@ class ReplicaProcess:
         # mutable: rolling restarts set this to the latest promoted
         # checkpoint so a restarted replica boots on current weights
         self.checkpoint = checkpoint
-        # AOT artifact for instant cold-start; extra child env (the bench
-        # isolates compile caches per arm through DL4JTPU_JAX_CACHE)
+        # AOT artifact for instant cold-start; extra child env
         self.aot = aot
         self.extra_env = env
         # spawn → port-file → first healthy probe, set by wait_ready()
@@ -409,6 +408,11 @@ class ReplicaProcess:
         return f"http://127.0.0.1:{self.port}"
 
     def start(self) -> "ReplicaProcess":
+        """Spawn the replica PINNED TO THE CPU (``JAX_PLATFORMS=cpu`` in
+        the child): an accelerator belongs to one process at a time, and
+        the spawning parent may already hold it. The child says so in
+        its ready line and under ``device`` in ``/stats``; no figure
+        taken through a ``ReplicaProcess`` is a chip figure."""
         if os.path.exists(self._port_file) and self.port is None:
             os.unlink(self._port_file)
         cmd = [sys.executable, "-m", "deeplearning4j_tpu.serving.replica",

@@ -614,8 +614,10 @@ class InferenceServer:
         return self.health_info()["status"]
 
     def stats(self) -> dict:
+        from deeplearning4j_tpu.exec.mesh import device_info
         out = {"engine": self.engine.stats(),
                "batcher": self.batcher.stats(),
+               "device": device_info(),
                "health": self.health(),
                "role": self.role,
                "model_version": self.engine.model_version,

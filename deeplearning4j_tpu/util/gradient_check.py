@@ -12,24 +12,18 @@ reference forces DOUBLE data type in its gradient-check tests.
 
 from __future__ import annotations
 
-import contextlib
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 
-@contextlib.contextmanager
 def _x64():
     """Scope float64 to the check (central differences at eps~1e-6 cancel
     catastrophically in float32; the reference similarly forces
     DataBuffer.Type.DOUBLE in its gradient-check suites). A process-global
     ``jax.config.update`` would leak x64 defaults into every test imported
-    after this module — the context manager keeps it local. (Lives under
-    jax.experimental since jax 0.4.31; the top-level alias is gone.)"""
-    from jax.experimental import enable_x64
-    with enable_x64():
-        yield
+    after this module — the context manager keeps it local."""
+    return jax.enable_x64(True)
 
 
 def gradient_check_fn(loss_fn, params, eps=1e-6, max_rel_error=1e-3,

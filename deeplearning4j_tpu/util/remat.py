@@ -1,7 +1,6 @@
 """Shared backward-rematerialization dispatch for the network containers.
 
-See GlobalConf.remat (nn/conf/configuration.py) for the modes and
-docs/PERF_R05.md for the measurements behind them.
+See GlobalConf.remat (nn/conf/configuration.py) for the modes.
 """
 
 from __future__ import annotations

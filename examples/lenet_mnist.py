@@ -14,8 +14,6 @@ import numpy as np
 
 def main():
     import jax
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     print("devices:", jax.devices())
 
     from deeplearning4j_tpu import MultiLayerNetwork, NeuralNetConfiguration

@@ -7,7 +7,6 @@ pumps them into the bounded-buffer streaming iterator, and plain
 TPU-native.
 """
 
-import os
 import threading
 import time
 
@@ -15,10 +14,6 @@ import numpy as np
 
 
 def main():
-    import jax
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     from deeplearning4j_tpu import MultiLayerNetwork, NeuralNetConfiguration
     from deeplearning4j_tpu.data.kafka import (InMemoryBroker,
                                                NDArrayPublisher,

@@ -1,5 +1,5 @@
 """EvaluationCalibration tests — bucketed counts vs hand-computed values
-(VERDICT r1 #6; reference eval/EvaluationCalibration.java)."""
+(reference eval/EvaluationCalibration.java)."""
 
 import numpy as np
 

@@ -1,4 +1,4 @@
-"""Two-process multi-host test for parallel/distributed.py (VERDICT r1 #8).
+"""Two-process multi-host test for parallel/distributed.py.
 
 Spawns two real OS processes, each with 2 virtual CPU devices, forms the
 jax.distributed cluster through a local coordinator, and asserts a pod-mesh

@@ -350,7 +350,11 @@ def test_flash_decode_paged_kernel_matches_gather():
     from deeplearning4j_tpu.ops.flash_decode import (flash_decode_step,
                                                      flash_decode_step_paged,
                                                      supported_paged)
-    assert supported_paged(16, 8) and not supported_paged(12, 8)
+    # the compiled kernel needs whole 128-lane head dims; the interpreter
+    # takes any (tests/test_tpu_compile.py holds the screen to the compiler)
+    assert supported_paged(16, 128, 4) and not supported_paged(12, 128, 4)
+    assert not supported_paged(16, 8, 4)
+    assert supported_paged(16, 8, 4, interpret=True)
     rng = np.random.default_rng(0)
     B, H, Dh, bs, nb, MB = 3, 4, 8, 16, 9, 4
     pk = rng.standard_normal((nb, bs, H, Dh)).astype(np.float32)

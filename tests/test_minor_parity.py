@@ -1,4 +1,4 @@
-"""Minor parity items (VERDICT r1 missing #7 + weak #8):
+"""Minor parity items:
 JointParallelDataSetIterator, CnnSentenceDataSetIterator, and
 ComputationGraph external epsilons."""
 

@@ -1,8 +1,8 @@
 """Backward rematerialization (GlobalConf.remat): identical training math,
 different schedule. Remat recomputes activations in the backward instead of
 storing them — on TPU this is faster for HBM-bound conv models and is the
-bench configuration for ResNet50 (docs/PERF_R05.md); these tests pin that
-it changes NOTHING numerically."""
+bench configuration for ResNet50; these tests pin that it changes NOTHING
+numerically."""
 
 import numpy as np
 import jax.numpy as jnp

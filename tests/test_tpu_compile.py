@@ -1,0 +1,202 @@
+"""The Pallas kernels of the main path, compiled for a described TPU v5e.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached — so what Mosaic refuses (a misaligned block, too
+much VMEM, a kernel under the SPMD partitioner) fails in this suite, not on
+the chip. Interpret mode sees none of it. Nothing runs: these tests say
+"compiles", never "is right" or "is fast".
+
+Claim pinned for every case: the shape screen and the compiler AGREE — a
+shape a screen accepts compiles (with the Mosaic custom call in the
+program), and the edges the screens reject are shapes the compiler really
+refuses.
+
+The topology is described inside a module-scoped fixture, never at import,
+in a ``parametrize`` argument or in a ``skipif``: only the worker that is
+handed this file loads the TPU library (tests stay in this ONE file for the
+same reason), and the persistent compile cache is off around the compiles
+(an entry written for a described chip cannot be read back without one).
+"""
+
+import importlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from deeplearning4j_tpu.exec import routing
+from deeplearning4j_tpu.ops import lstm_pallas
+
+# ops/__init__ re-exports the flash_attention FUNCTION under the module's name
+flash_attention = importlib.import_module(
+    "deeplearning4j_tpu.ops.flash_attention")
+flash_decode = importlib.import_module("deeplearning4j_tpu.ops.flash_decode")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def kernel_routes():
+    """Pin the LSTM routes to the kernel: off the chip the backend check
+    would answer 'scan' and the kernel under test would never be traced."""
+    routing.set_route("fused_lstm", "pallas")
+    routing.set_route("fused_lstm_grad", "pallas")
+    yield
+    routing.set_route("fused_lstm", None)
+    routing.set_route("fused_lstm_grad", None)
+
+
+def _compiles(fn, *shapes):
+    """(compiled?, Mosaic custom calls in the program, compiler's words)."""
+    try:
+        text = jax.jit(fn).lower(*shapes).compile().as_text()
+    except Exception as e:      # whatever the TPU compiler raises
+        return False, 0, str(e)
+    return True, text.count('custom_call_target="tpu_custom_call"'), ""
+
+
+def _agree(screen, fn, shapes, kernels=1):
+    ok, calls, err = _compiles(fn, *shapes)
+    assert ok == bool(screen), (
+        f"screen says {bool(screen)}, compiler says {ok}: {err[:400]}")
+    if ok:
+        assert calls >= kernels, f"{calls} Mosaic calls in the program"
+
+
+def _shapes(sharding, *specs):
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in specs]
+
+
+# ------------------------------------------------------------- fused LSTM
+# the two charRNN shapes of the zoo's TextGenerationLSTM (2 x LSTM(256))
+CHARRNN = [(64, 32, 256, "float32"), (64, 256, 256, "bfloat16")]
+
+
+@pytest.mark.parametrize("t,b,h,dtype", CHARRNN)
+def test_fused_lstm_fwd_and_grad(one_chip, kernel_routes, t, b, h, dtype):
+    dt = jnp.dtype(dtype)
+
+    def loss(gi, rw, h0, c0):
+        hs, c_t = lstm_pallas.fused_lstm_sequence(gi, rw, h0, c0, False)
+        return hs.astype(jnp.float32).sum() + c_t.astype(jnp.float32).sum()
+
+    shapes = _shapes(one_chip, ((t, b, 4 * h), dt), ((h, 4 * h), dt),
+                     ((b, h), dt), ((b, h), dt))
+    _agree(lstm_pallas.supported(b, t, h, dt.itemsize),
+           jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), shapes, kernels=2)
+
+
+@pytest.mark.parametrize("t,b,h,dtype", CHARRNN)
+def test_stacked_lstm_pair_fwd_and_grad(one_chip, kernel_routes, t, b, h,
+                                        dtype):
+    dt = jnp.dtype(dtype)
+
+    def loss(*a):
+        return sum(o.astype(jnp.float32).sum()
+                   for o in lstm_pallas.fused_lstm2_sequence(*a, False))
+
+    g = 4 * h
+    shapes = _shapes(one_chip, ((t, b, g), dt), ((h, g), dt), ((h, g), dt),
+                     ((g,), dt), ((h, g), dt),
+                     *[((b, h), dt)] * 4)
+    _agree(lstm_pallas.supported2(b, t, h, dt.itemsize),
+           jax.value_and_grad(loss, argnums=tuple(range(9))), shapes,
+           kernels=3)
+
+
+def test_lstm_kernel_under_a_mesh_is_refused_and_routed_to_scan(topo):
+    """XLA will not auto-partition a Mosaic call, so inside a step the
+    executor shards over a mesh every route answers 'scan'
+    (routing._mosaic_cannot_partition)."""
+    from deeplearning4j_tpu.exec import executor
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    t, b, h, dt = 64, 256, 256, jnp.bfloat16
+    on = lambda spec: NamedSharding(mesh, spec)
+    shapes = [jax.ShapeDtypeStruct((t, b, 4 * h), dt,
+                                   sharding=on(P(None, "data"))),
+              jax.ShapeDtypeStruct((h, 4 * h), dt, sharding=on(P())),
+              jax.ShapeDtypeStruct((b, h), dt, sharding=on(P("data"))),
+              jax.ShapeDtypeStruct((b, h), dt, sharding=on(P("data")))]
+    ok, _, err = _compiles(
+        lambda *a: lstm_pallas._fwd_call(*a, interpret=False,
+                                         save_reserve=True), *shapes)
+    assert not ok and "cannot be automatically partitioned" in err, err
+
+    executor._PARTITIONED.on = True     # what a mesh program's trace sets
+    try:
+        for route in (routing.lstm_fwd_route(b, h, t=t, dtype="bfloat16"),
+                      routing.lstm_grad_route(b, h, t=t, dtype="bfloat16",
+                                              backend="tpu"),
+                      routing.flash_attn_route(8, 4096, 128, True,
+                                               backend="tpu"),
+                      routing.decode_attn_route(512, 32, backend="tpu")):
+            assert route == "scan"
+        # the same program with the route applied compiles, kernel-free
+        ok, calls, err = _compiles(
+            lambda *a: lstm_pallas.fused_lstm_sequence(*a, False), *shapes)
+        assert ok and calls == 0, err
+    finally:
+        executor._PARTITIONED.on = False
+
+
+# -------------------------------------------------------- flash attention
+# T 4096 is where the layer seam starts routing to the kernel; at T 8192
+# K and V alone fill the 16 MiB Mosaic scopes a kernel to
+@pytest.mark.parametrize("bh,t,dh", [(8, 4096, 128), (8, 8192, 128)])
+def test_flash_attention_fwd_and_grad(one_chip, bh, t, dh):
+    def loss(q, k, v):
+        return flash_attention.flash_attention(q, k, v, True, False).sum()
+
+    shapes = _shapes(one_chip, *[((bh, t, dh), jnp.float32)] * 3)
+    _agree(flash_attention.supported(t, dh),
+           jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
+
+
+# ----------------------------------------------------------- flash decode
+@pytest.mark.parametrize("b,h,dh,c", [(4, 4, 32, 512), (8, 8, 128, 1024)])
+def test_flash_decode_dense(one_chip, b, h, dh, c):
+    shapes = _shapes(one_chip, ((b, h, dh), jnp.float32),
+                     ((b, c, h, dh), jnp.float32),
+                     ((b, c, h, dh), jnp.float32), ((b,), jnp.int32))
+    _agree(flash_decode.supported(c, dh), flash_decode.flash_decode_step,
+           shapes)
+
+
+# head dim 32 is TinyTransformer's zoo default: the pool's HBM layout pads
+# it to 128 lanes and Mosaic refuses to slice it, so the screen says no
+@pytest.mark.parametrize("b,h,dh,c,block", [(4, 4, 32, 512, 16),
+                                            (8, 8, 128, 1024, 16),
+                                            (8, 8, 128, 1024, 128)])
+def test_flash_decode_paged(one_chip, b, h, dh, c, block):
+    blocks = b * (c // block) + 1
+    shapes = _shapes(one_chip, ((b, h, dh), jnp.float32),
+                     ((blocks, block, h, dh), jnp.float32),
+                     ((blocks, block, h, dh), jnp.float32),
+                     ((b,), jnp.int32), ((b, c // block), jnp.int32))
+    _agree(flash_decode.supported_paged(block, dh, h),
+           flash_decode.flash_decode_step_paged, shapes)

@@ -401,7 +401,6 @@ class TestAutotuneRoundtrip:
     def test_persisted_table_consulted_for_fwd_and_grad(
             self, tmp_path, monkeypatch, clean_routing):
         from deeplearning4j_tpu.exec import autotune
-        monkeypatch.setenv("DL4JTPU_JAX_CACHE", str(tmp_path))
         # shapes chosen to exist in NO shipped table, with the fwd winning
         # and the grad losing — so each phase's answer can only come from
         # the persisted autotune rows
@@ -411,10 +410,12 @@ class TestAutotuneRoundtrip:
         flash = {"kernel": "flash_attention", "BH": 3, "T": 40, "Dh": 24,
                  "causal": False, "fwd_speedup": 2.0, "grad_speedup": 0.5,
                  "backend": "cpu", "autotuned": True}
-        path = autotune.save_rows([row, flash])
-        assert os.path.basename(path) == "autotune_cpu.json"
-
+        path = autotune.save_rows([row, flash],
+                                  str(tmp_path / "autotune_cpu.json"))
+        # a table reaches routing only through the caller that names it
         routing._reset_measurement_cache()
+        assert routing.lstm_fwd_route(3, 7, t=5, dtype="float32") == "scan"
+        assert routing.load_measurements_file(path) == 2
         # heuristic alone would say scan (B*H tiny) — pallas proves the
         # persisted fwd row was consulted
         assert routing.lstm_fwd_route(3, 7, t=5, dtype="float32") == "pallas"
@@ -428,15 +429,15 @@ class TestAutotuneRoundtrip:
 
     def test_save_rows_merges_by_shape(self, tmp_path, monkeypatch):
         from deeplearning4j_tpu.exec import autotune
-        monkeypatch.setenv("DL4JTPU_JAX_CACHE", str(tmp_path))
+        path = str(tmp_path / "table.json")
         r1 = {"kernel": "fused_lstm", "B": 1, "T": 2, "H": 3,
               "dtype": "float32", "fwd_speedup": 0.5}
-        autotune.save_rows([r1])
+        autotune.save_rows([r1], path)
         r2 = dict(r1, fwd_speedup=2.0)
         autotune.save_rows([r2, {"kernel": "fused_lstm", "B": 9, "T": 9,
                                  "H": 9, "dtype": "float32",
-                                 "fwd_speedup": 1.1}])
-        rows = autotune.load_table()
+                                 "fwd_speedup": 1.1}], path)
+        rows = autotune.load_table(path)
         assert len(rows) == 2
         mine = [r for r in rows if r["B"] == 1]
         assert mine[0]["fwd_speedup"] == 2.0

@@ -3,12 +3,13 @@
 The runtime harness (exec/autotune.py, ``DL4JTPU_AUTOTUNE=1``) measures
 each (kernel, shape, dtype) lazily on first use — which puts one
 benchmark pause inside the first training step that hits a new shape.
-This CLI runs the same measurements ahead of time and persists them to
-the same table (``<cache_dir>/autotune_<backend>.json``), so a fleet
-can ship a pre-warmed table alongside the persistent compile cache and
-never pay the first-use pause:
+This CLI runs the same measurements ahead of time and writes them to
+the table file ``--out`` names (KERNELS_TPU.json schema), which a process
+loads with ``exec.routing.load_measurements_file(path)`` and so never
+pays the first-use pause:
 
-    python tools/autotune.py --lstm 32x64x256:float32 --lstm 64x128x512 \
+    python tools/autotune.py --out autotune_tpu.json \
+        --lstm 32x64x256:float32 --lstm 64x128x512 \
         --flash 8x1024x64 --flash 8x2048x64:causal
 
 Shape syntax — LSTM: ``BxTxH[:dtype]`` (dtype defaults to float32);
@@ -66,8 +67,8 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3,
                     help="timing iterations per side (min taken; default 3)")
     ap.add_argument("--out", metavar="PATH", default=None,
-                    help="table path (default: <cache_dir>/"
-                         "autotune_<backend>.json)")
+                    help="table file to merge the rows into (required "
+                         "unless --dry-run)")
     ap.add_argument("--interpret", action="store_true",
                     help="force Pallas interpret mode (default off-TPU)")
     ap.add_argument("--dry-run", action="store_true",
@@ -84,13 +85,16 @@ def main(argv=None) -> int:
             print(f"flash_attention BH={bh} T={t} Dh={dh} causal={causal}")
         return 0
 
+    if not args.out:
+        ap.error("--out PATH is required: no table is written to, or read "
+                 "from, a default location")
+
     from deeplearning4j_tpu.exec import autotune
 
-    rows = autotune.sweep(lstm_shapes=args.lstm, flash_shapes=args.flash,
-                          iters=args.iters,
-                          interpret=args.interpret or None,
-                          path=args.out)
-    path = args.out or autotune.table_path()
+    rows = autotune.sweep(args.out, lstm_shapes=args.lstm,
+                          flash_shapes=args.flash, iters=args.iters,
+                          interpret=args.interpret or None)
+    path = args.out
     skipped = (len(args.lstm) + len(args.flash)) - len(rows)
     for r in rows:
         print(json.dumps(r, sort_keys=True))

@@ -98,7 +98,9 @@ def main(argv=None) -> int:
         from deeplearning4j_tpu.exec.aot import companion_path
         out = companion_path(args.checkpoint)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # builds for the backend the environment gives this process (the
+    # artifact is keyed on it) and names the device in the summary
+    from deeplearning4j_tpu.exec.mesh import device_info
     from deeplearning4j_tpu.util.compile_cache import setup_compile_cache
     setup_compile_cache()
 
@@ -106,6 +108,7 @@ def main(argv=None) -> int:
                              rungs=tuple(args.rungs),
                              slots=args.slots, max_len=args.max_len,
                              checkpoint=args.checkpoint)
+    summary["device"] = device_info()
     print(json.dumps(summary, indent=1))
     return 0
 
