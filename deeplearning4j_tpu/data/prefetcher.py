@@ -21,8 +21,9 @@ uint8 image batches cross the link raw and the f32 cast/scale runs on chip.
 The prefetcher is payload-agnostic: items may be DataSets, tuples/lists of
 arrays, or any nesting of them; every numpy/jax array leaf is device_put.
 Per-stage costs (``fetch`` = pulling the upstream iterator, ``h2d`` =
-device_put dispatch) are recorded into an optional
-``util.timing.PipelineTimer`` so callers can report a host-stall fraction.
+device_put dispatch) and the bytes put on the device are recorded into an
+optional ``util.timing.PipelineTimer`` so callers can report a host-stall
+fraction and the bytes staged per step.
 """
 
 from __future__ import annotations
@@ -34,15 +35,19 @@ import numpy as np
 from deeplearning4j_tpu.monitor.tracing import trace
 
 
-def _device_put_tree(item, device=None):
-    """device_put every array leaf of a DataSet / tuple / list / dict."""
+def _device_put_tree(item, device=None, staged=None):
+    """device_put every array leaf of a DataSet / tuple / list / dict;
+    ``staged``, a list, receives each leaf's ``nbytes``."""
     import jax
     from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
 
     def put(a):
         if a is None:
             return None
-        return jax.device_put(a, device)
+        out = jax.device_put(a, device)
+        if staged is not None:
+            staged.append(out.nbytes)
+        return out
 
     if isinstance(item, DataSet):
         return DataSet(put(item.features), put(item.labels),
@@ -56,11 +61,12 @@ def _device_put_tree(item, device=None):
             labels_masks=None if item.labels_masks is None else
             [put(m) for m in item.labels_masks])
     if isinstance(item, tuple):
-        return tuple(_device_put_tree(x, device) for x in item)
+        return tuple(_device_put_tree(x, device, staged) for x in item)
     if isinstance(item, list):
-        return [_device_put_tree(x, device) for x in item]
+        return [_device_put_tree(x, device, staged) for x in item]
     if isinstance(item, dict):
-        return {k: _device_put_tree(v, device) for k, v in item.items()}
+        return {k: _device_put_tree(v, device, staged)
+                for k, v in item.items()}
     if isinstance(item, (np.ndarray, np.generic)) or hasattr(item, "devices"):
         return put(item)
     return item               # strings/ints/None ride through untouched
@@ -121,16 +127,18 @@ class DevicePrefetcher:
                 self._exhausted = True
                 break
             t1 = _time.perf_counter()
+            nbytes = []
             with trace.span("h2d"):
                 device = (self.device(item) if callable(self.device)
                           else self.device)
-                staged = _device_put_tree(item, device)
+                staged = _device_put_tree(item, device, nbytes)
                 if self.transform is not None:
                     staged = self.transform(staged)
             # upstream stages (fetch/decode) time themselves; only the
             # device_put dispatch is this stage's own cost
             if self.timer is not None:
                 self.timer.add("h2d", _time.perf_counter() - t1)
+                self.timer.bytes_staged += sum(nbytes)
             self._buf.append(staged)
 
     def __next__(self):

@@ -363,12 +363,14 @@ class Executor:
         from deeplearning4j_tpu.exec.programs import get_programs
         return get_programs()
 
-    def register_program(self, caller, key, fn, args, compile_seconds=None):
+    def register_program(self, caller, key, fn, args, compile_seconds=None,
+                         scopes=False):
         """Record a program built by :meth:`jit` (single-device ``jax.jit``
         results and mesh wrappers both work); see
         ``programs.ProgramRegistry.record``."""
         return self.programs.record(caller, key, fn, args,
-                                    compile_seconds=compile_seconds)
+                                    compile_seconds=compile_seconds,
+                                    scopes=scopes)
 
 
 # ------------------------------------------------------- process default

@@ -15,6 +15,9 @@ What this buys:
 - ``dl4jtpu_program_{flops,bytes,memory_bytes,compile_seconds}`` gauges
   labelled ``{caller,key}`` — MFU is now derivable from /metrics alone.
 - ``GET /programs`` on the inference server: the live program table.
+- ``op_scopes``, a step program's table from instruction name to the
+  named scopes it was traced under (util/scopes.py): what turns a device
+  trace's compiler-made operation names into layer and phase.
 - ``bench.py`` MFU rows read flops from here instead of re-deriving them
   with a private lowering helper.
 
@@ -31,7 +34,8 @@ import threading
 import time
 from typing import Optional
 
-__all__ = ["ProgramRegistry", "get_programs", "is_registering"]
+__all__ = ["ProgramRegistry", "get_programs", "is_registering",
+           "hlo_instructions"]
 
 
 _REGISTERING = threading.local()
@@ -66,22 +70,98 @@ def _lowerable(fn):
     return None
 
 
-def _analyze(jitted, args) -> dict:
+_COMPUTATION = re.compile(r"^(ENTRY )?%([^\s(]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.*)$")
+_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+_FUSED = re.compile(r"\bcalls=%([^\s,)}]+)")
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|true_computation|false_computation)"
+    r"=%([^\s,)}]+)|\bbranch_computations=\{([^}]*)\}")
+
+
+def hlo_instructions(text: str) -> list:
+    """``(name, opcode, op_name)`` of every instruction that runs as a
+    step of its own in the compiled HLO ``text``: those of the entry
+    computation and of the bodies it calls (``while``, ``call``,
+    ``conditional``), transitively. A fusion is one instruction, named by
+    its own metadata, or, where the compiler gave the fusion none, by the
+    instruction nearest its root that has some; what is fused into it is
+    not listed. ``op_name`` is ``""`` where the compiler left none."""
+    comps, entry, cur = {}, None, None      # computation -> its raw lines
+    for line in text.splitlines():
+        if line[:1] in ("%", "E"):
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = comps.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+        elif cur is not None:
+            if line[:1] == "}":
+                cur = None
+            else:
+                cur.append(line)
+
+    def named(comp):
+        """The last ``op_name`` of a computation, nearest its root."""
+        for line in reversed(comps.get(comp, ())):
+            m = _OP_NAME.search(line)
+            if m:
+                return m.group(1)
+        return ""
+
+    out, todo, seen = [], [entry], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            rest = m.group(2)
+            op = _OPCODE.search(rest)
+            op = op.group(1) if op else ""
+            op_name = _OP_NAME.search(rest)
+            op_name = op_name.group(1) if op_name else ""
+            if op == "fusion" and not op_name:
+                fused = _FUSED.search(rest)
+                op_name = named(fused.group(1)) if fused else ""
+            elif op in ("while", "call", "conditional"):
+                for one, many in _CALLED.findall(rest):
+                    todo += [one] if one else [
+                        c.strip().lstrip("%") for c in many.split(",")]
+            out.append((m.group(1), op, op_name))
+    return out
+
+
+def _analyze(jitted, args, scopes=False) -> dict:
     """Record fields read off the AOT-compiled program; an analysis XLA
     does not offer comes back None. ``mosaic_calls`` counts the Mosaic
     (Pallas TPU) custom calls in the compiled program: 0 means no
     hand-written kernel made it in — the interpreter lowers a kernel to
     plain XLA ops, and so counts 0. ``all_reduces`` counts the all-reduce
     collectives the partitioner put in: 0 on one device, the gradient
-    reduction of a data-parallel step on a mesh."""
+    reduction of a data-parallel step on a mesh. ``op_scopes``, where
+    ``scopes`` asks for it, is ``{instruction name: op_name}`` of
+    :func:`hlo_instructions`: the named scopes (util/scopes.py) of the
+    program that was loaded, which may be an older commit's entry of the
+    persistent cache (the cache key leaves names out), so the table
+    always matches what runs.
+    ``memory_bytes`` counts a donated argument once: ``memory_analysis``
+    lists it among the arguments and again among the outputs it aliases."""
     t0 = time.perf_counter()
     compiled = jitted.lower(*args).compile()
+    aot_seconds = time.perf_counter() - t0
     text = compiled.as_text()
     out = {"flops": None, "bytes": None, "memory_bytes": None,
-           "aot_seconds": time.perf_counter() - t0,
+           "aot_seconds": aot_seconds,
            "mosaic_calls": text.count('custom_call_target="tpu_custom_call"'),
            "all_reduces": len(re.findall(r"\ball-reduce(?:-start)?\(",
-                                         text))}
+                                         text)),
+           "op_scopes": ({n: o for n, _, o in hlo_instructions(text)}
+                         if scopes else None)}
     try:
         an = compiled.cost_analysis()
         if isinstance(an, (list, tuple)):
@@ -99,8 +179,9 @@ def _analyze(jitted, args) -> dict:
             "temp_size_in_bytes", "argument_size_in_bytes",
             "output_size_in_bytes", "generated_code_size_in_bytes")]
         if any(v is not None for v in sizes):
-            out["memory_bytes"] = float(sum(v for v in sizes
-                                            if v is not None))
+            out["memory_bytes"] = float(
+                sum(v for v in sizes if v is not None)
+                - (getattr(mem, "alias_size_in_bytes", None) or 0))
     except Exception:
         pass
     return out
@@ -145,11 +226,14 @@ class ProgramRegistry:
                 fam.labels(**lbl).set(v)
 
     def record(self, caller: str, key: str, fn, args,
-               compile_seconds: Optional[float] = None) -> Optional[dict]:
+               compile_seconds: Optional[float] = None,
+               scopes: bool = False) -> Optional[dict]:
         """Register program ``(caller, key)``; re-registration of a known
         key is a no-op (returns the existing record). Analysis failures
         degrade to a record with None fields rather than raising into
-        the caller's hot path."""
+        the caller's hot path. ``scopes`` keeps the record's ``op_scopes``
+        table (the containers' step programs ask for it; a serving bucket
+        has no reader for one)."""
         caller, key = str(caller), str(key)
         with self._lock:
             existing = self._programs.get((caller, key))
@@ -160,19 +244,18 @@ class ProgramRegistry:
             return None
         fields = {"flops": None, "bytes": None, "memory_bytes": None,
                   "aot_seconds": None, "mosaic_calls": None,
-                  "all_reduces": None}
+                  "all_reduces": None, "op_scopes": None}
         try:
             with _Registering():
-                fields = _analyze(jitted, args)
+                fields = _analyze(jitted, args, scopes)
         except Exception:
             pass
-        aot_s = fields.pop("aot_seconds")
         record = {
             "caller": caller,
             "key": key,
             **fields,
             "compile_seconds": (compile_seconds if compile_seconds is not None
-                                else aot_s),
+                                else fields["aot_seconds"]),
         }
         with self._lock:
             # lost a race: keep the first registration
@@ -199,8 +282,12 @@ class ProgramRegistry:
             return out
 
     def entries(self) -> list:
+        """Every record without its ``op_scopes`` table (thousands of
+        rows a step program; ``get``/``last`` return the record with
+        it)."""
         with self._lock:
-            return [dict(rec) for rec in self._programs.values()]
+            return [{k: v for k, v in rec.items() if k != "op_scopes"}
+                    for rec in self._programs.values()]
 
     def clear(self) -> None:
         with self._lock:

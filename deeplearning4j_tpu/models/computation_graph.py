@@ -32,6 +32,7 @@ def _dtype_of(name):
             "float16": jnp.float16, "float64": jnp.float64}[name]
 
 
+from deeplearning4j_tpu.util.scopes import layer_scope
 from deeplearning4j_tpu.util.dtypes import (cast_floats as _cast_floats,
                                              restore_dtypes as _restore_dtypes)
 
@@ -181,34 +182,36 @@ class ComputationGraph:
             ins = [acts[i] for i in node.inputs]
             if node.kind == "vertex":
                 v = node.vertex
-                if getattr(v, "mask_input", None) is not None:
-                    # mask-aware vertex (LastTimeStepVertex): the named
-                    # network input's (B, T) mask locates true last steps
-                    m = masks.get(v.mask_input) if masks else None
-                    acts[name] = v.apply(ins, mask=m)
-                else:
-                    acts[name] = v.apply(ins)
+                with layer_scope(name, v):
+                    if getattr(v, "mask_input", None) is not None:
+                        # mask-aware vertex (LastTimeStepVertex): the named
+                        # network input's (B, T) mask locates true last steps
+                        m = masks.get(v.mask_input) if masks else None
+                        acts[name] = v.apply(ins, mask=m)
+                    else:
+                        acts[name] = v.apply(ins)
                 continue
             lrng = None if rng is None else jax.random.fold_in(rng, idx)
             mask = None
             if masks and node.inputs and node.inputs[0] in masks:
                 mask = masks[node.inputs[0]]
             p_n = params.get(name, {})
-            if (train and node.layer.weight_noise is not None
-                    and lrng is not None):
-                p_n = node.layer.weight_noise.apply(
-                    p_n, jax.random.fold_in(lrng, 0x5eed))
-            if (new_carries is not None
-                    and hasattr(node.layer, "apply_with_carry")):
-                y, c = node.layer.apply_with_carry(
-                    p_n, ins[0], new_carries.get(name), mask=mask)
-                new_carries[name] = c
-            else:
-                y, st = node.layer.apply(p_n, ins[0],
-                                         state.get(name), train=train,
-                                         rng=lrng, mask=mask)
-                if st is not None:
-                    new_state[name] = st
+            with layer_scope(name, node.layer):
+                if (train and node.layer.weight_noise is not None
+                        and lrng is not None):
+                    p_n = node.layer.weight_noise.apply(
+                        p_n, jax.random.fold_in(lrng, 0x5eed))
+                if (new_carries is not None
+                        and hasattr(node.layer, "apply_with_carry")):
+                    y, c = node.layer.apply_with_carry(
+                        p_n, ins[0], new_carries.get(name), mask=mask)
+                    new_carries[name] = c
+                else:
+                    y, st = node.layer.apply(p_n, ins[0],
+                                             state.get(name), train=train,
+                                             rng=lrng, mask=mask)
+                    if st is not None:
+                        new_state[name] = st
             acts[name] = y
         if cdt is not None:
             # persistent state (BN stats) keeps its storage dtype
@@ -222,28 +225,33 @@ class ComputationGraph:
               label_masks=None, carries=None):
         """Aux return is ``new_state`` normally; when ``carries`` is given
         (tBPTT chunked training) it is ``(new_state, new_carries)``."""
-        acts, new_state, new_carries = self._forward(
-            params, state, inputs, train=True, rng=rng, masks=masks,
-            carries=carries)
-        total = 0.0
-        for oi, out_name in enumerate(self.conf.network_outputs):
-            node = self.conf.nodes[out_name]
-            if node.kind != "layer" or not hasattr(node.layer, "compute_score"):
-                raise ValueError(f"Output '{out_name}' is not a loss-bearing layer")
-            pre_act_input = acts[node.inputs[0]]
-            lrng = None if rng is None else jax.random.fold_in(rng, 10000 + oi)
-            lm = None if not label_masks else label_masks[oi]
-            p_out = params.get(out_name, {})
-            if node.layer.weight_noise is not None and lrng is not None:
-                p_out = node.layer.weight_noise.apply(
-                    p_out, jax.random.fold_in(lrng, 0x5eed))
-            total = total + node.layer.compute_score(
-                p_out, pre_act_input, labels[oi], lm,
-                train=True, rng=lrng)
-        for name, p in params.items():
-            total = total + self.conf.nodes[name].layer.reg_loss(p)
-        if self._compute_dtype(True) is not None:
-            total = total.astype(jnp.float32)
+        with jax.named_scope("forward"):
+            acts, new_state, new_carries = self._forward(
+                params, state, inputs, train=True, rng=rng, masks=masks,
+                carries=carries)
+        with jax.named_scope("loss"):
+            total = 0.0
+            for oi, out_name in enumerate(self.conf.network_outputs):
+                node = self.conf.nodes[out_name]
+                if (node.kind != "layer"
+                        or not hasattr(node.layer, "compute_score")):
+                    raise ValueError(
+                        f"Output '{out_name}' is not a loss-bearing layer")
+                pre_act_input = acts[node.inputs[0]]
+                lrng = (None if rng is None
+                        else jax.random.fold_in(rng, 10000 + oi))
+                lm = None if not label_masks else label_masks[oi]
+                p_out = params.get(out_name, {})
+                if node.layer.weight_noise is not None and lrng is not None:
+                    p_out = node.layer.weight_noise.apply(
+                        p_out, jax.random.fold_in(lrng, 0x5eed))
+                total = total + node.layer.compute_score(
+                    p_out, pre_act_input, labels[oi], lm,
+                    train=True, rng=lrng)
+            for name, p in params.items():
+                total = total + self.conf.nodes[name].layer.reg_loss(p)
+            if self._compute_dtype(True) is not None:
+                total = total.astype(jnp.float32)
         if carries is not None:
             return total, (new_state, new_carries)
         return total, new_state
@@ -291,6 +299,7 @@ class ComputationGraph:
         return self._loss(params, state, inputs, labels, rng, masks,
                           label_masks)
 
+    @jax.named_scope("updater")
     def _dp_apply_updates(self, params, opt_state, grads, fused=None):
         """Fused flat update by default (nn/fused_update.py — bitwise-equal
         to the per-node loop below, kept as the DL4JTPU_FUSED_UPDATE=0
@@ -462,7 +471,7 @@ class ComputationGraph:
                 self._scan_fit,
                 (self.params, self.state, self.opt_state, inputs_steps,
                  labels_steps, jnp.asarray(self.iteration, jnp.int32)),
-                compile_seconds=time.perf_counter() - t0)
+                compile_seconds=time.perf_counter() - t0, scopes=True)
         if self.listeners:
             with trace.span("callback"):
                 for lst in self.listeners:
@@ -714,17 +723,18 @@ class ComputationGraph:
             stream = DevicePrefetcher(stream, depth=depth, timer=timer,
                                       device=self._stream_placement)
         it = iter(stream)
+        it0 = self.iteration
         timer.start()
         while True:
             # one "train_step" span per consumer iteration (nests the wait
-            # and the step — see MultiLayerNetwork._fit_stream)
-            with trace.span("train_step"):
+            # and the dispatch — see MultiLayerNetwork._fit_stream)
+            with trace.step("train_step", self.iteration):
                 with timer.stage("wait"):
                     try:
                         kind, payload = next(it)
                     except StopIteration:
                         break
-                with timer.stage("step"):
+                with timer.dispatch(lambda: self._score):
                     if kind == "chunk":
                         xs, ys = payload
                         xs = [jnp.asarray(a) for a in xs]
@@ -737,6 +747,7 @@ class ComputationGraph:
                         # processor)
                         self._fit_batch(dev_mds(payload))
         timer.stop()
+        timer.steps = self.iteration - it0
         self.last_pipeline_stats = timer.summary()
         timer.publish("fit")
 
@@ -757,6 +768,7 @@ class ComputationGraph:
         if (getattr(self.conf, "backprop_type", "standard") == "tbptt"
                 and inputs[0].ndim == 3):
             self._fit_tbptt(inputs, labels, masks, label_masks)
+            self._last_fit_time = time.perf_counter() - t0
         else:
             key = (masks is not None, label_masks is not None)
             if key not in self._train_step_cache:
@@ -770,6 +782,9 @@ class ComputationGraph:
                                 # get_score() (a read waits for the step)
             if self._flight is not None:
                 self._flight.record(self.iteration, out[4])
+            # taken before the registration below, whose second compile
+            # is the record's own aot_seconds, not this call's
+            self._last_fit_time = time.perf_counter() - t0
             if self._compile_count > c0:
                 # fresh XLA program: expose its cost/memory analysis via the
                 # registry (/programs). Donated inputs → lower with outputs.
@@ -780,8 +795,7 @@ class ComputationGraph:
                     (self.params, self.state, self.opt_state, inputs, labels,
                      jnp.asarray(self.iteration, jnp.int32), masks,
                      label_masks),
-                    compile_seconds=time.perf_counter() - t0)
-        self._last_fit_time = time.perf_counter() - t0
+                    compile_seconds=self._last_fit_time, scopes=True)
         self.iteration += 1
         self._epoch_batch += 1
         self._mon.record(seconds=self._last_fit_time, steps=1,

@@ -35,6 +35,7 @@ def _dtype_of(name):
             "float16": jnp.float16, "float64": jnp.float64}[name]
 
 
+from deeplearning4j_tpu.util.scopes import layer_scope
 from deeplearning4j_tpu.util.dtypes import (cast_floats as _cast_floats,
                                              restore_dtypes as _restore_dtypes)
 
@@ -184,22 +185,25 @@ class MultiLayerNetwork:
                     lstm_pair_fusable, apply_lstm_pair)
                 if lstm_pair_fusable(l, self.layers[i + 1], params[i],
                                      params[i + 1], x, mask):
-                    x = apply_lstm_pair(l, self.layers[i + 1],
-                                        params[i], params[i + 1], x,
-                                        train=train, rng=lrng)
+                    with layer_scope(l.name or f"layer{i}", l):
+                        x = apply_lstm_pair(l, self.layers[i + 1],
+                                            params[i], params[i + 1], x,
+                                            train=train, rng=lrng)
                     i += 2
                     continue
             p_i = params[i]
-            if train and l.weight_noise is not None and lrng is not None:
-                p_i = l.weight_noise.apply(
-                    p_i, jax.random.fold_in(lrng, 0x5eed))
-            if new_carries is not None and hasattr(l, "apply_with_carry"):
-                x, c = l.apply_with_carry(p_i, x, new_carries[i], mask=mask)
-                new_carries[i] = c
-            else:
-                x, st = l.apply(p_i, x, state[i], train=train, rng=lrng,
-                                mask=mask)
-                new_states[i] = st if st is not None else state[i]
+            with layer_scope(l.name or f"layer{i}", l):
+                if train and l.weight_noise is not None and lrng is not None:
+                    p_i = l.weight_noise.apply(
+                        p_i, jax.random.fold_in(lrng, 0x5eed))
+                if new_carries is not None and hasattr(l, "apply_with_carry"):
+                    x, c = l.apply_with_carry(p_i, x, new_carries[i],
+                                              mask=mask)
+                    new_carries[i] = c
+                else:
+                    x, st = l.apply(p_i, x, state[i], train=train, rng=lrng,
+                                    mask=mask)
+                    new_states[i] = st if st is not None else state[i]
             if x.ndim == 2:
                 mask = None  # sequence collapsed to per-example
             i += 1
@@ -212,27 +216,29 @@ class MultiLayerNetwork:
     def _loss(self, params, state, x, y, rng, mask_f, mask_l, carries=None):
         gc = self.conf.global_conf
         out_layer = self.layers[-1]
-        act, new_states, new_carries = self._forward(
-            params, state, x, train=True, rng=rng, mask=mask_f, carries=carries,
-            upto=len(self.layers) - 1)
-        lrng = None if rng is None else jax.random.fold_in(rng, len(self.layers) - 1)
-        p_out = params[-1]
-        if out_layer.weight_noise is not None and lrng is not None:
-            p_out = out_layer.weight_noise.apply(
-                p_out, jax.random.fold_in(lrng, 0x5eed))
-        if hasattr(out_layer, "compute_score"):
-            loss = out_layer.compute_score(p_out, act, y, mask_l,
-                                           train=True, rng=lrng)
-        else:
+        if not hasattr(out_layer, "compute_score"):
             raise ValueError(
                 f"Last layer {type(out_layer).__name__} has no loss; use an "
                 "OutputLayer/LossLayer variant")
-        reg = 0.0
-        for l, p in zip(self.layers, params):
-            reg = reg + l.reg_loss(p)
-        loss = loss + reg
-        if self._compute_dtype(True) is not None:
-            loss = loss.astype(jnp.float32)
+        with jax.named_scope("forward"):
+            act, new_states, new_carries = self._forward(
+                params, state, x, train=True, rng=rng, mask=mask_f,
+                carries=carries, upto=len(self.layers) - 1)
+        with jax.named_scope("loss"):
+            lrng = (None if rng is None
+                    else jax.random.fold_in(rng, len(self.layers) - 1))
+            p_out = params[-1]
+            if out_layer.weight_noise is not None and lrng is not None:
+                p_out = out_layer.weight_noise.apply(
+                    p_out, jax.random.fold_in(lrng, 0x5eed))
+            loss = out_layer.compute_score(p_out, act, y, mask_l,
+                                           train=True, rng=lrng)
+            reg = 0.0
+            for l, p in zip(self.layers, params):
+                reg = reg + l.reg_loss(p)
+            loss = loss + reg
+            if self._compute_dtype(True) is not None:
+                loss = loss.astype(jnp.float32)
         return loss, (new_states, new_carries)
 
     def _normalize_grads(self, grads):
@@ -266,6 +272,7 @@ class MultiLayerNetwork:
         loss, (new_state, _) = self._loss(params, state, x, y, rng, mf, ml)
         return loss, new_state
 
+    @jax.named_scope("updater")
     def _dp_apply_updates(self, params, opt_state, grads, fused=None):
         """Normalize grads, run updaters, apply constraints. Default path:
         the fused flat program (nn/fused_update.py — bitwise-equal to the
@@ -482,7 +489,7 @@ class MultiLayerNetwork:
                 self._scan_fit,
                 (self.params, self.state, self.opt_state, xs, ys,
                  jnp.asarray(self.iteration, jnp.int32)),
-                compile_seconds=time.perf_counter() - t0)
+                compile_seconds=time.perf_counter() - t0, scopes=True)
         if self.listeners:
             with trace.span("callback"):
                 for lst in self.listeners:
@@ -729,17 +736,18 @@ class MultiLayerNetwork:
             stream = DevicePrefetcher(stream, depth=depth, timer=timer,
                                       device=self._stream_placement)
         it = iter(stream)
+        it0 = self.iteration
         timer.start()
         while True:
             # one "train_step" span per consumer iteration: it nests the
-            # wait (and any fetch/h2d work surfaced inside it) + the step
-            with trace.span("train_step"):
+            # wait (and the fetch/stack/h2d work inside it) + the dispatch
+            with trace.step("train_step", self.iteration):
                 with timer.stage("wait"):
                     try:
                         kind, payload = next(it)
                     except StopIteration:
                         break
-                with timer.stage("step"):
+                with timer.dispatch(lambda: self._score):
                     if kind == "chunk":
                         xs, ys = payload
                         xs = jnp.asarray(xs)
@@ -752,6 +760,7 @@ class MultiLayerNetwork:
                         # for a device_side pp
                         self._fit_batch(self._apply_dev_pp(payload, dev_fn))
         timer.stop()
+        timer.steps = self.iteration - it0
         self.last_pipeline_stats = timer.summary()
         timer.publish("fit")
 

@@ -7,9 +7,9 @@ Two stores, one subsystem:
   Prometheus text exposition format. Scraped at ``GET /metrics`` on the
   serving server; read in-process by ``/stats``, the UI StatsListener and
   bench row snapshots — all the same numbers, so surfaces cannot drift.
-- ``tracing`` — a ring-buffered span tracer (``with trace.span("step")``)
+- ``tracing`` — a ring-buffered span tracer (``with trace.span("dispatch")``)
   exporting Chrome trace-event JSON for Perfetto; spans cover the train
-  loop (wait/fetch/h2d/step/callback) and the serving path
+  loop (wait/fetch/h2d/dispatch/callback) and the serving path
   (enqueue/bucket/pad/device/readback).
 
 Fleet additions (docs/OBSERVABILITY.md):

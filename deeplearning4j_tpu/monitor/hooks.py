@@ -13,8 +13,6 @@ which waits for the step, happens at scrape time, never in the train loop
 
 from __future__ import annotations
 
-import time
-
 from deeplearning4j_tpu.monitor.metrics import (
     DEFAULT_STEP_BUCKETS, get_registry)
 
@@ -69,7 +67,3 @@ class TrainMonitor:
             self.compile_seconds.inc(seconds)
         else:
             self._hist[path].observe(seconds)
-
-    def timed(self):
-        """Start-of-call timestamp (symmetry helper)."""
-        return time.perf_counter()

@@ -6,9 +6,21 @@ al., 2010): nestable named intervals recorded per thread, serialized as
 ``B``/``E`` (duration begin/end) events in the Chrome trace-event format
 — load the exported file straight into Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing`` and the ``train_step``
-spans visually nest their ``wait``/``fetch``/``h2d``/``step``/
-``callback`` children; the serving path shows
-``enqueue``/``bucket``/``pad``/``device``/``readback``.
+spans visually nest ``wait`` (with ``fetch``/``decode``/``stack``/``h2d``
+inside it) and ``dispatch`` (with ``callback`` inside it); the serving
+path shows ``enqueue``/``bucket``/``pad``/``device``/``readback``.
+
+Two sinks, one set of spans. The ring buffer below is for the fleet merge
+and is stamped with the wall clock. A ``jax.profiler`` capture has a clock
+of its own (an event's ``start_ns`` counts from the session's start, not
+from the unix epoch), so the ring's timestamps cannot be laid beside the
+device's operations after the fact. While the tracer is enabled every span
+therefore also enters a ``jax.profiler.TraceAnnotation`` of the same name
+and arguments (``Tracer.step`` a ``StepTraceAnnotation`` carrying
+``step_num``): under a capture with the host tracer at level 1
+(``monitor/profiling.py``) the spans land in the xplane's host plane, on
+the clock of the device's operations, and a device idle gap can be named
+by the span the host was in. Outside a capture the annotation is inert.
 
 Fleet tracing: timestamps are anchored to the unix epoch (wall clock) so
 spans recorded by *different processes* — the router, each replica
@@ -22,10 +34,10 @@ ring buffer over ``GET /trace`` and emits the single merged document.
 Overhead discipline: tracing is OFF by default; a disabled tracer's
 ``span()`` returns one shared no-op context manager (no allocation, no
 clock read). Enabled, argless spans are cached per name (no per-call
-allocation); each span costs two ``perf_counter`` reads and two dict
+allocation); each span costs two ``perf_counter`` reads, two dict
 appends into a bounded ring buffer (old events are dropped, the process
-never grows without bound). The bench's ``observability`` row pins the
-cost of both states.
+never grows without bound) and one profiler annotation. The bench's
+``observability`` row pins the cost of both states.
 
 Enable via code (``trace.enable()``) or environment::
 
@@ -38,6 +50,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -135,13 +148,25 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tr", "_name", "_args")
+def _profiler_annotation(name, args, step):
+    """The span's twin on the profiler's clock, or None in a process that
+    has not imported jax (it can hold no capture either)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    cls = (jax.profiler.StepTraceAnnotation if step
+           else jax.profiler.TraceAnnotation)
+    return cls(name, **args) if args else cls(name)
 
-    def __init__(self, tr, name, args):
+
+class _Span:
+    __slots__ = ("_tr", "_name", "_args", "_step")
+
+    def __init__(self, tr, name, args, step=False):
         self._tr = tr
         self._name = name
         self._args = args
+        self._step = step
 
     def __enter__(self):
         tr = self._tr
@@ -159,9 +184,21 @@ class _Span:
         if args:
             ev["args"] = args
         tr._events.append(ev)
+        # argless spans are shared between threads, so the annotation an
+        # enter opens lives on the thread's own stack, not on the span
+        anno = _profiler_annotation(self._name, args, self._step)
+        if anno is not None:
+            anno.__enter__()
+        try:
+            _CTX.annos.append(anno)
+        except AttributeError:
+            _CTX.annos = [anno]
         return self
 
     def __exit__(self, *exc):
+        anno = _CTX.annos.pop()
+        if anno is not None:
+            anno.__exit__(*exc)
         tr = self._tr
         tr._events.append(
             {"ph": "E", "name": self._name, "pid": tr._pid,
@@ -219,7 +256,7 @@ class Tracer:
         return self
 
     def span(self, name: str, **args):
-        """``with trace.span("step"): ...`` — nest freely; disabled
+        """``with trace.span("dispatch"): ...`` — nest freely; disabled
         tracing returns a shared no-op (near-zero cost)."""
         if not self._enabled:
             return _NULL_SPAN
@@ -231,6 +268,15 @@ class Tracer:
                 s = self._argless[name] = _Span(self, name, None)
             return s
         return _Span(self, name, args)
+
+    def step(self, name: str, step_num: int):
+        """One iteration of a loop: a span that carries ``step_num`` and
+        whose twin in a profiler capture is a ``StepTraceAnnotation``, so
+        the device's operations group under the step that dispatched them.
+        Positional, so that a disabled tracer's call allocates nothing."""
+        if not self._enabled:
+            return _NULL_SPAN
+        return _Span(self, name, {"step_num": int(step_num)}, step=True)
 
     def instant(self, name: str, **args):
         """Point-in-time marker (Chrome ``i`` event)."""

@@ -1,4 +1,5 @@
-"""Device timing for ops shorter than a dispatch, and pipeline stage clocks.
+"""Device timing for ops shorter than a dispatch, and the fit loop's stage
+clocks and counters.
 
 JAX dispatch is asynchronous: a timing that does not wait for the result
 measures the enqueue. On a directly attached chip ``block_until_ready``
@@ -15,6 +16,7 @@ an N=1 run. N is chosen adaptively so the measured delta dominates jitter.
 from __future__ import annotations
 
 import time
+from collections import deque
 from contextlib import contextmanager
 
 import numpy as np
@@ -23,24 +25,42 @@ from deeplearning4j_tpu.monitor.tracing import trace
 
 
 class PipelineTimer:
-    """Per-stage input-pipeline accounting (fetch / decode / h2d / step).
+    """Per-stage accounting of one streamed fit/eval call: where the
+    consumer loop's time went, what it cost in CPU, and what it moved.
 
-    The containers' streamed fit path records how long the consumer loop
-    spends in each stage; ``host_stall_frac()`` is the fraction of the
-    epoch's wall time the host spent WAITING ON DATA instead of dispatching
-    device work — the number that caps accelerator utilization once the
-    compiled step is fast (un-pipelined input feeding, not FLOPs).
+    ``host_stall_frac()`` is the fraction of the call's wall time the host
+    spent WAITING ON DATA instead of dispatching device work — the number
+    that caps accelerator utilization once the compiled step is fast
+    (un-pipelined input feeding, not FLOPs).
 
     Stage conventions used by ``_fit_stream``:
 
     - ``wait``  — consumer blocked in ``next()`` on the input stream. With
       the prefetch pipeline on, this is the ONLY stall the host sees (the
       fetch/decode/h2d work happens inside it or ahead of it).
-    - ``fetch`` / ``decode`` / ``h2d`` — informative sub-stage costs
-      recorded by the stream/prefetcher; they may be nested inside ``wait``
+    - ``fetch`` / ``decode`` / ``stack`` / ``h2d`` — sub-stage costs
+      recorded by the stream/prefetcher; they are nested inside ``wait``
       so they are NOT summed into the stall when ``wait`` was recorded.
-    - ``step`` — train-step dispatch (async on TPU: enqueue time, not
-      device time; honest device step timing is ``time_op`` below).
+    - ``dispatch`` — handing a step (or a chunk of steps) to the device.
+      Dispatch is asynchronous: this is enqueue time, and once the host has
+      run as far ahead as the runtime lets it, mostly time blocked on the
+      device's back-pressure. It is not the device's step time (that is
+      ``time_op`` below, or a device trace).
+
+    Beside the seconds (all per call, all in ``summary()``):
+
+    - ``loop_cpu_sec`` — ``time.thread_time()`` of the loop's thread: what
+      the loop *worked*. Wall minus CPU is time blocked, on data or on the
+      device.
+    - ``process_cpu_sec`` — ``time.process_time()`` over the loop: also the
+      runtime's own threads (``device_put`` linearizes a batch there).
+    - ``steps`` — train steps the loop dispatched.
+    - ``bytes_staged`` — bytes of every array the ``DevicePrefetcher`` put
+      on the device.
+    - ``in_flight_max`` — the most dispatched program calls not yet
+      complete, counted right after each dispatch (a call is one step on
+      the ``train_step`` path, one chunk on ``fit_scan``): how far ahead of
+      the device the host runs.
 
     ``host_stall_frac`` = wait/wall when ``wait`` was recorded, else
     (fetch+decode+h2d)/wall (the naive un-pipelined path executes those
@@ -53,6 +73,12 @@ class PipelineTimer:
         self.counts = {}
         self._t0 = None
         self.wall = 0.0
+        self.loop_cpu = 0.0
+        self.process_cpu = 0.0
+        self.steps = 0
+        self.bytes_staged = 0
+        self._in_flight = deque()
+        self.in_flight_max = 0
 
     def add(self, stage: str, sec: float):
         self.seconds[stage] = self.seconds.get(stage, 0.0) + sec
@@ -69,14 +95,40 @@ class PipelineTimer:
             finally:
                 self.add(name, time.perf_counter() - t0)
 
+    @contextmanager
+    def dispatch(self, result):
+        """The ``dispatch`` stage. ``result()`` is read after the block and
+        gives the container's score: the loss array of the dispatched call.
+        Calls whose array ``is_ready()`` are dropped before the dispatch,
+        the new one joins after it, and what is left is in flight. Where a
+        listener read the score inside the block (``get_score()`` leaves a
+        float there), the host has waited for this call and so for every
+        call before it: nothing is in flight."""
+        q = self._in_flight
+        while q and q[0].is_ready():
+            q.popleft()
+        with self.stage("dispatch"):
+            yield
+        loss = result()
+        if hasattr(loss, "is_ready"):
+            q.append(loss)
+        else:
+            q.clear()
+        self.in_flight_max = max(self.in_flight_max, len(q))
+
     def start(self):
-        self._t0 = time.perf_counter()
+        self._t0 = (time.perf_counter(), time.thread_time(),
+                    time.process_time())
         return self
 
     def stop(self):
         if self._t0 is not None:
-            self.wall += time.perf_counter() - self._t0
+            t0, c0, p0 = self._t0
+            self.wall += time.perf_counter() - t0
+            self.loop_cpu += time.thread_time() - c0
+            self.process_cpu += time.process_time() - p0
             self._t0 = None
+        self._in_flight.clear()
         return self
 
     def host_stall_frac(self):
@@ -91,7 +143,12 @@ class PipelineTimer:
 
     def summary(self) -> dict:
         out = {"wall_sec": round(self.wall, 4),
-               "host_stall_frac": self.host_stall_frac()}
+               "host_stall_frac": self.host_stall_frac(),
+               "loop_cpu_sec": round(self.loop_cpu, 4),
+               "process_cpu_sec": round(self.process_cpu, 4),
+               "steps": self.steps,
+               "bytes_staged": self.bytes_staged,
+               "in_flight_max": self.in_flight_max}
         if out["host_stall_frac"] is not None:
             out["host_stall_frac"] = round(out["host_stall_frac"], 4)
         for k in sorted(self.seconds):
@@ -99,11 +156,10 @@ class PipelineTimer:
         return out
 
     def publish(self, path: str):
-        """Flow this timer's stage totals into the process-wide
-        MetricsRegistry so ``host_stall_frac`` and per-stage seconds are
-        scrapeable at ``/metrics``. ``path`` labels the pipeline ("fit" /
-        "eval"). Stage counters accumulate across epochs; the stall
-        fraction gauge holds the LAST epoch's value."""
+        """Flow this timer's totals into the process-wide MetricsRegistry
+        so they are scrapeable at ``/metrics``. ``path`` labels the
+        pipeline ("fit" / "eval"). Counters accumulate across calls; the
+        stall fraction and in-flight gauges hold the LAST call's value."""
         from deeplearning4j_tpu.monitor.metrics import get_registry
         reg = get_registry()
         fam = reg.counter(
@@ -116,6 +172,26 @@ class PipelineTimer:
             "dl4jtpu_pipeline_wall_seconds_total",
             "Cumulative wall seconds of streamed fit/eval epochs.",
             ("path",)).labels(path=path).inc(self.wall)
+        cpu = reg.counter(
+            "dl4jtpu_pipeline_cpu_seconds_total",
+            "Cumulative CPU seconds over streamed fit/eval epochs: of the "
+            "consumer loop's thread (scope=loop) and of the whole process "
+            "(scope=process).", ("path", "scope"))
+        cpu.labels(path=path, scope="loop").inc(self.loop_cpu)
+        cpu.labels(path=path, scope="process").inc(self.process_cpu)
+        reg.counter(
+            "dl4jtpu_pipeline_steps_total",
+            "Train steps dispatched by streamed fit epochs.",
+            ("path",)).labels(path=path).inc(self.steps)
+        reg.counter(
+            "dl4jtpu_pipeline_bytes_staged_total",
+            "Bytes the device prefetcher put on the device.",
+            ("path",)).labels(path=path).inc(self.bytes_staged)
+        reg.gauge(
+            "dl4jtpu_pipeline_in_flight_max",
+            "Most dispatched program calls not yet complete at once in "
+            "the last epoch: how far the host ran ahead of the device.",
+            ("path",)).labels(path=path).set(self.in_flight_max)
         frac = self.host_stall_frac()
         if frac is not None:
             reg.gauge(

@@ -298,13 +298,14 @@ def test_streamed_fit_trace_nests_step_spans():
     by_name = {}
     for b, e in pairs:
         by_name.setdefault(b["name"], []).append((b, e))
-    for required in ("train_step", "wait", "step", "fetch", "h2d"):
+    for required in ("train_step", "wait", "dispatch", "fetch", "h2d"):
         assert required in by_name, f"missing span {required!r}"
-    # every step span sits inside some train_step span
-    for sb, se in by_name["step"]:
+    # every dispatch span sits inside some train_step span
+    for sb, se in by_name["dispatch"]:
         assert any(tb["ts"] <= sb["ts"] and se["ts"] <= te["ts"]
                    for tb, te in by_name["train_step"]
-                   if tb["tid"] == sb["tid"]), "step not nested in train_step"
+                   if tb["tid"] == sb["tid"]), \
+            "dispatch not nested in train_step"
 
 
 def test_train_metrics_recorded_and_pipeline_published():
@@ -320,7 +321,7 @@ def test_train_metrics_recorded_and_pipeline_published():
     assert ex_fam is not None
     stage = reg.get("dl4jtpu_pipeline_stage_seconds_total")
     assert stage is not None
-    assert stage.labels(path="fit", stage="step").value > 0
+    assert stage.labels(path="fit", stage="dispatch").value > 0
     frac = reg.get("dl4jtpu_pipeline_host_stall_frac")
     assert 0.0 <= frac.labels(path="fit").value <= 1.0
     # the registry snapshot renders cleanly with everything above in it
