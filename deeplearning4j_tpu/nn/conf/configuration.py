@@ -56,7 +56,8 @@ class GlobalConf:
     compute_dtype: Optional[str] = None  # e.g. 'bfloat16' for MXU-friendly fwd/bwd
     # rematerialize activations in the backward pass (jax.checkpoint over
     # the loss). True/'full' recomputes everything; 'save_convs' (alias
-    # 'selective') keeps conv outputs and recomputes only BN/activations.
+    # 'selective') keeps conv outputs and BatchNorm's batch statistics and
+    # recomputes only BN's normalisation and the activations.
     # On TPU the conv-net backward is HBM-bound on stored activations: full
     # remat measured up to 5x faster at CIFAR shapes, 'save_convs' won at
     # 224 where conv recompute costs real FLOPs (round-5 ablation, before

@@ -16,6 +16,7 @@ machinery to port: XLA owns scheduling and memory.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -451,14 +452,74 @@ class Cropping2D(Layer):
         return x[:, t:H - b if b else H, l:W - r if r else W, :], state
 
 
+def _bn_train_fwd(x, gamma, beta, eps):
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    axes = tuple(range(x.ndim - 1))
+    n = x.size // x.shape[-1]
+    xf = x.astype(acc)
+    mean = xf.sum(axis=axes) / n
+    if acc == x.dtype:
+        # sums no wider than the activations: a second pass, about the
+        # mean, or mean^2 would cancel the variance's low bits away
+        var = jnp.square(xf - mean).sum(axis=axes) / n
+    else:
+        # half-precision activations, float32 sums: both sums from one read
+        # of x, neither waiting for the other, so XLA makes them one
+        # multi-output reduction, in the epilogue of the convolution whose
+        # output x is. What cancels is far under x's own rounding
+        var = jnp.maximum((xf * xf).sum(axis=axes) / n - mean * mean, 0)
+    # named for selective rematerialization: a few KB a layer that save the
+    # replay a reduction over the whole activation
+    mean = checkpoint_name(mean, "bn_stats")
+    rstd = checkpoint_name(lax.rsqrt(var + eps), "bn_stats")
+    scale = rstd if gamma is None else rstd * gamma.astype(acc)
+    y = (xf - mean) * scale
+    if beta is not None:
+        y = y + beta.astype(acc)
+    return (y.astype(x.dtype), mean, var), (x, gamma, mean, rstd)
+
+
+def _bn_train_bwd(eps, res, cts):
+    x, gamma, mean, rstd = res
+    acc = mean.dtype
+    axes = tuple(range(x.ndim - 1))
+    n = x.size // x.shape[-1]
+    dy = cts[0].astype(acc)
+    xhat = (x.astype(acc) - mean) * rstd
+    # one read of dy and x for both sums, one elementwise pass for dx
+    db = dy.sum(axis=axes)
+    dg = (dy * xhat).sum(axis=axes)
+    scale = rstd if gamma is None else rstd * gamma.astype(acc)
+    dx = (scale * (dy - db / n - xhat * (dg / n))).astype(x.dtype)
+    if gamma is None:
+        return dx, None, None
+    return dx, dg.astype(gamma.dtype), db.astype(gamma.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def batch_norm_train(x, gamma, beta, eps):
+    """Train-mode batch normalisation over every axis but the last as one
+    op with its own backward (parity: CudnnBatchNormalizationHelper, which
+    keeps the batch mean and inverse deviation of the forward pass for the
+    backward one). Returns ``(y, mean, var)``: ``y`` in ``x.dtype``, the
+    statistics in ``max(float32, x.dtype)``, ``var`` biased. ``gamma`` and
+    ``beta`` are of one dtype, or both None (locked at 1 and 0). ``mean``
+    and ``var`` are for the running statistics and carry no gradient, as
+    the reference updates globalMean/globalVar outside backprop."""
+    return _bn_train_fwd(x, gamma, beta, eps)[0]
+
+
+batch_norm_train.defvjp(_bn_train_fwd, _bn_train_bwd)
+
+
 @register_layer
 @dataclass
 class BatchNormalization(Layer):
     """Batch norm with running stats carried as functional state
     (parity: nn/conf/layers/BatchNormalization.java + cuDNN seam
-    CudnnBatchNormalizationHelper; running stats = the reference's
-    globalMean/globalVar params, here non-trainable state updated in the
-    train step and returned — no mutation)."""
+    CudnnBatchNormalizationHelper = ``batch_norm_train`` above; running
+    stats = the reference's globalMean/globalVar params, here non-trainable
+    state updated in the train step and returned — no mutation)."""
     n_in: int = 0
     decay: float = 0.9
     eps: float = 1e-5
@@ -482,25 +543,23 @@ class BatchNormalization(Layer):
                 "var": jnp.ones((self.n_in,), dtype)}
 
     def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
-        axes = tuple(range(x.ndim - 1))
+        act = get_activation(self.activation or "identity")
         if train:
-            mean = x.mean(axis=axes)
-            var = x.var(axis=axes)
-            new_state = {
+            xn, mean, var = batch_norm_train(
+                x, params.get("gamma"), params.get("beta"), self.eps)
+            return act(xn), {
                 "mean": self.decay * state["mean"] + (1 - self.decay) * mean,
                 "var": self.decay * state["var"] + (1 - self.decay) * var,
             }
-        else:
-            # running stats are stored f32 (dtype-stable state contract);
-            # cast to the activation dtype or a bf16 forward would promote
-            # to f32 and crash the next conv on mixed dtypes
-            mean = state["mean"].astype(x.dtype)
-            var = state["var"].astype(x.dtype)
-            new_state = state
+        # running stats are stored f32 (dtype-stable state contract);
+        # cast to the activation dtype or a bf16 forward would promote
+        # to f32 and crash the next conv on mixed dtypes
+        mean = state["mean"].astype(x.dtype)
+        var = state["var"].astype(x.dtype)
         xn = (x - mean) * lax.rsqrt(var + self.eps)
         if not self.lock_gamma_beta:
             xn = xn * params["gamma"] + params["beta"]
-        return get_activation(self.activation or "identity")(xn), new_state
+        return act(xn), state
 
 
 @register_layer

@@ -23,8 +23,10 @@ def check_remat_mode(mode):
 def remat_loss(loss_fn, mode):
     """``loss_fn`` wrapped per the configured remat ``mode``:
     False → unchanged; True/'full' → jax.checkpoint;
-    'save_convs'/'selective' → checkpoint saving only named conv outputs
-    (ConvolutionLayer tags them "conv_out")."""
+    'save_convs'/'selective' → checkpoint saving only named values: conv
+    outputs (ConvolutionLayer tags them "conv_out") and BatchNorm's batch
+    mean and inverse deviation (``batch_norm_train`` tags them "bn_stats":
+    a few KB a layer that spare the replay a reduction over activations)."""
     if not mode:
         return loss_fn
     if mode in (True, "full"):
@@ -32,6 +34,7 @@ def remat_loss(loss_fn, mode):
     if mode in ("save_convs", "selective"):
         return jax.checkpoint(
             loss_fn,
-            policy=jax.checkpoint_policies.save_only_these_names("conv_out"))
+            policy=jax.checkpoint_policies.save_only_these_names(
+                "conv_out", "bn_stats"))
     check_remat_mode(mode)                     # raises; not a known mode
     raise AssertionError("unreachable")

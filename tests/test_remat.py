@@ -4,7 +4,10 @@ storing them — on TPU this is faster for HBM-bound conv models and is the
 bench configuration for ResNet50; these tests pin that it changes NOTHING
 numerically."""
 
+import re
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -152,3 +155,59 @@ def test_remat_roundtrips_in_conf_json():
 def test_remat_builder_rejects_bad_mode_eagerly():
     with pytest.raises(ValueError, match="remat"):
         NeuralNetConfiguration.builder().remat("save_conv")
+
+
+def _residual_run(n_devices, batch, steps):
+    """``steps`` of ``fit`` on the small residual graph under ``save_convs``
+    on a data mesh of ``n_devices``: the losses, BatchNorm's statistics and
+    the compiled step's text."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.exec import build_mesh, set_default_mesh
+    from deeplearning4j_tpu.exec.programs import _lowerable
+    rs = np.random.RandomState(1)
+    x = rs.rand(batch, 8, 8, 3).astype(np.float32)
+    y = np.eye(3, dtype=np.float32)[rs.randint(0, 3, batch)]
+    set_default_mesh(build_mesh(jax.devices()[:n_devices]))
+    try:
+        cg = _small_residual_cg("save_convs")
+        losses = []
+        for _ in range(steps):
+            cg.fit(DataSet(x, y))
+            losses.append(float(cg.get_score()))
+        text = _lowerable(cg._train_step_cache[(False, False)]).lower(
+            cg.params, cg.state, cg.opt_state, [jnp.asarray(x)],
+            [jnp.asarray(y)], jnp.asarray(0, jnp.int32), None, None
+        ).compile().as_text()
+    finally:
+        set_default_mesh(None)
+    stats = {(k, s): np.asarray(v) for k, st in cg.state.items()
+             for s, v in (st or {}).items()}
+    return losses, stats, text
+
+
+def test_save_convs_step_keeps_batchnorm_statistics_across_the_replay():
+    """``save_convs`` saves the BatchNorm op's residuals (a few KB a layer,
+    tagged ``bn_stats``), so the replay runs no reduction over activations;
+    and the whole step has at most two reduction pairs a BatchNorm: the
+    statistics, and the backward's ``sum(dy)`` and ``sum(dy * xhat)``."""
+    _, stats, text = _residual_run(1, 4, 1)
+    n_bn = len(stats) // 2
+    assert n_bn == 8
+    # per-channel: a reduce whose result is a vector (inside fusions too)
+    of_bn = [o for o in re.findall(
+        r'= \w+\[\d+\]\S* reduce\(.*?op_name="([^"]*)"', text)
+        if ":BatchNormalization" in o]
+    assert not [o for o in of_bn if "rematted_computation" in o]
+    assert [o for o in of_bn if "transpose(" in o]
+    assert 2 * n_bn <= len(of_bn) <= 4 * n_bn, len(of_bn)
+
+
+def test_batchnorm_step_on_four_devices_equals_the_single_device_step():
+    """The batch sharded over a four-device data mesh: BatchNorm's sums
+    become all-reduces and the step is the single-device step."""
+    many, one = _residual_run(4, 64, 3), _residual_run(1, 64, 3)
+    assert "all-reduce" in many[2] and "all-reduce" not in one[2]
+    np.testing.assert_allclose(many[0], one[0], rtol=1e-6, atol=1e-6)
+    assert one[1] and set(many[1]) == set(one[1])
+    for k, v in one[1].items():
+        np.testing.assert_allclose(many[1][k], v, rtol=1e-6, atol=1e-6)
