@@ -33,6 +33,7 @@ def _dtype_of(name):
 
 
 from deeplearning4j_tpu.util.scopes import layer_scope
+from deeplearning4j_tpu.util.remat import remat_segments
 from deeplearning4j_tpu.util.dtypes import (cast_floats as _cast_floats,
                                              restore_dtypes as _restore_dtypes)
 
@@ -163,56 +164,92 @@ class ComputationGraph:
         reference's rnnTimeStep stateMap, ComputationGraph.java:2362); when
         given, recurrent layers resume from it and the updated map is
         returned (None entries mean zero initial state)."""
-        gc = self.conf.global_conf
         acts: Dict[str, Any] = {}
         new_state = dict(state)
         new_carries = dict(carries) if carries is not None else None
         cdt = self._compute_dtype(train)
-        if cdt is not None:
+        # with a block as the replay unit the float32 parameters go into
+        # each checkpoint and are cast there, so that the compute-dtype
+        # copies live only while their block runs
+        by_block = (train and carries is None
+                    and self.conf.global_conf.remat == "blocks")
+        if cdt is not None and not by_block:
             params = _cast_floats(params, cdt)
         for i, n in enumerate(self.conf.network_inputs):
             x = inputs[i]
-            if cdt is not None:
-                x = x.astype(cdt)
+            if cdt is not None and jnp.issubdtype(x.dtype, jnp.floating):
+                x = x.astype(cdt)       # token ids stay integers
             acts[n] = x
-        for idx, name in enumerate(self.conf.topological_order):
-            node = self.conf.nodes[name]
-            if node.kind == "input":
-                continue
-            ins = [acts[i] for i in node.inputs]
-            if node.kind == "vertex":
-                v = node.vertex
-                with layer_scope(name, v):
-                    if getattr(v, "mask_input", None) is not None:
-                        # mask-aware vertex (LastTimeStepVertex): the named
-                        # network input's (B, T) mask locates true last steps
-                        m = masks.get(v.mask_input) if masks else None
-                        acts[name] = v.apply(ins, mask=m)
+
+        def run(names, params, state, acts, new_state):
+            """Apply the nodes ``names`` in order; fills ``acts`` and
+            ``new_state``."""
+            for name in names:
+                idx = order_index[name]
+                node = self.conf.nodes[name]
+                if node.kind == "input":
+                    continue
+                ins = [acts[i] for i in node.inputs]
+                if node.kind == "vertex":
+                    v = node.vertex
+                    with layer_scope(name, v):
+                        if getattr(v, "mask_input", None) is not None:
+                            # mask-aware vertex (LastTimeStepVertex): the
+                            # named network input's (B, T) mask locates true
+                            # last steps
+                            m = masks.get(v.mask_input) if masks else None
+                            acts[name] = v.apply(ins, mask=m)
+                        else:
+                            acts[name] = v.apply(ins)
+                    continue
+                lrng = None if rng is None else jax.random.fold_in(rng, idx)
+                mask = None
+                if masks and node.inputs and node.inputs[0] in masks:
+                    mask = masks[node.inputs[0]]
+                p_n = params.get(name, {})
+                if by_block and cdt is not None:
+                    p_n = _cast_floats(p_n, cdt)
+                with layer_scope(name, node.layer):
+                    if (train and node.layer.weight_noise is not None
+                            and lrng is not None):
+                        p_n = node.layer.weight_noise.apply(
+                            p_n, jax.random.fold_in(lrng, 0x5eed))
+                    if (new_carries is not None
+                            and hasattr(node.layer, "apply_with_carry")):
+                        y, c = node.layer.apply_with_carry(
+                            p_n, ins[0], new_carries.get(name), mask=mask)
+                        new_carries[name] = c
                     else:
-                        acts[name] = v.apply(ins)
-                continue
-            lrng = None if rng is None else jax.random.fold_in(rng, idx)
-            mask = None
-            if masks and node.inputs and node.inputs[0] in masks:
-                mask = masks[node.inputs[0]]
-            p_n = params.get(name, {})
-            with layer_scope(name, node.layer):
-                if (train and node.layer.weight_noise is not None
-                        and lrng is not None):
-                    p_n = node.layer.weight_noise.apply(
-                        p_n, jax.random.fold_in(lrng, 0x5eed))
-                if (new_carries is not None
-                        and hasattr(node.layer, "apply_with_carry")):
-                    y, c = node.layer.apply_with_carry(
-                        p_n, ins[0], new_carries.get(name), mask=mask)
-                    new_carries[name] = c
-                else:
-                    y, st = node.layer.apply(p_n, ins[0],
-                                             state.get(name), train=train,
-                                             rng=lrng, mask=mask)
-                    if st is not None:
-                        new_state[name] = st
-            acts[name] = y
+                        y, st = node.layer.apply(p_n, ins[0],
+                                                 state.get(name), train=train,
+                                                 rng=lrng, mask=mask)
+                        if st is not None:
+                            new_state[name] = st
+                acts[name] = y
+
+        order = self.conf.topological_order
+        order_index = {n: i for i, n in enumerate(order)}
+        if not by_block:
+            run(order, params, state, acts, new_state)
+        else:
+            for names, outs in remat_segments(self.conf):
+                if outs is None:
+                    run(names, params, state, acts, new_state)
+                    continue
+                needs = [i for n in names for i in self.conf.nodes[n].inputs
+                         if i not in names]
+
+                def block(p, s, a, names=names, outs=outs):
+                    a, ns = dict(a), {}
+                    run(names, p, s, a, ns)
+                    return {o: a[o] for o in outs}, ns
+
+                got, ns = jax.checkpoint(block)(
+                    {n: params[n] for n in names if n in params},
+                    {n: state[n] for n in names if n in state},
+                    {i: acts[i] for i in needs})
+                acts.update(got)
+                new_state.update(ns)
         if cdt is not None:
             # persistent state (BN stats) keeps its storage dtype
             new_state = {
@@ -343,7 +380,8 @@ class ComputationGraph:
     # ----------------------------------------------------------- train step
     def _loss_for_grad(self):
         """jax.checkpoint-wrapped loss when remat is configured (see
-        GlobalConf.remat / MultiLayerNetwork._loss_for_grad)."""
+        GlobalConf.remat / MultiLayerNetwork._loss_for_grad); with
+        ``'blocks'`` the checkpoints are inside ``_forward``."""
         from deeplearning4j_tpu.util.remat import remat_loss
         return remat_loss(self._loss, self.conf.global_conf.remat)
 
@@ -580,7 +618,8 @@ class ComputationGraph:
         return self._epoch_batch
 
     # chunk caps — see MultiLayerNetwork._fit_stream (same design: runs of
-    # mask-free same-shape batches stack onto the device-resident scan path)
+    # mask-free same-shape batches stack onto the device-resident scan path;
+    # util/chunking.py sends a step of heavy estimated work singly)
     _CHUNK_MAX_STEPS = 64
     _CHUNK_MAX_BYTES = 256 << 20
 
@@ -613,9 +652,13 @@ class ComputationGraph:
         is bitwise-identical with prefetch on or off."""
         from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
 
+        from deeplearning4j_tpu.util.chunking import (n_parameters,
+                                                      steps_per_chunk)
+
         chunkable = (getattr(self.conf, "backprop_type", "standard")
                      != "tbptt")
         buf, shape = [], None
+        n_params = n_parameters(self.params)
 
         def flush():
             nonlocal buf, shape
@@ -679,10 +722,9 @@ class ComputationGraph:
                     yield out
             shape = key
             buf.append(batch)
-            per = (sum(np.asarray(f).nbytes for f in batch.features)
-                   + sum(np.asarray(l).nbytes for l in batch.labels))
-            if len(buf) >= max(1, min(self._CHUNK_MAX_STEPS,
-                                      self._CHUNK_MAX_BYTES // max(1, per))):
+            if len(buf) >= steps_per_chunk(
+                    batch.features, batch.labels, n_params,
+                    self._CHUNK_MAX_STEPS, self._CHUNK_MAX_BYTES):
                 yield flush()
         out = flush()
         if out is not None:
@@ -750,6 +792,8 @@ class ComputationGraph:
         timer.steps = self.iteration - it0
         self.last_pipeline_stats = timer.summary()
         timer.publish("fit")
+        self._mon.publish_expert_counters(
+            {n: self.conf.nodes[n].layer for n in self.state}, self.state)
 
     def _fit_batch(self, mds):
         inputs = [jnp.asarray(f) for f in mds.features]

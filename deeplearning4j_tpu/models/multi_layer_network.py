@@ -45,6 +45,13 @@ class MultiLayerNetwork:
 
     def __init__(self, conf: MultiLayerConfiguration):
         conf.finalize()
+        if conf.global_conf.remat == "blocks":
+            # util/remat.py: blocks are runs of graph nodes named
+            # '<block>.<node>'; here the mode would do nothing, silently
+            raise ValueError(
+                "remat='blocks' replays blocks of a ComputationGraph whose "
+                "nodes are named '<block>.<node>'; a list of layers has "
+                "none (use True, 'full' or 'save_convs')")
         self.conf = conf
         self.layers = conf.layers
         self.params: Optional[List[Dict]] = None
@@ -168,7 +175,8 @@ class MultiLayerNetwork:
         gc = self.conf.global_conf
         cdt = self._compute_dtype(train)
         if cdt is not None:
-            x = x.astype(cdt)
+            if jnp.issubdtype(x.dtype, jnp.floating):
+                x = x.astype(cdt)       # token ids stay integers
             params = _cast_floats(params, cdt)
         n = len(self.layers) if upto is None else upto
         new_states = list(state)
@@ -617,9 +625,13 @@ class MultiLayerNetwork:
     _CHUNK_MAX_BYTES = 256 << 20
 
     def _chunk_len(self, ds):
-        per = ds.features.nbytes + ds.labels.nbytes
-        return max(1, min(self._CHUNK_MAX_STEPS,
-                          self._CHUNK_MAX_BYTES // max(1, per)))
+        """util/chunking.py: bounded by steps and by staged bytes; one where
+        the step's estimated work is heavy."""
+        from deeplearning4j_tpu.util.chunking import (n_parameters,
+                                                      steps_per_chunk)
+        return steps_per_chunk([ds.features], [ds.labels],
+                               n_parameters(self.params),
+                               self._CHUNK_MAX_STEPS, self._CHUNK_MAX_BYTES)
 
     # device-resident prefetch depth for the streamed fit/eval path: work
     # items are device_put this many batches ahead of consumption so the
@@ -763,6 +775,8 @@ class MultiLayerNetwork:
         timer.steps = self.iteration - it0
         self.last_pipeline_stats = timer.summary()
         timer.publish("fit")
+        self._mon.publish_expert_counters(
+            {i: l for i, l in enumerate(self.layers)}, self.state)
 
     def _stream_placement(self, item):
         """Where the step wants a ``_stream_chunks`` item, so that the

@@ -25,6 +25,8 @@ class TrainMonitor:
     def __init__(self, model_kind: str):
         reg = get_registry()
         lab = {"model": model_kind}
+        self._kind = model_kind
+        self._moe = None              # dl4jtpu_moe_* families, on first use
         self.steps = reg.counter(
             "dl4jtpu_train_steps_total",
             "Train steps executed (fit_scan counts every scanned step).",
@@ -67,3 +69,47 @@ class TrainMonitor:
             self.compile_seconds.inc(seconds)
         else:
             self._hist[path].observe(seconds)
+
+    def publish_expert_counters(self, layers, state) -> None:
+        """At the end of a streamed fit call: what the expert layers counted
+        inside the steps (their state, which the step returns), as
+        ``dl4jtpu_moe_*`` labelled by layer. ``layers``: key -> layer,
+        ``state``: the container's state under the same keys. One host read
+        of four scalars a layer; a model without expert layers reads
+        nothing."""
+        keys = [k for k in layers if state[k] and "pairs_total" in state[k]]
+        if not keys:
+            return
+        import jax
+        if self._moe is None:
+            reg = get_registry()
+            lab = ("model", "layer")
+            self._moe = {
+                "pairs_total": reg.counter(
+                    "dl4jtpu_moe_pairs_total",
+                    "(token, expert) pairs routed to experts the layer "
+                    "holds, over training steps.", lab),
+                "pairs_dropped_total": reg.counter(
+                    "dl4jtpu_moe_pairs_dropped_total",
+                    "Such pairs that no round computed, counted by the "
+                    "rounds that ran (a dropless layer keeps this at 0).",
+                    lab),
+                "load_max": reg.gauge(
+                    "dl4jtpu_moe_expert_load_max",
+                    "Pairs on the fullest held expert in the last step.",
+                    lab),
+                "load_mean": reg.gauge(
+                    "dl4jtpu_moe_expert_load_mean",
+                    "Pairs per held expert in the last step.", lab)}
+            self._moe_seen = {}
+        got = jax.device_get({k: state[k] for k in keys})
+        for k in keys:
+            lab = {"model": self._kind, "layer": str(layers[k].name or k)}
+            for name in ("pairs_total", "pairs_dropped_total"):
+                now = int(got[k][name]) & 0xFFFFFFFF
+                last = self._moe_seen.get((k, name), 0)
+                self._moe[name].labels(**lab).inc((now - last) & 0xFFFFFFFF)
+                self._moe_seen[(k, name)] = now
+            self._moe["load_max"].labels(**lab).set(int(got[k]["load_max"]))
+            self._moe["load_mean"].labels(**lab).set(
+                int(got[k]["pairs"]) / layers[k].held[0])

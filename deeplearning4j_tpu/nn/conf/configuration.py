@@ -63,7 +63,9 @@ class GlobalConf:
     # 224 where conv recompute costs real FLOPs (round-5 ablation, before
     # PR 1; not measured since) — the role cudnn workspace tuning plays in
     # the reference's helper seam
-    remat: object = False   # False | True | 'full' | 'save_convs' | 'selective'
+    # 'blocks' (graphs): one jax.checkpoint around each run of nodes named
+    # ``<block>.<node>``; only the blocks' inputs are kept (util/remat.py)
+    remat: object = False   # False|True|'full'|'save_convs'|'selective'|'blocks'
     weight_noise: Optional[object] = None  # IWeightNoise (DropConnect/...)
 
     def defaults_dict(self):

@@ -49,6 +49,13 @@ import optax
 
 _OVERRIDE: Optional[bool] = None
 
+# A member this large is updated by itself. Fusing exists to spare the
+# launches of hundreds of tiny elementwise ops; a layer of 8M parameters is
+# no tiny op, and raveling it into the group's flat vector copies its
+# parameters, gradients and optimizer moments once more (a decoder's 100M
+# layers would not fit beside themselves). The math is the same either way.
+FUSE_MAX_MEMBER_SIZE = 1 << 23
+
 
 def fused_update_enabled() -> bool:
     """Fused updates are on by default; ``DL4JTPU_FUSED_UPDATE=0`` (env)
@@ -205,8 +212,9 @@ def build_fused_update(params: Dict, transforms: Dict,
     keys. ``group_keys[k]`` is any hashable describing the updater config
     (the containers use the updater's sorted-JSON dict) — members fuse
     only when BOTH the key and every param leaf's dtype match. ``None``
-    marks a member non-fusable (frozen layers, cross-leaf clipping);
-    empty param trees pass through untouched.
+    marks a member non-fusable (frozen layers, cross-leaf clipping), as
+    does a size of ``FUSE_MAX_MEMBER_SIZE`` parameters or more; empty param
+    trees pass through untouched.
     """
     constraints = constraints or {}
     groups: Dict[Tuple, _Group] = {}
@@ -219,7 +227,8 @@ def build_fused_update(params: Dict, transforms: Dict,
             continue
         gk = group_keys.get(k)
         dtypes = {l.dtype for l in leaves}
-        if gk is None or len(dtypes) != 1:
+        if (gk is None or len(dtypes) != 1
+                or sum(l.size for l in leaves) >= FUSE_MAX_MEMBER_SIZE):
             fallback.append(k)
             continue
         bucket = (gk, next(iter(dtypes)))

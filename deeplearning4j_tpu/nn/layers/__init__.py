@@ -30,6 +30,9 @@ from deeplearning4j_tpu.nn.layers.special import (
 from deeplearning4j_tpu.nn.layers.attention import (
     MultiHeadAttention, LayerNormalization, PositionalEmbedding,
 )
+from deeplearning4j_tpu.nn.layers.decoder import (
+    RMSNorm, SwiGLU, RotaryGQAttention, ExpertLayer,
+)
 from deeplearning4j_tpu.nn.layers.pretrain import RBM
 
 __all__ = [
@@ -47,4 +50,5 @@ __all__ = [
     "GlobalPoolingLayer", "AutoEncoder", "VariationalAutoencoder",
     "CenterLossOutputLayer", "Yolo2OutputLayer", "FrozenLayer",
     "MultiHeadAttention", "LayerNormalization", "PositionalEmbedding", "RBM",
+    "RMSNorm", "SwiGLU", "RotaryGQAttention", "ExpertLayer",
 ]

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, require_dims
 from deeplearning4j_tpu.nn.activations import get_activation
-from deeplearning4j_tpu.nn.losses import get_loss
+from deeplearning4j_tpu.nn.losses import (get_loss, is_class_ids,
+                                          sparse_mcxent_from_features)
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 
@@ -71,6 +72,18 @@ class OutputLayer(DenseLayer):
         x = self.maybe_dropout(x, train=train, rng=rng)
         if x.ndim >= 4 or (x.ndim == 3 and x.shape[-1] != self.n_in):
             x = x.reshape(x.shape[0], -1)
+        if is_class_ids(labels):
+            # integer labels are class ids, (B,) or (B, T): never one-hot
+            if (str(self.loss).lower() not in ("mcxent",
+                                               "negativeloglikelihood")
+                    or str(self.activation or "softmax").lower() != "softmax"):
+                raise ValueError(
+                    "integer labels need loss 'mcxent' over a softmax; got "
+                    f"loss {self.loss!r}, activation {self.activation!r}")
+            return sparse_mcxent_from_features(
+                labels.reshape(-1), x.reshape(-1, x.shape[-1]), params["W"],
+                params["b"] if self.has_bias else None,
+                None if mask is None else mask.reshape(-1))
         pre = x @ params["W"]
         if self.has_bias:
             pre = pre + params["b"]

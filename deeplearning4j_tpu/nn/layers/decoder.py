@@ -1,0 +1,505 @@
+"""Layers of a sparse decoder block: RMSNorm, rotary grouped-query
+attention with a window and a head gate, a SwiGLU MLP, and a dropless
+top-k expert layer that holds a share of the experts.
+
+The reference (DL4J 0.9.2) has none of them. Each is a plain ``Layer``: the
+containers hold it, ``model_serializer`` writes it, ``util/scopes.py`` names
+it. Sequences are (B, T, C) as everywhere in ``nn/layers``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from dataclasses import dataclass
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, require_dims
+from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+
+
+def _seq_n_in(input_type):
+    return input_type.size or input_type.flat_size()
+
+
+def _w(layer, rng, shape, dtype):
+    return init_weights(rng, shape, layer.weight_init or "xavier",
+                        layer.dist, dtype)
+
+
+# ------------------------------------------------------------------ RMSNorm
+
+def rms_norm(x, gamma, eps):
+    """x / sqrt(mean(x^2) + eps) * gamma, the statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclass
+class RMSNorm(Layer):
+    """Root-mean-square norm over the feature axis: one gain, no mean, no
+    bias."""
+    n_in: int = 0
+    eps: float = 1e-6
+
+    def set_n_in(self, input_type):
+        if self.n_in == 0:
+            self.n_in = _seq_n_in(input_type)
+
+    def init(self, rng, dtype=jnp.float32):
+        require_dims(self, n_in=self.n_in)
+        return {"gamma": jnp.ones((self.n_in,), dtype)}
+
+    def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["gamma"], self.eps), state
+
+
+# ------------------------------------------------------------------- SwiGLU
+
+def swiglu(x, wg, wu, wd):
+    """(silu(x Wg) * (x Wu)) Wd. The two products come out in x's dtype (a
+    float32 copy of a (tokens, width) activation is the step's largest
+    buffer); the gate itself is taken in float32."""
+    g, u = jnp.dot(x, wg), jnp.dot(x, wu)
+    h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    return jnp.dot(h.astype(x.dtype), wd)
+
+
+@register_layer
+@dataclass
+class SwiGLU(Layer):
+    """Gated MLP without biases. Param keys: Wg, Wu (n_in, width) and Wd
+    (width, n_out)."""
+    n_in: int = 0
+    n_out: int = 0          # defaults to n_in
+    width: int = 0
+
+    def set_n_in(self, input_type):
+        if self.n_in == 0:
+            self.n_in = _seq_n_in(input_type)
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+    def output_type(self, input_type):
+        return InputType.recurrent(self.n_out or self.n_in,
+                                   input_type.timeseries_length)
+
+    def init(self, rng, dtype=jnp.float32):
+        self.n_out = self.n_out or self.n_in
+        require_dims(self, n_in=self.n_in, width=self.width)
+        k = jax.random.split(rng, 3)
+        return {"Wg": _w(self, k[0], (self.n_in, self.width), dtype),
+                "Wu": _w(self, k[1], (self.n_in, self.width), dtype),
+                "Wd": _w(self, k[2], (self.width, self.n_out), dtype)}
+
+    def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
+        return swiglu(x, params["Wg"], params["Wu"], params["Wd"]), state
+
+
+# ---------------------------------------------------------------- attention
+
+def rotary_inv_freq(rotary):
+    """Inverse frequencies of the rotated pairs, a Python list. ``rotary``:
+    ``theta``, ``dims`` (how many leading dims of a head rotate) and, for
+    YaRN, ``factor``, ``original_max_position``, ``beta_fast``,
+    ``beta_slow`` (arXiv:2309.00071: frequencies that turn fewer than
+    beta_slow times over the original context are divided by ``factor``,
+    those that turn more than beta_fast times are kept, a linear ramp
+    between)."""
+    dims, theta = int(rotary["dims"]), float(rotary["theta"])
+    half = dims // 2
+    freq = [theta ** (-2.0 * i / dims) for i in range(half)]
+    factor = rotary.get("factor")
+    if not factor:
+        return freq
+    orig = float(rotary["original_max_position"])
+
+    def correction_dim(turns):
+        return dims * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(float(rotary["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(rotary["beta_slow"]))),
+               dims - 1)
+    if low == high:
+        high += 0.001
+    out = []
+    for i, f in enumerate(freq):
+        keep = 1.0 - min(max((i - low) / (high - low), 0.0), 1.0)
+        out.append(f / factor * (1.0 - keep) + f * keep)
+    return out
+
+
+def apply_rotary(x, rotary):
+    """Rotate the first ``rotary['dims']`` dims of every head in halves
+    (x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin); the rest pass through.
+    x: (B, H, T, Dh). ``attention_factor`` multiplies cos and sin."""
+    t, dims = x.shape[2], int(rotary["dims"])
+    half = dims // 2
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(rotary_inv_freq(rotary), jnp.float32)
+    scale = float(rotary.get("attention_factor") or 1.0)
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:dims]
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x32[..., dims:]], axis=-1)
+    return out.astype(x.dtype)
+
+
+def banded_attention(q, k, v, window=None):
+    """The plain path: causal grouped-query attention by a masked softmax
+    over the whole (T, T) score matrix. Shapes as ``gqa_flash_attention``."""
+    b, hq, t, dh = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, t, dh)
+    s = jnp.einsum("bkgqd,bksd->bkgqs", qg, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(dh)
+    i = jnp.arange(t)[:, None]
+    j = jnp.arange(t)[None, :]
+    ok = i >= j
+    if window is not None:
+        ok = ok & (i - j < window)
+    p = jax.nn.softmax(jnp.where(ok, s, -jnp.inf), axis=-1).astype(v.dtype)
+    return jnp.einsum("bkgqs,bksd->bkgqd", p, v).reshape(b, hq, t, dh)
+
+
+# below this length the score matrix is small and XLA's fused path is used
+_KERNEL_MIN_SEQ = 1024
+
+
+@register_layer
+@dataclass
+class RotaryGQAttention(Layer):
+    """Causal self-attention over (B, T, C) with ``n_heads`` query heads
+    reading ``n_kv_heads`` key/value heads (query head h reads kv head
+    ``h // (n_heads / n_kv_heads)``), rotary positions on q and k, an
+    optional ``window`` (key j is seen from query i only if i - j < window)
+    and an optional sigmoid gate per head on the attention output
+    (arXiv:2505.06708). No biases. Param keys: Wq (n_in, H*Dh), Wk, Wv
+    (n_in, Hkv*Dh), Wo (H*Dh, n_out), Wgate (n_in, H) with ``head_gate``.
+
+    ``rotary``: a dict as ``rotary_inv_freq`` reads it, or None.
+    The head count is the layer's own: layers of one model may differ."""
+    n_in: int = 0
+    n_out: int = 0          # model dim (defaults to n_in)
+    n_heads: int = 4
+    n_kv_heads: int = 1
+    head_dim: int = 0
+    window: Optional[int] = None
+    rotary: Optional[dict] = None
+    head_gate: bool = False
+
+    def set_n_in(self, input_type):
+        if self.n_in == 0:
+            self.n_in = _seq_n_in(input_type)
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+    def output_type(self, input_type):
+        return InputType.recurrent(self.n_out or self.n_in,
+                                   input_type.timeseries_length)
+
+    def init(self, rng, dtype=jnp.float32):
+        self.n_out = self.n_out or self.n_in
+        require_dims(self, n_in=self.n_in, head_dim=self.head_dim)
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_heads={self.n_heads} is not a multiple of "
+                             f"n_kv_heads={self.n_kv_heads}")
+        if self.rotary and int(self.rotary["dims"]) > self.head_dim:
+            raise ValueError("rotary dims exceed head_dim")
+        k = jax.random.split(rng, 5)
+        hq, hkv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        p = {"Wq": _w(self, k[0], (self.n_in, hq), dtype),
+             "Wk": _w(self, k[1], (self.n_in, hkv), dtype),
+             "Wv": _w(self, k[2], (self.n_in, hkv), dtype),
+             "Wo": _w(self, k[3], (hq, self.n_out), dtype)}
+        if self.head_gate:
+            p["Wgate"] = _w(self, k[4], (self.n_in, self.n_heads), dtype)
+        return p
+
+    def _attend(self, q, k, v):
+        from deeplearning4j_tpu import ops
+        from deeplearning4j_tpu.exec.executor import tracing_partitioned
+        from deeplearning4j_tpu.ops.flash_attention import (
+            gqa_flash_attention, gqa_supported)
+        t = q.shape[2]
+        if (ops.helpers_enabled() and not tracing_partitioned()
+                and gqa_supported(t, self.head_dim, self.n_heads,
+                                  self.n_kv_heads)
+                and (ops.interpret_mode() or t >= _KERNEL_MIN_SEQ)):
+            return gqa_flash_attention(q, k, v, self.window, None,
+                                       ops.interpret_mode())
+        return banded_attention(q, k, v, self.window)
+
+    def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
+        if mask is not None:
+            raise ValueError("RotaryGQAttention takes no padding mask: pack "
+                             "sequences to full length")
+        b, t, _ = x.shape
+
+        def heads(w, n):
+            return (x @ w).reshape(b, t, n, self.head_dim).transpose(0, 2, 1, 3)
+
+        q = heads(params["Wq"], self.n_heads)
+        k = heads(params["Wk"], self.n_kv_heads)
+        v = heads(params["Wv"], self.n_kv_heads)
+        if self.rotary:
+            q, k = apply_rotary(q, self.rotary), apply_rotary(k, self.rotary)
+        with jax.named_scope("attend"):
+            o = self._attend(q, k, v).transpose(0, 2, 1, 3)  # (B, T, H, Dh)
+        if self.head_gate:
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, params["Wgate"], preferred_element_type=jnp.float32))
+            o = o * gate[..., None].astype(o.dtype)
+        return o.reshape(b, t, -1) @ params["Wo"], state
+
+    def init_decode_state(self, params, batch, max_len, dtype=jnp.float32):
+        raise NotImplementedError(
+            "RotaryGQAttention trains through fit(); decoding it needs a "
+            "cache that keeps a window for some layers and every position "
+            "for others (ROADMAP, Reach)")
+
+
+# ------------------------------------------------------------ expert layer
+
+# the sorted-pair buffer of one round holds this many times the pairs an
+# even routing would send to the experts held
+_ROUND_SLACK = 1.5
+
+
+def route_top_k(x2, wr, k, norm_topk, routed_scale):
+    """Softmax over all experts, the k largest, their weights. x2: (N, C).
+    Returns (idx (N, k) int32, p (N, k) float32)."""
+    s = jax.nn.softmax(
+        jnp.dot(x2, wr, preferred_element_type=jnp.float32), axis=-1)
+    val, idx = jax.lax.top_k(s, k)
+    if norm_topk:
+        val = val / val.sum(axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), val * routed_scale
+
+
+def _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts, ends):
+    """Round ``r`` of the routed part: rows ``r*rows .. (r+1)*rows`` of the
+    pairs sorted by expert go through the three grouped products and are
+    added to their tokens, weighted. x2: (N, C); eg, eu: (E, C, W); ed:
+    (E, W, C); wgt, tok: weight and token of every sorted row (token N for
+    a row past the last pair); starts, ends: each expert's rows. Returns
+    ((N, C) float32, the pairs this round computed: rows inside an expert's
+    group that carry a token, int32)."""
+    n, c = x2.shape
+    lo = r * rows
+    with jax.named_scope("dispatch"):
+        t_r = jax.lax.dynamic_slice(tok, (lo,), (rows,))
+        w_r = jax.lax.dynamic_slice(wgt, (lo,), (rows,))
+        sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+        # a row past the last pair points past the tokens: the gather fills
+        # it with zeros and the scatter drops it
+        xs = jnp.take(x2, t_r, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("experts"):
+        def gmm(a, w, out=None):
+            return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=out)
+        g, u = gmm(xs, eg), gmm(xs, eu)
+        h = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(x2.dtype)
+        ys = gmm(h, ed, jnp.float32)
+    with jax.named_scope("combine"):
+        ys = jnp.where((t_r < n)[:, None], ys * w_r[:, None], 0.0)
+        done = ((jnp.arange(rows) < sizes.sum()) & (t_r < n)).sum(
+            dtype=jnp.int32)
+        return jnp.zeros((n, c), jnp.float32).at[t_r].add(ys, mode="drop"), \
+            done
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _later_rounds(rows, x2, eg, eu, ed, wgt, tok, starts, ends, total):
+    """Rounds 1.. of the routed part, as many as the pairs left need: none
+    under a routing that fits the first round. The trip count is the
+    data's, so both passes are loops of their own: nothing is kept per
+    round, and the backward pass runs each round again for its gradient.
+    Returns (y, the pairs these rounds computed)."""
+    return _later_fwd(rows, x2, eg, eu, ed, wgt, tok, starts, ends, total)[0]
+
+
+def _later_fwd(rows, x2, eg, eu, ed, wgt, tok, starts, ends, total):
+    def body(r, acc):
+        y, done = _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts,
+                                ends)
+        return acc[0] + y, acc[1] + done
+
+    out = jax.lax.fori_loop(
+        1, (total + rows - 1) // rows, body,
+        (jnp.zeros(x2.shape, jnp.float32), jnp.zeros((), jnp.int32)))
+    return out, (x2, eg, eu, ed, wgt, tok, starts, ends, total)
+
+
+def _later_bwd(rows, res, ct):
+    x2, eg, eu, ed, wgt, tok, starts, ends, total = res
+    diff = (x2, eg, eu, ed, wgt)
+    dy = ct[0]                     # the count is an integer: no cotangent
+
+    def body(r, acc):
+        _, vjp = jax.vjp(
+            lambda *d: _expert_round(rows, r, *d, tok, starts, ends)[0],
+            *diff)
+        return jax.tree_util.tree_map(
+            lambda a, g: a + g.astype(jnp.float32), acc, vjp(dy))
+
+    acc = jax.lax.fori_loop(
+        1, (total + rows - 1) // rows, body,
+        tuple(jnp.zeros(d.shape, jnp.float32) for d in diff))
+    return tuple(a.astype(d.dtype) for a, d in zip(acc, diff)) \
+        + (None, None, None, None)
+
+
+_later_rounds.defvjp(_later_fwd, _later_bwd)
+
+
+@register_layer
+@dataclass
+class ExpertLayer(Layer):
+    """Top-k mixture of SwiGLU experts plus an ungated shared expert, for a
+    chip that holds a share of the experts.
+
+    The router scores all ``n_experts`` and picks ``experts_per_token``;
+    this layer holds ``experts_held = (count, first)``: experts
+    ``first .. first+count-1`` (None: all of them). It computes, for every
+    (token, expert) pair that falls on an expert it holds, that expert's
+    output times the pair's weight, and leaves out what absent experts
+    would add; the shared expert is added whole. No capacity: the pairs
+    are sorted by expert and go through one grouped matrix product per
+    projection (``jax.lax.ragged_dot``) in rounds of a fixed buffer; the
+    first round holds ``_ROUND_SLACK`` times an even routing's pairs, and
+    further rounds run only when the routing is so uneven that pairs are
+    left, so nothing is dropped.
+
+    Param keys: Wr (n_in, n_experts); Eg, Eu (count, n_in, expert_width),
+    Ed (count, expert_width, n_in); Sg, Su, Sd the shared expert.
+    State (updated on training steps, read at the fit loop's boundary):
+    ``pairs_total`` and ``pairs_dropped_total`` (int32, wrapping; dropped
+    is the pairs routed here minus the pairs the rounds that ran counted as
+    computed), ``pairs`` and ``load_max`` of the last step."""
+    n_in: int = 0
+    n_experts: int = 8
+    experts_per_token: int = 2
+    expert_width: int = 0
+    shared_width: int = 0
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    experts_held: Optional[tuple] = None     # (count, first index)
+
+    def set_n_in(self, input_type):
+        if self.n_in == 0:
+            self.n_in = _seq_n_in(input_type)
+
+    def output_type(self, input_type):
+        return InputType.recurrent(self.n_in, input_type.timeseries_length)
+
+    @property
+    def held(self):
+        """(count, first) of the experts this layer holds."""
+        return tuple(self.experts_held) if self.experts_held \
+            else (self.n_experts, 0)
+
+    def init(self, rng, dtype=jnp.float32):
+        require_dims(self, n_in=self.n_in, expert_width=self.expert_width)
+        count, first = self.held
+        if not (0 <= first and first + count <= self.n_experts and count > 0):
+            raise ValueError(f"experts_held={self.experts_held} lies outside "
+                             f"0..{self.n_experts}")
+        k = jax.random.split(rng, 7)
+        c, w = self.n_in, self.expert_width
+
+        def stack(key, shape):
+            return jnp.stack([_w(self, kk, shape, dtype)
+                              for kk in jax.random.split(key, count)])
+
+        p = {"Wr": _w(self, k[0], (c, self.n_experts), dtype),
+             "Eg": stack(k[1], (c, w)), "Eu": stack(k[2], (c, w)),
+             "Ed": stack(k[3], (w, c))}
+        if self.shared_width:
+            p["Sg"] = _w(self, k[4], (c, self.shared_width), dtype)
+            p["Su"] = _w(self, k[5], (c, self.shared_width), dtype)
+            p["Sd"] = _w(self, k[6], (self.shared_width, c), dtype)
+        return p
+
+    def init_state(self, dtype=jnp.float32):
+        # one buffer each: the step donates its state
+        return {k: jnp.zeros((), jnp.int32) for k in
+                ("pairs_total", "pairs_dropped_total", "pairs", "load_max")}
+
+    # -- the routed part ---------------------------------------------------
+    def round_rows(self, n_tokens):
+        """Rows of one round's buffer, and how many rounds hold the worst
+        routing (every token on as many held experts as it can pick)."""
+        count = self.held[0]
+        worst = n_tokens * min(self.experts_per_token, count)
+        even = n_tokens * self.experts_per_token * count / self.n_experts
+        rows = min(worst, -(-int(math.ceil(_ROUND_SLACK * even)) // 8) * 8)
+        return rows, -(-worst // rows)
+
+    def routed(self, params, x2, first=None):
+        """Sum over the held experts of weight * expert(x) for the pairs
+        routed to them. x2: (N, C). ``first``: index of the first expert
+        held (a traced value under ``shard_map``; None: the layer's own).
+        Returns (y (N, C) float32, counters dict)."""
+        n, c = x2.shape
+        count = self.held[0]
+        first = self.held[1] if first is None else first
+        k = self.experts_per_token
+        rows, rounds = self.round_rows(n)
+        with jax.named_scope("route"):
+            idx, p = route_top_k(x2, params["Wr"], k, self.norm_topk,
+                                 self.routed_scale)
+        with jax.named_scope("dispatch"):
+            local = (idx - first).reshape(-1)
+            key = jnp.where((local >= 0) & (local < count), local, count)
+            order = jnp.argsort(key, stable=True)
+            counts = (key[:, None] == jnp.arange(count)[None, :]).sum(
+                axis=0, dtype=jnp.int32)
+            ends = jnp.cumsum(counts)
+            starts, total = ends - counts, ends[-1]
+            pad = rounds * rows - n * k
+            rank = jnp.arange(rounds * rows, dtype=jnp.int32)
+            if pad > 0:
+                order = jnp.concatenate([order, jnp.zeros((pad,), order.dtype)])
+            order = order[:rounds * rows]
+            # a row past the last held pair points past the tokens: the
+            # gather fills it with zeros and the scatter drops it
+            tok = jnp.where(rank < total, (order // k).astype(jnp.int32), n)
+            wgt = jnp.where(rank < total, p.reshape(-1)[order], 0.0)
+
+        args = (x2, params["Eg"], params["Eu"], params["Ed"], wgt, tok,
+                starts, ends)
+        y, done = _expert_round(rows, 0, *args)
+        if rounds > 1:
+            later, more = _later_rounds(rows, *args, total)
+            y, done = y + later, done + more
+        # ``done`` is counted by the rounds that ran, so a round left out
+        # reads as pairs dropped
+        return y, {"pairs": total, "pairs_dropped": total - done,
+                   "load_max": counts.max()}
+
+    def shared(self, params, x2):
+        with jax.named_scope("shared"):
+            return swiglu(x2, params["Sg"], params["Su"], params["Sd"])
+
+    def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
+        b, t, c = x.shape
+        x2 = x.reshape(b * t, c)
+        y, seen = self.routed(params, x2)
+        if self.shared_width:
+            y = y + self.shared(params, x2)
+        if train and state:
+            state = {
+                "pairs_total": state["pairs_total"] + seen["pairs"],
+                "pairs_dropped_total": (state["pairs_dropped_total"]
+                                        + seen["pairs_dropped"]),
+                "pairs": seen["pairs"], "load_max": seen["load_max"]}
+        return y.astype(x.dtype).reshape(b, t, c), state

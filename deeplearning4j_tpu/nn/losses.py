@@ -83,6 +83,53 @@ def mcxent(labels, preout, activation="softmax", mask=None):
     return _reduce(per, mask)
 
 
+def sparse_mcxent(labels, preout, mask=None):
+    """Multi-class cross entropy over integer labels: ``labels`` (N,) class
+    ids, ``preout`` (N, C) logits; the same number ``mcxent`` gives for the
+    one-hot form of the labels, without the (N, C) label array. The log
+    softmax is taken in float32."""
+    per, _ = _apply_mask(_neg_logp_at(preout, labels), mask)
+    return _reduce(per, mask)
+
+
+def _neg_logp_at(preout, labels):
+    """-log softmax(preout)[label] per row, (N, 1), taken in float32."""
+    logp = jax.nn.log_softmax(preout.astype(jnp.float32), axis=-1)
+    return -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32),
+                                axis=1)
+
+
+# logits of rows x classes beyond this many elements are never held whole
+_CHUNKED_LOGITS = 1 << 26
+_LOGIT_ROWS = 2048
+
+
+def sparse_mcxent_from_features(labels, x, w, b=None, mask=None):
+    """``sparse_mcxent(labels, x @ w + b, mask)``; where the (N, C) logits
+    would be large (a language model's head) they are made, reduced and
+    dropped ``_LOGIT_ROWS`` rows at a time, each chunk a ``jax.checkpoint``
+    of its own, so that neither pass holds them whole."""
+    n, c = x.shape[0], w.shape[-1]
+
+    def logits(xc):
+        z = xc @ w
+        return z if b is None else z + b
+
+    if n * c < _CHUNKED_LOGITS or n % _LOGIT_ROWS:
+        return sparse_mcxent(labels, logits(x), mask)
+
+    rows = jax.checkpoint(lambda args: _neg_logp_at(logits(args[0]), args[1]))
+    per = jax.lax.map(rows, (x.reshape(-1, _LOGIT_ROWS, x.shape[-1]),
+                             labels.reshape(-1, _LOGIT_ROWS))).reshape(n, 1)
+    per, _ = _apply_mask(per, mask)
+    return _reduce(per, mask)
+
+
+def is_class_ids(labels) -> bool:
+    """Integer labels are class ids; floating ones a distribution."""
+    return jnp.issubdtype(labels.dtype, jnp.integer)
+
+
 def negativeloglikelihood(labels, preout, activation="softmax", mask=None):
     return mcxent(labels, preout, activation, mask)
 

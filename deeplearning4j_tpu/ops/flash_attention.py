@@ -234,3 +234,267 @@ def _fa_bwd(causal, interpret, res, do):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# ===================================================== grouped-query, banded
+# Causal attention for decoder training: K and V are read by kv head (a
+# query head h reads kv head h // group, no copy repeated in memory), keys
+# are streamed block by block over the grid with the running max, sum and
+# accumulator in VMEM scratch, operands stay in their own dtype (bfloat16 on
+# the training path) with float32 accumulation, and with a ``window`` only
+# the key blocks inside the band are visited: the grid's last axis is as long
+# as the band, not the sequence.
+
+def _band_blocks(t, window, rows, cols):
+    """How many blocks of ``cols`` a block of ``rows`` can need: the whole
+    sequence without a window, else the band's width."""
+    if window is None:
+        return t // cols
+    return min(t // cols, (rows + window - 2) // cols + 2)
+
+
+def _kv_range(iq, bq, bk, window):
+    """First and last key block a query block needs."""
+    hi = ((iq + 1) * bq - 1) // bk
+    if window is None:
+        return 0, hi
+    return jnp.maximum(iq * bq - window + 1, 0) // bk, hi
+
+
+def _q_range(jk, bq, bk, window, nq):
+    """First and last query block that sees a key block."""
+    lo = (jk * bk) // bq
+    if window is None:
+        return lo, nq - 1
+    return lo, jnp.minimum(((jk + 1) * bk + window - 2) // bq, nq - 1)
+
+
+def _visible(qpos, kpos, window):
+    ok = qpos >= kpos
+    if window is not None:
+        ok = jnp.logical_and(ok, qpos - kpos < window)
+    return ok
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
+                    bq, bk, window, scale):
+    iq, j = pl.program_id(2), pl.program_id(3)
+    lo, hi = _kv_range(iq, bq, bk, window)
+    kb = lo + j
+
+    @pl.when(j == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(kb <= hi)
+    def _():
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        s = jnp.where(_visible(qpos, kpos, window), s, _NEG)
+        m = m_s[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_s[...] = l_s[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_s[...] = m_new
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        o_ref[...] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+        lse_ref[...] = m_s[...] + jnp.log(l_s[...])
+
+
+def _gqa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dq_s, *, bq, bk, window, scale):
+    iq, j = pl.program_id(2), pl.program_id(3)
+    lo, hi = _kv_range(iq, bq, bk, window)
+    kb = lo + j
+
+    @pl.when(j == 0)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    @pl.when(kb <= hi)
+    def _():
+        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        p = jnp.where(_visible(qpos, kpos, window),
+                      jnp.exp(s - lse_ref[...]), 0.0)
+        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[...]) * scale).astype(k.dtype)
+        dq_s[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        dq_ref[...] = dq_s[...].astype(dq_ref.dtype)
+
+
+def _gqa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_s, dv_s, *, bq, bk, window, scale, nq):
+    jk, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    lo, hi = _q_range(jk, bq, bk, window, nq)
+    qb = lo + i
+
+    @pl.when(jnp.logical_and(g == 0, i == 0))
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(qb <= hi)
+    def _():
+        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        qpos = qb * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = jk * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        p = jnp.where(_visible(qpos, kpos, window),
+                      jnp.exp(s - lse_ref[...]), 0.0)
+        dv_s[...] += lax.dot_general(p.astype(do.dtype), do,
+                                     (((0,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[...]) * scale).astype(q.dtype)
+        dk_s[...] += lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(g == pl.num_programs(3) - 1,
+                             i == pl.num_programs(4) - 1))
+    def _():
+        dk_ref[...] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
+
+
+def gqa_supported(t, dh, n_heads, n_kv_heads):
+    """Shape screen of ``gqa_flash_attention``."""
+    return (_pick_gqa_block(t) is not None and dh % 8 == 0 and dh <= 256
+            and n_heads % n_kv_heads == 0)
+
+
+def _pick_gqa_block(t):
+    for b in (512, 256, 128, 64, 32, 16, 8):
+        if t % b == 0:
+            return b
+    return None
+
+
+def _gqa_call(kernel, grid, in_specs, out_specs, out_shape, scratch, args,
+              interpret):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3
+            + ("arbitrary",) * (len(grid) - 3)),
+        interpret=interpret,
+    )(*args)
+
+
+def _gqa_specs(bq, bk, dh, group, window):
+    """Block specs of the kernels whose grid is (B, Hq, query block, key
+    step): a query block, the key/value block of the head's kv head at that
+    step of the band (held at the last one needed, so that a skipped step
+    fetches nothing), and a per-row vector."""
+    def kv_index(b_, h, i, j):
+        lo, hi = _kv_range(i, bq, bk, window)
+        return b_, h // group, jnp.minimum(lo + j, hi), 0
+
+    def q_index(b_, h, i, j):
+        return b_, h, i, 0
+
+    return (pl.BlockSpec((None, None, bq, dh), q_index),
+            pl.BlockSpec((None, None, bk, dh), kv_index),
+            pl.BlockSpec((None, None, bq, 1), q_index))
+
+
+def _gqa_fwd_call(q, k, v, window, block, interpret):
+    b, hq, t, dh = q.shape
+    group = hq // k.shape[1]
+    bq = bk = block or _pick_gqa_block(t)
+    scale = 1.0 / (dh ** 0.5)
+
+    qspec, kvspec, vec = _gqa_specs(bq, bk, dh, group, window)
+    return _gqa_call(
+        functools.partial(_gqa_fwd_kernel, bq=bq, bk=bk, window=window,
+                          scale=scale),
+        (b, hq, t // bq, _band_blocks(t, window, bq, bk)),
+        [qspec, kvspec, kvspec], (qspec, vec),
+        (jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((b, hq, t, 1), jnp.float32)),
+        [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
+         pltpu.VMEM((bq, dh), jnp.float32)],
+        (q, k, v), interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def gqa_flash_attention(q, k, v, window=None, block=None, interpret=False):
+    """Causal grouped-query attention. q: (B, Hq, T, Dh); k, v:
+    (B, Hkv, T, Dh) with Hq a multiple of Hkv; any float dtype, float32
+    accumulation. ``window``: key j is seen from query i only if
+    ``i - j < window`` (None: every earlier key). ``block``: the query and
+    key block size (None: the largest of 512..8 that divides T). Returns
+    (B, Hq, T, Dh) in q's dtype."""
+    return _gqa_fwd_call(q, k, v, window, block, interpret)[0]
+
+
+def _gqa_fwd(q, k, v, window, block, interpret):
+    o, lse = _gqa_fwd_call(q, k, v, window, block, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _gqa_bwd(window, block, interpret, res, do):
+    q, k, v, o, lse = res
+    b, hq, t, dh = q.shape
+    hkv = k.shape[1]
+    group = hq // hkv
+    bq = bk = block or _pick_gqa_block(t)
+    nq = t // bq
+    scale = 1.0 / (dh ** 0.5)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        axis=-1, keepdims=True)                      # (B, Hq, T, 1)
+
+    qspec, kvspec, vec = _gqa_specs(bq, bk, dh, group, window)
+    dq = _gqa_call(
+        functools.partial(_gqa_dq_kernel, bq=bq, bk=bk, window=window,
+                          scale=scale),
+        (b, hq, nq, _band_blocks(t, window, bq, bk)),
+        [qspec, kvspec, kvspec, qspec, vec, vec], qspec,
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((bq, dh), jnp.float32)],
+        (q, k, v, do, lse, delta), interpret)
+
+    def q_index(b_, h, jk, g, i):
+        lo, hi = _q_range(jk, bq, bk, window, nq)
+        return b_, h * group + g, jnp.minimum(lo + i, hi), 0
+
+    qspec2 = pl.BlockSpec((None, None, bq, dh), q_index)
+    vec2 = pl.BlockSpec((None, None, bq, 1), q_index)
+    kvspec2 = pl.BlockSpec((None, None, bk, dh),
+                           lambda b_, h, jk, g, i: (b_, h, jk, 0))
+    dk, dv = _gqa_call(
+        functools.partial(_gqa_dkv_kernel, bq=bq, bk=bk, window=window,
+                          scale=scale, nq=nq),
+        (b, hkv, t // bk, group, _band_blocks(t, window, bk, bq)),
+        [qspec2, kvspec2, kvspec2, qspec2, vec2, vec2], (kvspec2, kvspec2),
+        (jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        [pltpu.VMEM((bk, dh), jnp.float32), pltpu.VMEM((bk, dh), jnp.float32)],
+        (q, k, v, do, lse, delta), interpret)
+    return dq, dk, dv
+
+
+gqa_flash_attention.defvjp(_gqa_fwd, _gqa_bwd)

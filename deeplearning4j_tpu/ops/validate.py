@@ -27,6 +27,8 @@ from jax import lax
 
 from deeplearning4j_tpu.ops import lstm_pallas
 from deeplearning4j_tpu.ops.flash_attention import (flash_attention,
+                                                    gqa_flash_attention,
+                                                    gqa_supported,
                                                     supported as fa_supported)
 
 
@@ -276,6 +278,134 @@ def validate_attention_case(bh, t, dh, causal, rtol=1e-2, atol=1e-3,
     return res
 
 
+def validate_gqa_attention_case(b, hq, hkv, t, dh, window,
+                                dtype="bfloat16", rtol=2e-2, atol=2e-2,
+                                time_it=True):
+    """The grouped-query banded kernel against the layer's plain path (the
+    masked softmax over the whole score matrix, ``banded_attention``), outputs
+    and the three gradients, in the training path's dtype. bfloat16 both
+    sides: the tolerance is the rounding of p and of the outputs."""
+    from deeplearning4j_tpu.nn.layers.decoder import banded_attention
+    assert gqa_supported(t, dh, hq, hkv), (t, dh, hq, hkv)
+    dt = jnp.dtype(dtype)
+    rs = np.random.RandomState(t + hq)
+    q = jnp.asarray(rs.randn(b, hq, t, dh), dt)
+    k, v = (jnp.asarray(rs.randn(b, hkv, t, dh), dt) for _ in range(2))
+    cot = jnp.asarray(rs.randn(b, hq, t, dh), jnp.float32)
+
+    def total(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * cot)
+
+    fa = lambda q, k, v: gqa_flash_attention(q, k, v, window)
+    ref = lambda q, k, v: banded_attention(q, k, v, window)
+    fa_fwd, ref_fwd = jax.jit(fa), jax.jit(ref)
+    fa_g = jax.jit(jax.grad(total(fa), argnums=(0, 1, 2)))
+    ref_g = jax.jit(jax.grad(total(ref), argnums=(0, 1, 2)))
+    errs = {"o": _max_err(fa_fwd(q, k, v).astype(jnp.float32),
+                          ref_fwd(q, k, v).astype(jnp.float32))}
+    assert errs["o"] <= atol + rtol, errs
+    for name, a, b_ in zip("qkv", fa_g(q, k, v), ref_g(q, k, v)):
+        a, b_ = a.astype(jnp.float32), b_.astype(jnp.float32)
+        errs["d" + name] = _max_err(a, b_)
+        scale = float(jnp.max(jnp.abs(b_))) + 1.0
+        assert errs["d" + name] <= atol + rtol * scale, \
+            f"GQA B={b} Hq={hq} Hkv={hkv} T={t} window={window}: " \
+            f"d{name} err {errs['d' + name]} (scale {scale})"
+    res = {"kernel": "gqa_flash_attention", "B": b, "Hq": hq, "Hkv": hkv,
+           "T": t, "Dh": dh, "window": window, "dtype": dtype,
+           "max_err": round(max(errs.values()), 6)}
+    if time_it:
+        tf, tr = _time(fa_fwd, q, k, v), _time(ref_fwd, q, k, v)
+        tgf, tgr = _time(fa_g, q, k, v), _time(ref_g, q, k, v)
+        res.update(fwd_us=round(tf * 1e6, 1), fwd_ref_us=round(tr * 1e6, 1),
+                   fwd_speedup=_speedup(tr, tf),
+                   grad_us=round(tgf * 1e6, 1), grad_ref_us=round(tgr * 1e6, 1),
+                   grad_speedup=_speedup(tgr, tgf))
+    return res
+
+
+def validate_expert_rounds_case(n, c, n_experts, top_k, width, count,
+                                dtype="bfloat16", tol=2e-2, time_it=True):
+    """The expert layer's overflow rounds (the ``custom_vjp`` of two
+    ``fori_loop``s, which an even routing never enters) against a plain
+    loop over the experts held with a mask, in float32 at ``highest``:
+    the router is set so that every token picks every expert held, the
+    worst the layer can see, so every later round runs. Output and the
+    gradients of x and of the three stacked projections, by relative norm
+    gap; ``pairs_dropped`` must read 0 and more than one round must run."""
+    from deeplearning4j_tpu.nn.layers.decoder import ExpertLayer, route_top_k
+    dt = jnp.dtype(dtype)
+    layer = ExpertLayer(n_in=c, n_experts=n_experts, experts_per_token=top_k,
+                        expert_width=width, routed_scale=2.5,
+                        experts_held=(count, 0), weight_init="xavier")
+    p = layer.init(jax.random.PRNGKey(n + count), jnp.float32)
+    # positive tokens and one hot router column: expert 1 scores highest for
+    # every token, the rest tie and top_k takes the lowest indices
+    p["Wr"] = jnp.zeros_like(p["Wr"]).at[:, 1].set(1.0)
+    p = {k: v.astype(dt) for k, v in p.items()}
+    rs = np.random.RandomState(c)
+    x = jnp.asarray(np.abs(rs.randn(n, c)) + 0.1, dt)
+    cot = jnp.asarray(rs.randn(n, c), jnp.float32)
+    rows, rounds = layer.round_rows(n)
+
+    def ours(x, eg, eu, ed):
+        y, seen = layer.routed(dict(p, Eg=eg, Eu=eu, Ed=ed), x)
+        return jnp.sum(y * cot), (y, seen)
+
+    def plain(x, eg, eu, ed):
+        f32 = lambda a: a.astype(jnp.float32)
+        hi = dict(precision=lax.Precision.HIGHEST)
+        idx, w = route_top_k(x, p["Wr"], top_k, True, 2.5)
+        y = jnp.zeros((n, c), jnp.float32)
+        for e in range(count):
+            pe = jnp.sum(jnp.where(idx == e, w, 0.0), axis=-1)
+            h = jax.nn.silu(jnp.dot(f32(x), f32(eg[e]), **hi)) \
+                * jnp.dot(f32(x), f32(eu[e]), **hi)
+            y = y + pe[:, None] * jnp.dot(h, f32(ed[e]), **hi)
+        return jnp.sum(y * cot), y
+
+    args = (x, p["Eg"], p["Eu"], p["Ed"])
+    ours_g = jax.jit(jax.value_and_grad(ours, argnums=(0, 1, 2, 3),
+                                        has_aux=True))
+    plain_g = jax.jit(jax.value_and_grad(plain, argnums=(0, 1, 2, 3),
+                                         has_aux=True))
+    (_, (got, seen)), g_got = ours_g(*args)
+    (_, want), g_want = plain_g(*args)
+    pairs, dropped = int(seen["pairs"]), int(seen["pairs_dropped"])
+    assert rounds > 1 and pairs > rows, (pairs, rows, rounds)
+    assert dropped == 0, f"{dropped} of {pairs} pairs dropped"
+
+    def gap(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+    gaps = {"y": gap(got, want)}
+    for name, a, b in zip(("dx", "dEg", "dEu", "dEd"), g_got, g_want):
+        gaps[name] = gap(a, b)
+    for name, v in gaps.items():
+        assert v <= tol, f"expert rounds N={n} held={count}: {name} gap {v}"
+    res = {"kernel": "expert_rounds", "N": n, "C": c, "experts": n_experts,
+           "top_k": top_k, "width": width, "held": count, "dtype": dtype,
+           "pairs": pairs, "rows": rows,
+           "rounds_run": -(-pairs // rows), "pairs_dropped": dropped,
+           "gaps": {k: round(v, 6) for k, v in gaps.items()},
+           "max_err": round(max(gaps.values()), 6)}
+    if time_it:
+        res.update(grad_us=round(_time(ours_g, *args) * 1e6, 1),
+                   grad_ref_us=round(_time(plain_g, *args) * 1e6, 1))
+    return res
+
+
+# (N, C, experts, top_k, width, held): one chip's share of the benchmark's
+# sparse decoder at its step's 16,384 tokens (18 rounds), and a small case
+EXPERT_SWEEP = [(16384, 3072, 256, 10, 1024, 8), (256, 64, 16, 3, 32, 4)]
+EXPERT_QUICK = EXPERT_SWEEP[1:]
+
+# (B, Hq, Hkv, T, Dh, window): group sizes 6 and 9, the band and the triangle
+GQA_SWEEP = [(1, 12, 2, 2048, 128, None), (1, 18, 2, 2048, 128, 512),
+             (2, 6, 1, 1024, 64, 256), (1, 9, 1, 4096, 128, 512)]
+GQA_QUICK = GQA_SWEEP[:2]
+
 LSTM_SWEEP = [
     # the supported() envelope edges: small/odd-ish H (8-aligned), big H
     (1, 4, 8), (4, 16, 8), (8, 16, 24), (4, 32, 56), (8, 32, 120),
@@ -339,6 +469,24 @@ def run(quick=False, time_it=True):
                                  "T": t, "Dh": dh, "causal": causal,
                                  "error": f"{type(e).__name__}: {e}"[:300]})
                 print(json.dumps(failures[-1]))
+    for case in (GQA_QUICK if quick else GQA_SWEEP):
+        try:
+            r = validate_gqa_attention_case(*case, time_it=time_it)
+            results.append(r)
+            print(json.dumps(r))
+        except Exception as e:  # noqa: BLE001
+            failures.append({"kernel": "gqa_flash_attention", "case": case,
+                             "error": f"{type(e).__name__}: {e}"[:300]})
+            print(json.dumps(failures[-1]))
+    for case in (EXPERT_QUICK if quick else EXPERT_SWEEP):
+        try:
+            r = validate_expert_rounds_case(*case, time_it=time_it)
+            results.append(r)
+            print(json.dumps(r))
+        except Exception as e:  # noqa: BLE001
+            failures.append({"kernel": "expert_rounds", "case": case,
+                             "error": f"{type(e).__name__}: {e}"[:300]})
+            print(json.dumps(failures[-1]))
     summary = {"backend": jax.default_backend(),
                "device": jax.devices()[0].device_kind,
                "passed": len(results), "failed": len(failures),

@@ -1,101 +1,62 @@
-"""Expert parallelism: capacity-based mixture-of-experts over a mesh axis.
+"""Expert parallelism: ``ExpertLayer``'s experts divided over a mesh axis.
 
-The reference has no MoE/expert parallelism (SURVEY §2 inventory); this is
-the TPU-idiomatic extension completing dp/tp/sp/pp/ep. The classic dense
-formulation (Shazeer et al.): top-1 gating builds static-shaped dispatch /
-combine tensors (tokens × experts × capacity) so the whole layer is three
-einsums plus the expert FFNs — no ragged shapes, XLA inserts the all-to-alls
-when the expert axis of the parameters and intermediate (E, C, D) tensors is
-sharded over the mesh's 'expert' axis.
+The reference has no mixture of experts (SURVEY §2 inventory). The layer
+(``nn/layers/decoder.py``) is written for a chip that holds a share of the
+experts: it routes over all of them, is told which it holds, and computes
+their part of the result for the tokens routed to them, dropping nothing.
+Here every device of the ``expert`` axis is such a chip: under ``shard_map``
+each holds ``n_experts / devices`` consecutive experts, sees every token,
+computes its part, and the parts are summed over the axis (``psum``); the
+shared expert, which every device would compute alike, is added once. No
+capacity, no dispatch tensors, no auxiliary loss.
 
-Tokens routed to a full expert (beyond ``capacity``) are dropped (output 0
-for that token — the standard GShard/Switch behavior); an auxiliary
-load-balancing loss keeps the router from collapsing onto one expert.
+The exchange is the all-gather of tokens the replicated input implies plus
+the ``psum``; an all-to-all that sends each device only its own tokens is
+the next step for a mesh where that traffic matters (ROADMAP, Reach).
 """
 
 from __future__ import annotations
 
-import numpy as np
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def init_moe_params(rng, d_model: int, d_hidden: int, n_experts: int,
-                    dtype=jnp.float32):
-    kg, k1, k2 = jax.random.split(rng, 3)
-    scale_in = 1.0 / np.sqrt(d_model)
-    scale_h = 1.0 / np.sqrt(d_hidden)
-    return {
-        "Wg": jax.random.normal(kg, (d_model, n_experts), dtype) * scale_in,
-        "W1": jax.random.normal(k1, (n_experts, d_model, d_hidden), dtype)
-        * scale_in,
-        "b1": jnp.zeros((n_experts, d_hidden), dtype),
-        "W2": jax.random.normal(k2, (n_experts, d_hidden, d_model), dtype)
-        * scale_h,
-        "b2": jnp.zeros((n_experts, d_model), dtype),
-    }
+EXPERT_LEAVES = ("Eg", "Eu", "Ed")
 
 
-def shard_moe_params(params, mesh: Mesh, axis: str = "expert"):
+def shard_expert_params(params, mesh: Mesh, axis: str = "expert"):
     """Expert-major leaves shard their leading (expert) dim over ``axis``;
-    the router is replicated."""
+    the router and the shared expert are replicated."""
     def place(name, a):
-        if name == "Wg":
-            return jax.device_put(a, NamedSharding(mesh, P()))
-        return jax.device_put(
-            a, NamedSharding(mesh, P(*([axis] + [None] * (a.ndim - 1)))))
+        spec = P(axis) if name in EXPERT_LEAVES else P()
+        return jax.device_put(a, NamedSharding(mesh, spec))
     return {k: place(k, v) for k, v in params.items()}
 
 
-def moe_ffw(params, x, capacity_factor: float = 1.25):
-    """Top-1 routed expert feed-forward.
+def expert_parallel_apply(layer, params, x2, mesh: Mesh,
+                          axis: str = "expert"):
+    """``layer`` (an ``ExpertLayer`` that holds all its experts) applied to
+    tokens x2 (N, C) with its experts divided over ``axis``. Returns
+    (y (N, C), counters summed over the axis; ``load_max`` the largest)."""
+    import dataclasses
 
-    x: (T, D) tokens. Returns (y, aux_loss) where y: (T, D) and aux_loss is
-    the Switch-style load-balancing penalty (mean fraction × mean prob per
-    expert, scaled by E).
-    """
-    T, D = x.shape
-    E = params["Wg"].shape[-1]
-    C = max(1, int(capacity_factor * T / E))
+    n_dev = mesh.shape[axis]
+    if layer.held != (layer.n_experts, 0) or layer.n_experts % n_dev:
+        raise ValueError("expert_parallel_apply divides a whole layer's "
+                         f"{layer.n_experts} experts over {n_dev} devices")
+    per = layer.n_experts // n_dev
+    share = dataclasses.replace(layer, experts_held=(per, 0))
 
-    logits = x @ params["Wg"]                     # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)           # (T,)
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
+    def part(p, x):
+        first = jax.lax.axis_index(axis) * per
+        y, seen = share.routed(p, x, first=first)
+        return (jax.lax.psum(y, axis),
+                {"pairs": jax.lax.psum(seen["pairs"], axis),
+                 "pairs_dropped": jax.lax.psum(seen["pairs_dropped"], axis),
+                 "load_max": jax.lax.pmax(seen["load_max"], axis)})
 
-    onehot = jax.nn.one_hot(expert, E, dtype=x.dtype)          # (T, E)
-    # position of each token within its expert's queue
-    pos = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot          # (T, E)
-    keep = onehot * (pos < C)                                  # capacity drop
-    pos_c = jax.nn.one_hot(pos.astype(jnp.int32), C, dtype=x.dtype)  # (T,E,C)
-    dispatch = keep[..., None] * pos_c                         # (T, E, C)
-    combine = dispatch * gate[:, None, None]                   # (T, E, C)
-
-    xe = jnp.einsum("tec,td->ecd", dispatch, x)                # (E, C, D)
-    h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", xe, params["W1"])
-                    + params["b1"][:, None, :])
-    ye = jnp.einsum("ech,ehd->ecd", h, params["W2"]) \
-        + params["b2"][:, None, :]
-    y = jnp.einsum("tec,ecd->td", combine, ye)                 # (T, D)
-
-    # Switch load-balancing aux loss
-    frac_tokens = onehot.mean(axis=0)                          # (E,)
-    frac_probs = probs.mean(axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return y, aux
-
-
-def moe_ffw_dense_reference(params, x):
-    """Every token through its argmax expert with NO capacity limit — the
-    unsharded oracle for tests (equals moe_ffw when capacity is ample)."""
-    probs = jax.nn.softmax(x @ params["Wg"], axis=-1)
-    expert = jnp.argmax(probs, axis=-1)
-    gate = jnp.take_along_axis(probs, expert[:, None], axis=1)[:, 0]
-    W1 = params["W1"][expert]                     # (T, D, H)
-    b1 = params["b1"][expert]
-    W2 = params["W2"][expert]
-    b2 = params["b2"][expert]
-    h = jax.nn.gelu(jnp.einsum("td,tdh->th", x, W1) + b1)
-    y = jnp.einsum("th,thd->td", h, W2) + b2
-    return y * gate[:, None]
+    specs = {k: (P(axis) if k in EXPERT_LEAVES else P()) for k in params}
+    y, seen = jax.shard_map(part, mesh=mesh, in_specs=(specs, P()),
+                            out_specs=(P(), P()), check_vma=False)(params, x2)
+    if layer.shared_width:
+        y = y + layer.shared(params, x2)
+    return y.astype(x2.dtype), seen

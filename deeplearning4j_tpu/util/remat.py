@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import jax
 
-_MODES = (False, True, "full", "save_convs", "selective")
+_MODES = (False, True, "full", "save_convs", "selective", "blocks")
 
 
 def check_remat_mode(mode):
@@ -16,7 +16,7 @@ def check_remat_mode(mode):
     if mode not in _MODES:
         raise ValueError(
             f"unknown remat mode {mode!r} "
-            "(False | True | 'full' | 'save_convs' | 'selective')")
+            "(False | True | 'full' | 'save_convs' | 'selective' | 'blocks')")
     return mode
 
 
@@ -26,9 +26,12 @@ def remat_loss(loss_fn, mode):
     'save_convs'/'selective' → checkpoint saving only named values: conv
     outputs (ConvolutionLayer tags them "conv_out") and BatchNorm's batch
     mean and inverse deviation (``batch_norm_train`` tags them "bn_stats":
-    a few KB a layer that spare the replay a reduction over activations)."""
-    if not mode:
-        return loss_fn
+    a few KB a layer that spare the replay a reduction over activations);
+    'blocks' → unchanged here: the containers' forward wraps each block of
+    ``remat_segments`` in a ``jax.checkpoint`` of its own, so the backward
+    pass keeps the blocks' inputs and replays one block at a time."""
+    if not mode or mode == "blocks":
+        return loss_fn          # 'blocks': the checkpoints are in the forward
     if mode in (True, "full"):
         return jax.checkpoint(loss_fn)
     if mode in ("save_convs", "selective"):
@@ -38,3 +41,39 @@ def remat_loss(loss_fn, mode):
                 "conv_out", "bn_stats"))
     check_remat_mode(mode)                     # raises; not a known mode
     raise AssertionError("unreachable")
+
+
+def block_of(name):
+    """The block a node or layer belongs to: its name up to the first
+    ``.`` (``b3.attn`` -> ``b3``), or None for a name without one."""
+    name = str(name)
+    return name.split(".", 1)[0] if "." in name else None
+
+
+def remat_segments(conf):
+    """A graph's topological order cut into replay units for
+    ``remat='blocks'``: ``[(names, outs)]`` where a run of consecutive nodes
+    of one block is a unit with ``outs`` the activations that leave it
+    (read by a later node, or a network output), and the nodes outside any
+    block form runs with ``outs`` None, applied as they are."""
+    order = [n for n in conf.topological_order
+             if conf.nodes[n].kind != "input"]
+    runs = []
+    for n in order:
+        b = block_of(n)
+        if runs and runs[-1][0] == b:
+            runs[-1][1].append(n)
+        else:
+            runs.append((b, [n]))
+    out = []
+    for b, names in runs:
+        if b is None:
+            out.append((names, None))
+            continue
+        inside = set(names)
+        leaving = [n for n in names
+                   if n in conf.network_outputs
+                   or any(n in conf.nodes[m].inputs
+                          for m in order if m not in inside)]
+        out.append((names, leaving))
+    return out

@@ -14,7 +14,8 @@ from deeplearning4j_tpu.zoo.resnet import ResNet50, ResNet50Cifar
 from deeplearning4j_tpu.zoo.inception import (
     GoogLeNet, InceptionResNetV1, FaceNetNN4Small2,
 )
+from deeplearning4j_tpu.zoo.decoder import SparseDecoder
 
 __all__ = ["ZooModel", "LeNet", "SimpleCNN", "AlexNet", "VGG16", "VGG19",
            "Darknet19", "TextGenerationLSTM", "TinyTransformer", "ResNet50", "ResNet50Cifar", "GoogLeNet",
-           "InceptionResNetV1", "FaceNetNN4Small2"]
+           "InceptionResNetV1", "FaceNetNN4Small2", "SparseDecoder"]

@@ -1,0 +1,281 @@
+"""The sparse decoder's layers, kernel and model against the plain
+reference of the benchmark (perfbench/lib/reference_lm.py), at a small size
+on the CPU with seeded weights."""
+
+import os
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu import ops
+from deeplearning4j_tpu.data.dataset import DataSet
+from deeplearning4j_tpu.nn.layers import (RMSNorm, SwiGLU, RotaryGQAttention,
+                                          ExpertLayer)
+from deeplearning4j_tpu.nn.layers.decoder import banded_attention
+from deeplearning4j_tpu.ops.flash_attention import gqa_flash_attention
+from perfbench.lib import arch, reference_lm as ref
+from perfbench.jobs import fit_lm
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+C, HD, KV = 32, 8, 2
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    """The benchmark's configuration at its rehearsal size: 1 + 4 layers,
+    hidden 32, 16 experts of which 4 are held, window 8."""
+    return arch.load_config(
+        os.path.join(ROOT, "perfbench", "configs", "laguna-s-2.1.json"),
+        rehearse=True)
+
+
+def _rand(shape, seed, scale=1.0):
+    return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
+                       jnp.float32)
+
+
+def _close(a, b, tol=2e-4):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                               atol=tol)
+
+
+def _agree(prog, plain, params, x):
+    """Forward and the gradients wrt the parameters and the input."""
+    _close(prog(params, x), plain(params, x))
+    cot = _rand(x.shape, 99)
+    gp = jax.grad(lambda p, x: (prog(p, x) * cot).sum(), (0, 1))(params, x)
+    gr = jax.grad(lambda p, x: (plain(p, x) * cot).sum(), (0, 1))(params, x)
+    jax.tree_util.tree_map(lambda a, b: _close(a, b, 1e-3), gp, gr)
+
+
+def _rope(cfg, kind):
+    return ref.rope_of(ref.dims(cfg), kind)
+
+
+def test_rmsnorm_against_reference():
+    layer = RMSNorm(n_in=C, eps=1e-6)
+    p = {"gamma": 1.0 + 0.1 * _rand((C,), 0)}
+    _agree(lambda p, x: layer.apply(p, x)[0],
+           lambda p, x: ref.rms_norm(x, p["gamma"], 1e-6), p,
+           _rand((2, 16, C), 1))
+
+
+def test_swiglu_against_reference():
+    layer = SwiGLU(n_in=C, n_out=C, width=64)
+    p = layer.init(jax.random.PRNGKey(0))
+    _agree(lambda p, x: layer.apply(p, x)[0],
+           lambda p, x: ref.swiglu(x, p["Wg"], p["Wu"], p["Wd"]), p,
+           _rand((2, 16, C), 2))
+
+
+@pytest.mark.parametrize("kind,heads,kernel", [
+    ("full_attention", 12, False), ("sliding_attention", 18, False),
+    ("full_attention", 12, True), ("sliding_attention", 18, True)])
+def test_attention_against_reference(cfg, kind, heads, kernel):
+    """Full layers: 12 heads on 2 kv heads, YaRN over half of each head;
+    sliding layers: 18 heads, window 8, plain rotary. With ``kernel`` the
+    layer runs the Pallas kernel interpreted."""
+    window = 8 if kind == "sliding_attention" else None
+    rope = _rope(cfg, kind)
+    layer = RotaryGQAttention(n_in=C, n_out=C, n_heads=heads, n_kv_heads=KV,
+                              head_dim=HD, window=window, rotary=rope,
+                              head_gate=True)
+    p = layer.init(jax.random.PRNGKey(1))
+
+    def plain(p, x):
+        return jnp.stack([ref.attention(
+            xi, p, heads=heads, kv_heads=KV, head_dim=HD, window=window,
+            rope=rope, head_gate=True, chunk=8) for xi in x])
+
+    prev = ops.set_helpers_enabled(True, interpret=True) if kernel else None
+    try:
+        _agree(lambda p, x: layer.apply(p, x)[0], plain, p,
+               _rand((2, 32, C), 3))
+    finally:
+        if kernel:
+            ops.set_helpers_enabled(prev[0], interpret=prev[1])
+
+
+@pytest.mark.parametrize("hq,hkv,window", [(12, 2, None), (12, 2, 24),
+                                           (9, 1, None), (9, 1, 24)])
+def test_gqa_kernel_against_masked_softmax(hq, hkv, window):
+    """The kernel interpreted, group sizes 6 and 9, the triangle and a band
+    that crosses block boundaries (blocks of 16, window 24)."""
+    q = _rand((2, hq, 64, 8), 4)
+    k, v = _rand((2, hkv, 64, 8), 5), _rand((2, hkv, 64, 8), 6)
+    kern = lambda q, k, v: gqa_flash_attention(q, k, v, window, 16, True)
+    plain = lambda q, k, v: banded_attention(q, k, v, window)
+    _close(kern(q, k, v), plain(q, k, v), 1e-5)
+    gk = jax.grad(lambda *a: (kern(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    gp = jax.grad(lambda *a: (plain(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(gk, gp):
+        _close(a, b, 1e-4)
+
+
+def _expert_layer(held=None, e=16):
+    return ExpertLayer(n_in=C, n_experts=e, experts_per_token=3,
+                       expert_width=16, shared_width=16, routed_scale=2.5,
+                       experts_held=held)
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)])
+def test_expert_layer_against_reference(held):
+    layer = _expert_layer(held)
+    p = layer.init(jax.random.PRNGKey(2))
+
+    def plain(p, x):
+        return jnp.stack([ref.experts(
+            xi, p, top_k=3, held=layer.held, routed_scale=2.5,
+            norm_topk=True)[0] for xi in x])
+
+    _agree(lambda p, x: layer.apply(p, x)[0], plain, p, _rand((2, 24, C), 7))
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """Guide section 4: 16 experts as 4 shares of 4. The routed parts of all
+    shares, plus the shared expert once, equal the uncut reference's
+    layer."""
+    whole = _expert_layer()
+    p = whole.init(jax.random.PRNGKey(3))
+    x = _rand((48, C), 8)
+    total = whole.shared(p, x).astype(jnp.float32)
+    pairs = 0
+    for s in range(4):
+        share = _expert_layer((4, 4 * s))
+        ps = dict(p, **{k: p[k][4 * s:4 * s + 4] for k in ("Eg", "Eu", "Ed")})
+        y, seen = share.routed(ps, x)
+        total = total + y
+        pairs += int(seen["pairs"])
+        assert int(seen["pairs_dropped"]) == 0
+    want, _ = ref.experts(x, p, top_k=3, held=(16, 0), routed_scale=2.5,
+                          norm_topk=True)
+    _close(total, want)
+    assert pairs == 48 * 3
+
+
+def test_no_pair_dropped_when_routing_is_skewed_onto_one_expert():
+    layer = _expert_layer((4, 0))
+    p = layer.init(jax.random.PRNGKey(4))
+    p["Wr"] = jnp.zeros_like(p["Wr"]).at[:, 1].set(1.0)
+    x = jnp.abs(_rand((64, C), 9)) + 0.1       # expert 1 first, for all
+    rows, rounds = layer.round_rows(64)
+    y, seen = layer.routed(p, x)
+    assert int(seen["load_max"]) == 64 and int(seen["pairs_dropped"]) == 0
+    assert int(seen["pairs"]) > rows and rounds > 1
+    want, n = ref.experts(x, {k: v for k, v in p.items() if k[0] != "S"},
+                          top_k=3, held=(4, 0), routed_scale=2.5,
+                          norm_topk=True)
+    assert int(n) == int(seen["pairs"])
+    _close(y, want)
+
+
+def test_a_round_left_out_reads_as_pairs_dropped(monkeypatch):
+    """``pairs_dropped`` counts what the rounds computed: with the loop of
+    the later rounds cut one short, the pairs of that round are missed."""
+    from deeplearning4j_tpu.nn.layers import decoder
+    layer = _expert_layer((4, 0))
+    p = layer.init(jax.random.PRNGKey(4))
+    p["Wr"] = jnp.zeros_like(p["Wr"]).at[:, 1].set(1.0)
+    x = jnp.abs(_rand((64, C), 9)) + 0.1
+    rows, _ = layer.round_rows(64)
+    _, sound = layer.routed(p, x)
+    real = jax.lax.fori_loop
+    monkeypatch.setattr(
+        decoder.jax.lax, "fori_loop",
+        lambda lo, hi, body, init: real(lo, hi - 1, body, init))
+    _, cut = layer.routed(p, x)
+    last = int(sound["pairs"]) - (int(sound["pairs"]) - 1) // rows * rows
+    assert int(sound["pairs_dropped"]) == 0
+    assert int(cut["pairs_dropped"]) == last > 0
+
+
+def test_the_chip_screen_of_the_overflow_rounds_runs_small():
+    from deeplearning4j_tpu.ops import validate
+    r = validate.validate_expert_rounds_case(*validate.EXPERT_QUICK[0],
+                                             time_it=False)
+    assert r["rounds_run"] > 1 and r["pairs_dropped"] == 0
+
+
+# ------------------------------------------------------------- the model
+
+def _net(cfg, **kw):
+    cfg = dict(cfg, program=dict(cfg["program"], kwargs=dict(
+        cfg["program"]["kwargs"], **kw)))
+    return fit_lm.build_net(cfg)
+
+
+def _batches(cfg, n, seed=0):
+    traffic = {"pool_batches": n}
+    return fit_lm.make_pool(cfg, traffic, seed, 2, 32)
+
+
+def test_three_fit_steps_against_three_reference_steps(cfg):
+    """Loss of each step, Adam's first moment after step 1, the parameters'
+    change after step 3; float32 on both sides."""
+    net = _net(cfg)
+    fit_lm.set_weights(cfg, net, ref.init_params(cfg, 5))
+    pool = _batches(cfg, 3)
+    seen = fit_lm.check_steps(
+        cfg, {"steps_per_call": 1, "check_steps": 3}, net, pool, DataSet, 5)
+    want = ref.run_steps(cfg, 5, pool)
+    np.testing.assert_allclose([seen["losses"][i] for i in (1, 2, 3)],
+                               want["losses"], rtol=1e-5)
+    np.testing.assert_allclose(seen["trace_norms"], want["trace_norms"],
+                               rtol=1e-4)
+    np.testing.assert_allclose(seen["delta_norms"], want["delta_norms"],
+                               rtol=5e-3)
+    assert [[p for _, p in s] for s in seen["pairs"]] == want["pairs"]
+
+
+def test_block_replay_and_bfloat16_leave_the_step_what_it_is(cfg):
+    """remat='blocks' changes no number; bfloat16 compute stays near."""
+    pool = _batches(cfg, 1, seed=3)
+    losses = {}
+    for name, kw in (("plain", {"remat": None}), ("blocks", {}),
+                     ("bf16", {"compute_dtype": "bfloat16"})):
+        net = _net(cfg, **kw)
+        fit_lm.set_weights(cfg, net, ref.init_params(cfg, 6))
+        net.fit(iter([DataSet(*pool[0])]))
+        losses[name] = (net.get_score(), np.asarray(
+            net.params["b1.mlp"]["Eg"]))
+    assert losses["plain"][0] == pytest.approx(losses["blocks"][0], rel=1e-6)
+    np.testing.assert_allclose(losses["plain"][1], losses["blocks"][1],
+                               rtol=1e-5, atol=1e-7)
+    assert losses["bf16"][0] == pytest.approx(losses["plain"][0], rel=2e-2)
+
+
+def test_model_through_the_serializer_and_back(cfg, tmp_path):
+    from deeplearning4j_tpu.util.model_serializer import (
+        write_model, restore_computation_graph as restore_model)
+    net = _net(cfg)
+    pool = _batches(cfg, 1)
+    net.fit(iter([DataSet(*pool[0])]))
+    path = str(tmp_path / "decoder.zip")
+    write_model(net, path)
+    back = restore_model(path)
+    layer = back.conf.nodes["b1.mlp"].layer
+    assert type(layer).__name__ == "ExpertLayer" and layer.held == (4, 0)
+    assert back.conf.nodes["b1.attn"].layer.rotary["dims"] == HD
+    a = net.output(pool[0][0], bucketed=False)
+    b = back.output(pool[0][0], bucketed=False)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+    back.fit(iter([DataSet(*pool[0])]))           # and it trains on
+    assert np.isfinite(back.get_score())
+
+
+def test_expert_counters_at_the_fit_boundary(cfg):
+    from deeplearning4j_tpu.monitor.metrics import get_registry
+    net = _net(cfg)
+    pool = _batches(cfg, 2)
+    net.fit(iter([DataSet(*b) for b in pool]))
+    reg = get_registry()
+    fam = reg.get("dl4jtpu_moe_pairs_total")
+    mine = {k: c.value for k, c in fam.children() if "b1.mlp" in k}
+    state = fit_lm.expert_counts(net)
+    assert sum(mine.values()) >= state["b1.mlp"]["pairs_total"] > 0
+    dropped = reg.get("dl4jtpu_moe_pairs_dropped_total")
+    assert all(c.value == 0 for _, c in dropped.children())
+    assert reg.get("dl4jtpu_moe_expert_load_max") is not None
+    assert reg.get("dl4jtpu_moe_expert_load_mean") is not None

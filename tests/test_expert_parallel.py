@@ -1,80 +1,101 @@
-"""Expert-parallel MoE tests (TPU-idiomatic extension; oracle = per-token
-dense expert application)."""
+"""The dropless expert layer (nn/layers/decoder.py:ExpertLayer) and its
+experts over a mesh axis (parallel/expert_parallel.py). Oracle: every token
+through each of its chosen experts, one at a time."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from deeplearning4j_tpu.nn.layers import ExpertLayer
+from deeplearning4j_tpu.nn.layers.decoder import route_top_k, swiglu
 from deeplearning4j_tpu.parallel.expert_parallel import (
-    init_moe_params, shard_moe_params, moe_ffw, moe_ffw_dense_reference,
-)
+    shard_expert_params, expert_parallel_apply)
 
-D, H, E = 8, 16, 4
-
-
-def _params(seed=0):
-    return init_moe_params(jax.random.PRNGKey(seed), D, H, E)
+D, W, E, K = 8, 16, 8, 3
 
 
-class TestMoE:
-    def test_matches_dense_reference_with_ample_capacity(self):
-        params = _params()
-        x = jnp.asarray(np.random.RandomState(1).randn(32, D), jnp.float32)
-        y, aux = moe_ffw(params, x, capacity_factor=E * 1.0)  # C = T, no drops
-        want = moe_ffw_dense_reference(params, x)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(want),
-                                   rtol=1e-4, atol=1e-5)
-        assert float(aux) > 0
+def _layer(**kw):
+    return ExpertLayer(n_in=D, n_experts=E, experts_per_token=K,
+                       expert_width=W, shared_width=W, routed_scale=2.5, **kw)
 
-    def test_capacity_drops_zero_tokens(self):
-        params = _params(2)
-        x = jnp.asarray(np.random.RandomState(2).randn(64, D), jnp.float32)
-        y_tight, _ = moe_ffw(params, x, capacity_factor=0.25)
-        y_ample, _ = moe_ffw(params, x, capacity_factor=E * 1.0)
-        dropped = np.asarray(jnp.all(y_tight == 0, axis=-1))
-        assert dropped.any(), "tight capacity should drop some tokens"
-        kept = ~dropped
-        np.testing.assert_allclose(np.asarray(y_tight)[kept],
-                                   np.asarray(y_ample)[kept],
-                                   rtol=1e-4, atol=1e-5)
 
-    def test_sharded_run_matches_unsharded(self):
-        """Experts sharded over the mesh 'expert' axis: same outputs, XLA
-        inserts the all-to-alls."""
-        mesh = Mesh(np.array(jax.devices()[:E]), ("expert",))
-        params = _params(3)
-        x = jnp.asarray(np.random.RandomState(3).randn(32, D), jnp.float32)
-        y_ref, aux_ref = moe_ffw(params, x, capacity_factor=2.0)
+def _dense_oracle(layer, p, x2):
+    idx, w = route_top_k(x2, p["Wr"], K, True, 2.5)
+    y = swiglu(x2, p["Sg"], p["Su"], p["Sd"])
+    for e in range(p["Eg"].shape[0]):
+        we = jnp.where(idx == e, w, 0.0).sum(-1)
+        y = y + we[:, None] * swiglu(x2, p["Eg"][e], p["Eu"][e], p["Ed"][e])
+    return y
 
-        sharded = shard_moe_params(params, mesh)
-        assert len(sharded["W1"].sharding.device_set) == E
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else mesh:
-            y_sh, aux_sh = jax.jit(moe_ffw, static_argnames="capacity_factor")(
-                sharded, x, capacity_factor=2.0)
-        np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_ref),
-                                   rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(float(aux_sh), float(aux_ref), rtol=1e-4)
 
-    def test_trainable_end_to_end(self):
-        """Router + experts learn a mapping; aux loss keeps routing spread."""
-        params = _params(4)
-        rs = np.random.RandomState(5)
-        x = jnp.asarray(rs.randn(64, D), jnp.float32)
-        tgt = jnp.asarray(np.tanh(rs.randn(64, D)), jnp.float32)
+def _x(n, seed=1):
+    return jnp.asarray(np.random.RandomState(seed).randn(n, D), jnp.float32)
 
-        @jax.jit
-        def step(params, x, tgt):
-            def loss(p):
-                y, aux = moe_ffw(p, x, capacity_factor=2.0)
-                return jnp.mean((y - tgt) ** 2) + 0.01 * aux
-            l, g = jax.value_and_grad(loss)(params)
-            return jax.tree_util.tree_map(lambda p, gg: p - 0.3 * gg,
-                                          params, g), l
 
-        losses = []
-        for _ in range(200):
-            params, l = step(params, x, tgt)
-            losses.append(float(l))
-        assert losses[-1] < losses[0] * 0.6, losses[::40]
+def test_routing_equals_dense_oracle():
+    layer = _layer()
+    p = layer.init(jax.random.PRNGKey(0))
+    x = _x(32)
+    y, _ = layer.apply(p, x[None])
+    np.testing.assert_allclose(np.asarray(y[0]),
+                               np.asarray(_dense_oracle(layer, p, x)),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_nothing_dropped_under_skew():
+    """Every token on the same three experts of the four held: they take
+    all the pairs, past the first round's buffer, and none is left out."""
+    layer = _layer(experts_held=(4, 0))
+    p = layer.init(jax.random.PRNGKey(2))
+    p["Wr"] = jnp.zeros_like(p["Wr"]).at[:, :K].set(1.0)
+    x = jnp.abs(_x(64, 2)) + 0.1         # positive: experts 0..K-1 win
+    rows, rounds = layer.round_rows(64)
+    assert rounds > 1 and rows < 64 * K
+    y, seen = layer.routed(p, x)
+    assert int(seen["pairs"]) == 64 * K and int(seen["pairs_dropped"]) == 0
+    assert int(seen["load_max"]) == 64
+    want = _dense_oracle(layer, p, x) - swiglu(x, p["Sg"], p["Su"], p["Sd"])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    # and the gradient flows through the later rounds
+    g = jax.grad(lambda p: layer.routed(p, x)[0].sum())(p)
+    gw = jax.grad(lambda p: (_dense_oracle(layer, p, x)
+                             - swiglu(x, p["Sg"], p["Su"], p["Sd"])).sum())(p)
+    for k in ("Eg", "Eu", "Ed"):
+        np.testing.assert_allclose(np.asarray(g[k]), np.asarray(gw[k]),
+                                   rtol=1e-3, atol=1e-4, err_msg=k)
+
+
+def test_sharded_over_expert_axis_equals_unsharded():
+    mesh = Mesh(np.array(jax.devices()[:4]), ("expert",))
+    layer = _layer()
+    p = layer.init(jax.random.PRNGKey(3))
+    x = _x(32, 3)
+    want, _ = layer.apply(p, x[None])
+    sharded = shard_expert_params(p, mesh)
+    assert len(sharded["Eg"].sharding.device_set) == 4
+    y, seen = jax.jit(lambda p, x: expert_parallel_apply(layer, p, x, mesh))(
+        sharded, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want[0]),
+                               rtol=1e-4, atol=1e-5)
+    assert int(seen["pairs"]) == 32 * K and int(seen["pairs_dropped"]) == 0
+
+
+def test_trainable_end_to_end():
+    layer = _layer()
+    p = layer.init(jax.random.PRNGKey(4))
+    x = _x(64, 4)
+    target = jnp.tanh(x)
+
+    def loss(p):
+        y, _ = layer.apply(p, x[None])
+        return jnp.mean((y[0] - target) ** 2)
+
+    step = jax.jit(lambda p: jax.tree_util.tree_map(
+        lambda a, g: a - 0.05 * g, p, jax.grad(loss)(p)))
+    l0 = float(loss(p))
+    for _ in range(40):
+        p = step(p)
+    assert float(loss(p)) < 0.7 * l0
+    assert float(jnp.abs(jax.grad(loss)(p)["Wr"]).sum()) > 0

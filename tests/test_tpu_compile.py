@@ -177,6 +177,33 @@ def test_flash_attention_fwd_and_grad(one_chip, bh, t, dh):
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
+# the benchmark's decoder cell: 2 sequences of 8192, 2 kv heads held, 12
+# query heads on full layers and 18 behind a window of 512 on sliding ones
+@pytest.mark.parametrize("hq,window", [(12, None), (18, 512)])
+def test_gqa_flash_attention_fwd_and_grad(one_chip, hq, window):
+    def loss(q, k, v):
+        return flash_attention.gqa_flash_attention(
+            q, k, v, window).astype(jnp.float32).sum()
+
+    shapes = _shapes(one_chip, ((2, hq, 8192, 128), jnp.bfloat16),
+                     ((2, 2, 8192, 128), jnp.bfloat16),
+                     ((2, 2, 8192, 128), jnp.bfloat16))
+    _agree(flash_attention.gqa_supported(8192, 128, hq, 2),
+           jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
+
+
+def test_ragged_dot_is_one_grouped_product_on_the_chip(one_chip):
+    """The expert layer's grouped product at the cell's shape: the TPU
+    compiler keeps it one kernel of M x K x N multiply-adds, not one dense
+    product per expert."""
+    m, k, n, e = 7680, 3072, 1024, 8
+    shapes = _shapes(one_chip, ((m, k), jnp.bfloat16),
+                     ((e, k, n), jnp.bfloat16), ((e,), jnp.int32))
+    compiled = jax.jit(jax.lax.ragged_dot).lower(*shapes).compile()
+    assert compiled.cost_analysis()["flops"] < 1.1 * 2 * m * k * n
+    assert "ragged-dot" in compiled.as_text()
+
+
 # ----------------------------------------------------------- flash decode
 @pytest.mark.parametrize("b,h,dh,c", [(4, 4, 32, 512), (8, 8, 128, 1024)])
 def test_flash_decode_dense(one_chip, b, h, dh, c):
