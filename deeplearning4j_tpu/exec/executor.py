@@ -364,13 +364,14 @@ class Executor:
         return get_programs()
 
     def register_program(self, caller, key, fn, args, compile_seconds=None,
-                         scopes=False):
+                         scopes=False, remat_kept_bytes=None):
         """Record a program built by :meth:`jit` (single-device ``jax.jit``
         results and mesh wrappers both work); see
         ``programs.ProgramRegistry.record``."""
         return self.programs.record(caller, key, fn, args,
                                     compile_seconds=compile_seconds,
-                                    scopes=scopes)
+                                    scopes=scopes,
+                                    remat_kept_bytes=remat_kept_bytes)
 
 
 # ------------------------------------------------------- process default

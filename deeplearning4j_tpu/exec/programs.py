@@ -18,6 +18,10 @@ What this buys:
 - ``op_scopes``, a step program's table from instruction name to the
   named scopes it was traced under (util/scopes.py): what turns a device
   trace's compiler-made operation names into layer and phase.
+- ``remat_kept_bytes``, a step program's count under ``remat="blocks"`` of
+  the bytes its blocks keep for the backward pass beside their inputs, by
+  name (util/remat.py), and the ``dl4jtpu_remat_kept_bytes`` gauge
+  labelled ``{caller,key,name}``: a name that reads 0 is replayed.
 - ``bench.py`` MFU rows read flops from here instead of re-deriving them
   with a private lowering helper.
 
@@ -194,6 +198,7 @@ class ProgramRegistry:
         self._lock = threading.Lock()
         self._programs = {}        # (caller, key) -> record dict
         self._gauges = None
+        self._kept = None          # the remat_kept_bytes gauge family
 
     def _metric(self, record):
         if self._gauges is None:
@@ -219,21 +224,31 @@ class ProgramRegistry:
                     "produced the program (AOT relower time if unmeasured)",
                     labelnames=("caller", "key")),
             }
+            self._kept = reg.gauge(
+                "dl4jtpu_remat_kept_bytes",
+                "bytes a step program's blocks keep for the backward pass "
+                "beside their inputs under remat='blocks', by name",
+                labelnames=("caller", "key", "name"))
         lbl = {"caller": record["caller"], "key": record["key"]}
         for field, fam in self._gauges.items():
             v = record.get(field)
             if v is not None:
                 fam.labels(**lbl).set(v)
+        for name, v in (record.get("remat_kept_bytes") or {}).items():
+            self._kept.labels(name=name, **lbl).set(v)
 
     def record(self, caller: str, key: str, fn, args,
                compile_seconds: Optional[float] = None,
-               scopes: bool = False) -> Optional[dict]:
+               scopes: bool = False,
+               remat_kept_bytes: Optional[dict] = None) -> Optional[dict]:
         """Register program ``(caller, key)``; re-registration of a known
         key is a no-op (returns the existing record). Analysis failures
         degrade to a record with None fields rather than raising into
         the caller's hot path. ``scopes`` keeps the record's ``op_scopes``
         table (the containers' step programs ask for it; a serving bucket
-        has no reader for one)."""
+        has no reader for one). ``remat_kept_bytes``: what the caller
+        counted while it traced the program (a graph's step under
+        ``remat="blocks"``), kept as the record's field of that name."""
         caller, key = str(caller), str(key)
         with self._lock:
             existing = self._programs.get((caller, key))
@@ -256,6 +271,8 @@ class ProgramRegistry:
             **fields,
             "compile_seconds": (compile_seconds if compile_seconds is not None
                                 else fields["aot_seconds"]),
+            "remat_kept_bytes": (dict(remat_kept_bytes)
+                                 if remat_kept_bytes is not None else None),
         }
         with self._lock:
             # lost a race: keep the first registration

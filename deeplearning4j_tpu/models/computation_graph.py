@@ -33,7 +33,8 @@ def _dtype_of(name):
 
 
 from deeplearning4j_tpu.util.scopes import layer_scope
-from deeplearning4j_tpu.util.remat import remat_segments
+from deeplearning4j_tpu.util.remat import (BLOCK_KEPT, block_checkpoint,
+                                           counting_kept, remat_segments)
 from deeplearning4j_tpu.util.dtypes import (cast_floats as _cast_floats,
                                              restore_dtypes as _restore_dtypes)
 
@@ -63,6 +64,7 @@ class ComputationGraph:
         self._fused = None            # fused update plan (nn/fused_update.py)
         self._update_step = None      # standalone donated update program
         self._compile_count = 0       # train programs traced (see _note_compile)
+        self._remat_kept = None       # remat='blocks': bytes kept, by name
         self._flight = None           # FlightRecorder (monitor/flight.py)
         self._train_mon = None        # lazy TrainMonitor (metric children)
         self._exec = None             # execution core (lazy; exec/executor.py)
@@ -232,6 +234,9 @@ class ComputationGraph:
         if not by_block:
             run(order, params, state, acts, new_state)
         else:
+            # bytes the blocks keep beside their inputs, by name, of the
+            # step being traced: the program's registry record carries them
+            kept = self._remat_kept = dict.fromkeys(BLOCK_KEPT, 0)
             for names, outs in remat_segments(self.conf):
                 if outs is None:
                     run(names, params, state, acts, new_state)
@@ -244,10 +249,11 @@ class ComputationGraph:
                     run(names, p, s, a, ns)
                     return {o: a[o] for o in outs}, ns
 
-                got, ns = jax.checkpoint(block)(
-                    {n: params[n] for n in names if n in params},
-                    {n: state[n] for n in names if n in state},
-                    {i: acts[i] for i in needs})
+                with counting_kept(kept):
+                    got, ns = block_checkpoint(block)(
+                        {n: params[n] for n in names if n in params},
+                        {n: state[n] for n in names if n in state},
+                        {i: acts[i] for i in needs})
                 acts.update(got)
                 new_state.update(ns)
         if cdt is not None:
@@ -509,7 +515,8 @@ class ComputationGraph:
                 self._scan_fit,
                 (self.params, self.state, self.opt_state, inputs_steps,
                  labels_steps, jnp.asarray(self.iteration, jnp.int32)),
-                compile_seconds=time.perf_counter() - t0, scopes=True)
+                compile_seconds=time.perf_counter() - t0, scopes=True,
+                remat_kept_bytes=self._remat_kept)
         if self.listeners:
             with trace.span("callback"):
                 for lst in self.listeners:
@@ -839,7 +846,8 @@ class ComputationGraph:
                     (self.params, self.state, self.opt_state, inputs, labels,
                      jnp.asarray(self.iteration, jnp.int32), masks,
                      label_masks),
-                    compile_seconds=self._last_fit_time, scopes=True)
+                    compile_seconds=self._last_fit_time, scopes=True,
+                    remat_kept_bytes=self._remat_kept)
         self.iteration += 1
         self._epoch_batch += 1
         self._mon.record(seconds=self._last_fit_time, steps=1,

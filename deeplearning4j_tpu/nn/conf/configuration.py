@@ -64,7 +64,9 @@ class GlobalConf:
     # PR 1; not measured since) — the role cudnn workspace tuning plays in
     # the reference's helper seam
     # 'blocks' (graphs): one jax.checkpoint around each run of nodes named
-    # ``<block>.<node>``; only the blocks' inputs are kept (util/remat.py)
+    # ``<block>.<node>``; a block's input is kept and, of what runs inside
+    # it, the values its layers name through util/remat.py:keep (the
+    # decoder's: BLOCK_KEPT there); the rest is replayed
     remat: object = False   # False|True|'full'|'save_convs'|'selective'|'blocks'
     weight_noise: Optional[object] = None  # IWeightNoise (DropConnect/...)
 

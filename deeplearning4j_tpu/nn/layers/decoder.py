@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, require_dims
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.util.remat import keep
 
 
 def _seq_n_in(input_type):
@@ -65,8 +66,9 @@ class RMSNorm(Layer):
 def swiglu(x, wg, wu, wd):
     """(silu(x Wg) * (x Wu)) Wd. The two products come out in x's dtype (a
     float32 copy of a (tokens, width) activation is the step's largest
-    buffer); the gate itself is taken in float32."""
-    g, u = jnp.dot(x, wg), jnp.dot(x, wu)
+    buffer); the gate itself is taken in float32. The two products are
+    kept across a block's replay (util/remat.py)."""
+    g, u = keep(jnp.dot(x, wg), "gate_up"), keep(jnp.dot(x, wu), "gate_up")
     h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
     return jnp.dot(h.astype(x.dtype), wd)
 
@@ -252,6 +254,9 @@ class RotaryGQAttention(Layer):
         v = heads(params["Wv"], self.n_kv_heads)
         if self.rotary:
             q, k = apply_rotary(q, self.rotary), apply_rotary(k, self.rotary)
+        # what the kernel's backward pass reads: a block's replay runs
+        # neither the three projections nor rotary again (util/remat.py)
+        q, k, v = (keep(a, "qkv") for a in (q, k, v))
         with jax.named_scope("attend"):
             o = self._attend(q, k, v).transpose(0, 2, 1, 3)  # (B, T, H, Dh)
         if self.head_gate:
@@ -274,25 +279,57 @@ class RotaryGQAttention(Layer):
 _ROUND_SLACK = 1.5
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(s, k):
+    """``jax.lax.top_k`` whose values and indices are kept across a block's
+    replay (util/remat.py). ``top_k``'s own derivative reads the indices
+    that it returns itself, so a name put on them afterwards keeps nothing
+    and the replay would run it again; here the backward pass reads the
+    named indices."""
+    return tuple(jax.lax.top_k(s, k))
+
+
+def _top_k_fwd(s, k):
+    val, idx = (keep(a, "routing") for a in jax.lax.top_k(s, k))
+    return (val, idx), (idx, jax.ShapeDtypeStruct(s.shape, s.dtype))
+
+
+def _top_k_bwd(k, res, ct):
+    idx, s = res
+    return jax.linear_transpose(
+        lambda t: jnp.take_along_axis(t, idx, axis=-1), s)(ct[0])
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def route_top_k(x2, wr, k, norm_topk, routed_scale):
     """Softmax over all experts, the k largest, their weights. x2: (N, C).
-    Returns (idx (N, k) int32, p (N, k) float32)."""
-    s = jax.nn.softmax(
-        jnp.dot(x2, wr, preferred_element_type=jnp.float32), axis=-1)
-    val, idx = jax.lax.top_k(s, k)
+    Returns (idx (N, k) int32, p (N, k) float32). The router's product is
+    kept across a block's replay (util/remat.py), named before the softmax
+    because softmax's derivative reads its own result, not a name put on
+    it; the top k are kept by ``_top_k``."""
+    s = jax.nn.softmax(keep(
+        jnp.dot(x2, wr, preferred_element_type=jnp.float32), "routing"),
+        axis=-1)
+    val, idx = _top_k(s, k)
     if norm_topk:
         val = val / val.sum(axis=-1, keepdims=True)
     return idx.astype(jnp.int32), val * routed_scale
 
 
-def _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts, ends):
+def _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts, ends,
+                  kept=False):
     """Round ``r`` of the routed part: rows ``r*rows .. (r+1)*rows`` of the
     pairs sorted by expert go through the three grouped products and are
     added to their tokens, weighted. x2: (N, C); eg, eu: (E, C, W); ed:
     (E, W, C); wgt, tok: weight and token of every sorted row (token N for
-    a row past the last pair); starts, ends: each expert's rows. Returns
-    ((N, C) float32, the pairs this round computed: rows inside an expert's
-    group that carry a token, int32)."""
+    a row past the last pair); starts, ends: each expert's rows. ``kept``:
+    the gate and up products are kept across a block's replay
+    (util/remat.py): round 0, which every step runs; the later rounds are
+    loops, where a name keeps nothing. Returns ((N, C) float32, the pairs
+    this round computed: rows inside an expert's group that carry a token,
+    int32)."""
     n, c = x2.shape
     lo = r * rows
     with jax.named_scope("dispatch"):
@@ -306,6 +343,8 @@ def _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts, ends):
         def gmm(a, w, out=None):
             return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=out)
         g, u = gmm(xs, eg), gmm(xs, eu)
+        if kept:
+            g, u = keep(g, "expert_gate_up"), keep(u, "expert_gate_up")
         h = (jax.nn.silu(g.astype(jnp.float32))
              * u.astype(jnp.float32)).astype(x2.dtype)
         ys = gmm(h, ed, jnp.float32)
@@ -460,9 +499,11 @@ class ExpertLayer(Layer):
         with jax.named_scope("dispatch"):
             local = (idx - first).reshape(-1)
             key = jnp.where((local >= 0) & (local < count), local, count)
-            order = jnp.argsort(key, stable=True)
-            counts = (key[:, None] == jnp.arange(count)[None, :]).sum(
-                axis=0, dtype=jnp.int32)
+            # the sort and the group sizes: a block's replay (util/remat.py)
+            # neither sorts nor counts again
+            order = keep(jnp.argsort(key, stable=True), "routing")
+            counts = keep((key[:, None] == jnp.arange(count)[None, :]).sum(
+                axis=0, dtype=jnp.int32), "routing")
             ends = jnp.cumsum(counts)
             starts, total = ends - counts, ends[-1]
             pad = rounds * rows - n * k
@@ -477,7 +518,7 @@ class ExpertLayer(Layer):
 
         args = (x2, params["Eg"], params["Eu"], params["Ed"], wgt, tok,
                 starts, ends)
-        y, done = _expert_round(rows, 0, *args)
+        y, done = _expert_round(rows, 0, *args, kept=True)
         if rounds > 1:
             later, more = _later_rounds(rows, *args, total)
             y, done = y + later, done + more

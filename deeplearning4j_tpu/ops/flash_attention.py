@@ -23,6 +23,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.util.remat import keep
+
 _NEG = -1e30
 
 
@@ -452,7 +454,10 @@ def gqa_flash_attention(q, k, v, window=None, block=None, interpret=False):
 
 
 def _gqa_fwd(q, k, v, window, block, interpret):
-    o, lse = _gqa_fwd_call(q, k, v, window, block, interpret)
+    # named inside the forward rule, so that output and residuals are the
+    # named values and a block's replay (util/remat.py) runs no kernel
+    o, lse = (keep(a, "attn_out")
+              for a in _gqa_fwd_call(q, k, v, window, block, interpret))
     return o, (q, k, v, o, lse)
 
 

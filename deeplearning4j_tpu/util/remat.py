@@ -5,7 +5,11 @@ See GlobalConf.remat (nn/conf/configuration.py) for the modes.
 
 from __future__ import annotations
 
+import contextlib
+import threading
+
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
 _MODES = (False, True, "full", "save_convs", "selective", "blocks")
 
@@ -28,8 +32,9 @@ def remat_loss(loss_fn, mode):
     mean and inverse deviation (``batch_norm_train`` tags them "bn_stats":
     a few KB a layer that spare the replay a reduction over activations);
     'blocks' → unchanged here: the containers' forward wraps each block of
-    ``remat_segments`` in a ``jax.checkpoint`` of its own, so the backward
-    pass keeps the blocks' inputs and replays one block at a time."""
+    ``remat_segments`` in a ``block_checkpoint`` of its own, so the backward
+    pass keeps each block's input and the ``BLOCK_KEPT`` values, and
+    replays the rest of one block at a time."""
     if not mode or mode == "blocks":
         return loss_fn          # 'blocks': the checkpoints are in the forward
     if mode in (True, "full"):
@@ -41,6 +46,52 @@ def remat_loss(loss_fn, mode):
                 "conv_out", "bn_stats"))
     check_remat_mode(mode)                     # raises; not a known mode
     raise AssertionError("unreachable")
+
+
+# What a block's replay under 'blocks' does not run again, by the name the
+# decoder's layers (nn/layers/decoder.py, ops/flash_attention.py) give it
+# through ``keep``: q, k after rotary and v; the attention kernel's output
+# and log-sum-exp; the router's product, its top k with their indices, the
+# sort of the pairs and the group sizes; round 0's grouped gate and up
+# products; a SwiGLU's gate and up products (a dense layer's and a shared
+# expert's). Each costs the replay a kernel, a sort or a matrix product and
+# is small beside what a step holds. Left to the replay: what is cheap to
+# compute again (norms, the head gate, silu(g) * u, the gather of the expert
+# rows) and the output projection, whose result is as large as q and spares
+# one product.
+BLOCK_KEPT = ("qkv", "attn_out", "routing", "expert_gate_up", "gate_up")
+
+_counting = threading.local()
+
+
+def keep(x, name):
+    """``x`` under ``name`` for a checkpoint policy: the identity wherever
+    no policy names it. While ``counting_kept`` is open its bytes are added
+    to that count."""
+    into = getattr(_counting, "into", None)
+    if into is not None:
+        into[name] = into.get(name, 0) + x.size * x.dtype.itemsize
+    return checkpoint_name(x, name)
+
+
+@contextlib.contextmanager
+def counting_kept(into):
+    """While open on this thread, ``keep`` sums the bytes it names into the
+    dict ``into``, by name."""
+    prev = getattr(_counting, "into", None)
+    _counting.into = into
+    try:
+        yield into
+    finally:
+        _counting.into = prev
+
+
+def block_checkpoint(block):
+    """``block`` as one replay unit of 'blocks': its arguments and the
+    ``BLOCK_KEPT`` values inside it are kept for the backward pass."""
+    return jax.checkpoint(
+        block,
+        policy=jax.checkpoint_policies.save_only_these_names(*BLOCK_KEPT))
 
 
 def block_of(name):

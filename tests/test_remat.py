@@ -4,6 +4,7 @@ storing them — on TPU this is faster for HBM-bound conv models and is the
 bench configuration for ResNet50; these tests pin that it changes NOTHING
 numerically."""
 
+import os
 import re
 
 import numpy as np
@@ -11,11 +12,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deeplearning4j_tpu import MultiLayerNetwork, NeuralNetConfiguration
+from deeplearning4j_tpu import MultiLayerNetwork, NeuralNetConfiguration, ops
+from deeplearning4j_tpu.data.dataset import DataSet
 from deeplearning4j_tpu.nn.layers import (ConvolutionLayer, DenseLayer,
                                           BatchNormalization, OutputLayer)
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.updaters import Adam
+from perfbench.lib import arch
+from perfbench.jobs import fit_lm
 
 
 def _conf(remat):
@@ -211,3 +215,182 @@ def test_batchnorm_step_on_four_devices_equals_the_single_device_step():
     assert one[1] and set(many[1]) == set(one[1])
     for k, v in one[1].items():
         np.testing.assert_allclose(many[1][k], v, rtol=1e-6, atol=1e-6)
+
+
+def _primitives(jaxpr, into=None):
+    """How often each primitive appears in ``jaxpr``, inner jaxprs (a
+    checkpoint's, a loop's, a kernel's) included."""
+    into = {} if into is None else into
+    for e in jaxpr.eqns:
+        into[e.primitive.name] = into.get(e.primitive.name, 0) + 1
+        for v in e.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else (v,)):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    _primitives(j, into)
+    return into
+
+
+def _replays(wrap, name):
+    """Whether the gradient of ``sin(name(sin(x)))`` under ``wrap`` runs
+    the inner ``sin`` again: False where the wrapper's policy keeps
+    ``name``."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def f(x):
+        return jnp.sin(checkpoint_name(jnp.sin(x), name)).sum()
+
+    n = _primitives(jax.make_jaxpr(jax.grad(wrap(f)))(jnp.ones(3)).jaxpr)
+    return {2: False, 3: True}[n["sin"]]
+
+
+@pytest.mark.parametrize("name", ["conv_out", "bn_stats", "qkv", "attn_out",
+                                  "routing", "expert_gate_up", "gate_up",
+                                  "anything_else"])
+def test_each_policy_keeps_its_own_names_and_no_other(name):
+    """``save_convs`` keeps what it kept before ``blocks`` had names of its
+    own, a block keeps ``BLOCK_KEPT``, and full remat keeps nothing."""
+    from deeplearning4j_tpu.util import remat
+    assert _replays(lambda f: remat.remat_loss(f, "save_convs"), name) \
+        == (name not in ("conv_out", "bn_stats"))
+    assert _replays(remat.block_checkpoint, name) \
+        == (name not in remat.BLOCK_KEPT)
+    assert _replays(lambda f: remat.remat_loss(f, True), name)
+    assert remat.remat_loss(abs, "blocks") is abs
+
+
+def test_keep_counts_bytes_only_while_counting():
+    from deeplearning4j_tpu.util import remat
+    x = jnp.ones((4, 8), jnp.bfloat16)
+    assert remat.keep(x, "qkv") is not None       # no count open: no error
+    seen = {"qkv": 0}
+    with remat.counting_kept(seen):
+        y = jax.jit(lambda a: remat.keep(a, "qkv") + remat.keep(
+            a.astype(jnp.float32), "gate_up"))(x)
+    remat.keep(x, "qkv")
+    assert seen == {"qkv": 64, "gate_up": 128}
+    np.testing.assert_array_equal(np.asarray(y), 2.0)
+
+
+# ---------------------------------------------- what a block's replay keeps
+
+HD, KV = 8, 2        # the rehearsal's head_dim and kv heads
+BLOCKS = [("full_attention", "dense"), ("full_attention", "sparse"),
+          ("sliding_attention", "dense"), ("sliding_attention", "sparse")]
+HEADS = 6            # of each of the two blocks below, on the 2 kv heads
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    """The benchmark's decoder configuration at its rehearsal size."""
+    return arch.load_config(
+        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), "perfbench", "configs", "laguna-s-2.1.json"),
+        rehearse=True)
+
+
+@pytest.fixture
+def kernels():
+    """The attention kernel interpreted, as the chip runs it compiled."""
+    prev = ops.set_helpers_enabled(True, interpret=True)
+    yield
+    ops.set_helpers_enabled(prev[0], interpret=prev[1])
+
+
+def _two_blocks(cfg, kind, mlp, remat):
+    """A ``SparseDecoder`` of two blocks of one sort at the rehearsal's
+    widths (hidden 32, head_dim 8, 16 experts of which 4 are held), with a
+    batch of 2 x 32 ids and the gradient of its loss."""
+    from deeplearning4j_tpu.zoo.decoder import SparseDecoder
+    keys = dict(cfg, **cfg["rehearsal"]["model"])
+    keys.update(num_experts=16, layer_types=[kind] * 2,
+                mlp_layer_types=[mlp] * 2,
+                num_attention_heads_per_layer=[HEADS] * 2)
+    net = SparseDecoder(keys, seed=3, experts_held=(4, 0), remat=remat).init()
+    ids, labels = fit_lm.make_pool(cfg, {"pool_batches": 1}, 4, 2, 32)[0]
+    loss = net._loss_for_grad()
+
+    def grad(params):
+        return jax.value_and_grad(loss, has_aux=True)(
+            params, net.state, [jnp.asarray(ids)], [jnp.asarray(labels)],
+            jax.random.PRNGKey(0), None, None)
+
+    return net, (ids, labels), grad
+
+
+@pytest.mark.parametrize("kind,mlp", BLOCKS)
+def test_blocks_keep_the_gradient_and_the_state_what_they_are(
+        cfg, kernels, kind, mlp):
+    got = {}
+    for remat in (False, "blocks"):
+        net, _, grad = _two_blocks(cfg, kind, mlp, remat)
+        (loss, state), grads = jax.jit(grad)(net.params)
+        got[remat] = (loss, state, grads)
+    assert float(got[False][0]) == pytest.approx(float(got["blocks"][0]),
+                                                 rel=1e-6)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7),
+        got[False][1:], got["blocks"][1:])
+    pairs = [int(s["pairs"]) for s in got["blocks"][1].values()
+             if s and "pairs" in s]
+    assert len(pairs) == (2 if mlp == "sparse" else 0) and all(pairs)
+
+
+@pytest.mark.parametrize("kind,mlp", BLOCKS)
+def test_a_block_replay_runs_no_kernel_sort_or_kept_product_again(
+        cfg, kernels, kind, mlp):
+    """In the gradient's jaxpr: the attention kernel's three passes, one
+    sort and one ``top_k`` a block, as without replay; of the grouped
+    products only the down product, whose result the pair weights' gradient
+    reads; of the dense products only the head gate and the output
+    projection. A name that stops keeping its value fails here."""
+    seen = {}
+    for remat in (False, "blocks"):
+        net, _, grad = _two_blocks(cfg, kind, mlp, remat)
+        seen[remat] = _primitives(jax.make_jaxpr(grad)(net.params).jaxpr)
+    plain, blocks = seen[False], seen["blocks"]
+    sparse = 2 if mlp == "sparse" else 0
+    assert blocks.get("remat2") == 2 and "remat2" not in plain
+    assert blocks["pallas_call"] == plain["pallas_call"] == 3 * 2
+    assert blocks.get("sort", 0) == plain.get("sort", 0) == sparse
+    assert blocks.get("top_k", 0) == plain.get("top_k", 0) == sparse
+    assert blocks.get("ragged_dot_general", 0) \
+        == plain.get("ragged_dot_general", 0) + sparse
+    assert (plain.get("ragged_dot_general", 0) > 0) == bool(sparse)
+    assert blocks["dot_general"] == plain["dot_general"] + 2 * 2
+
+
+@pytest.mark.parametrize("kind,mlp", BLOCKS)
+def test_the_registry_says_what_the_blocks_keep(cfg, kernels, kind, mlp):
+    """Bytes by name of the step program's record and of the
+    ``dl4jtpu_remat_kept_bytes`` gauge against a count by hand: float32,
+    2 x 32 tokens, hidden 32, 6 + 2 + 2 heads of 8, 16 experts, top 3."""
+    from deeplearning4j_tpu.exec.programs import get_programs
+    from deeplearning4j_tpu.monitor.metrics import get_registry
+    net, (ids, labels), _ = _two_blocks(cfg, kind, mlp, "blocks")
+    net.fit(iter([DataSet(ids, labels)]))
+    n, f32 = 2 * 32, 4
+    want = {"qkv": 2 * n * (HEADS + 2 * KV) * HD * f32,
+            "attn_out": 2 * n * HEADS * (HD + 1) * f32,     # o and lse
+            "routing": 0, "expert_gate_up": 0,
+            "gate_up": 2 * 2 * n * 64 * f32}
+    if mlp == "sparse":
+        rows, _ = net.conf.nodes["b0.mlp"].layer.round_rows(n)
+        want.update(
+            # the router's product, the top 3 and their indices, the sort
+            # of the pairs, the group sizes
+            routing=2 * (n * 16 + 2 * n * 3 + n * 3 + 4) * f32,
+            expert_gate_up=2 * 2 * rows * 16 * f32,
+            gate_up=2 * 2 * n * 16 * f32)                   # shared expert
+    rec = get_programs().last(net._prog_caller)
+    assert rec["key"].startswith("train_step") \
+        and rec["remat_kept_bytes"] == want
+    fam = get_registry().get("dl4jtpu_remat_kept_bytes")
+    mine = {k: c.value for k, c in fam.children()
+            if net._prog_caller in k and rec["key"] in k}
+    assert sorted(mine.values()) == sorted(want.values())
+    plain, _, _ = _two_blocks(cfg, kind, mlp, False)
+    plain.fit(iter([DataSet(ids, labels)]))
+    assert get_programs().last(
+        plain._prog_caller)["remat_kept_bytes"] is None
