@@ -37,7 +37,6 @@ containers register (see ``_apply_updates_jitted``).
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -58,18 +57,15 @@ FUSE_MAX_MEMBER_SIZE = 1 << 23
 
 
 def fused_update_enabled() -> bool:
-    """Fused updates are on by default; ``DL4JTPU_FUSED_UPDATE=0`` (env)
-    or ``set_fused_update(False)`` forces the legacy per-leaf path. Read
-    at optimizer-build time — call ``_build_optimizer()`` after toggling."""
-    if _OVERRIDE is not None:
-        return _OVERRIDE
-    return os.environ.get("DL4JTPU_FUSED_UPDATE", "1").lower() not in (
-        "0", "false", "off", "no")
+    """Fused updates are on; ``set_fused_update(False)`` forces the per-leaf
+    path. Read at optimizer-build time — call ``_build_optimizer()`` after
+    toggling."""
+    return _OVERRIDE is not False
 
 
 def set_fused_update(flag: Optional[bool]) -> None:
-    """Process-wide override (None restores the env default). Used by the
-    bench fused-vs-per-leaf sub-row and tests; rebuild optimizers after."""
+    """Process-wide override (None restores the default). Used by the bench
+    fused-vs-per-leaf sub-row and tests; rebuild optimizers after."""
     global _OVERRIDE
     _OVERRIDE = flag
 

@@ -389,7 +389,7 @@ class ParallelWrapper:
 
             # auto-chunk runs of scan-able batches onto the device-resident
             # sharded multi-step path (same design as
-            # MultiLayerNetwork._fit_stream: one compiled call per chunk
+            # BaseNetwork._fit_stream: one compiled call per chunk
             # instead of one host dispatch per minibatch)
             chunkable = (getattr(model.conf, "backprop_type", "standard")
                          != "tbptt")

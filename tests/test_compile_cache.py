@@ -101,39 +101,60 @@ def test_routing_ignores_a_table_in_the_cache_dir(tmp_path, monkeypatch,
         routing._file_loaded = saved[2]
 
 
-@pytest.mark.parametrize("container", ["mln", "graph"])
-def test_streamed_fit_batch_is_split_over_four_devices(container):
-    """``fit(iterator)`` on a 4-device mesh: what the prefetcher hands the
-    step already has one shard per device along the batch axis."""
-    from deeplearning4j_tpu import NeuralNetConfiguration, MultiLayerNetwork
-    from deeplearning4j_tpu.data.iterators import ExistingDataSetIterator
-    from deeplearning4j_tpu.data.dataset import DataSet
-    from deeplearning4j_tpu.exec import Executor, build_mesh
+def _small_net(container, **global_conf):
+    """Dense 5 -> 8 -> 3 as a list of layers or as a graph."""
+    from deeplearning4j_tpu import (ComputationGraph, MultiLayerNetwork,
+                                    NeuralNetConfiguration)
     from deeplearning4j_tpu.nn.conf.inputs import InputType
     from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
     from deeplearning4j_tpu.nn.updaters import Sgd
 
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 devices")
-    B, steps = 64, 4
+    b = NeuralNetConfiguration.builder().seed(0).updater(Sgd(0.1))
+    for name, value in global_conf.items():
+        b = getattr(b, name)(value)
     if container == "mln":
-        conf = (NeuralNetConfiguration.builder().seed(0).updater(Sgd(0.1))
-                .list()
+        conf = (b.list()
                 .layer(DenseLayer(n_out=8, activation="relu"))
                 .layer(OutputLayer(n_out=3, activation="softmax",
                                    loss="mcxent"))
                 .set_input_type(InputType.feed_forward(5)).build())
-        net = MultiLayerNetwork(conf).init()
-    else:
-        from deeplearning4j_tpu import ComputationGraph
-        conf = (NeuralNetConfiguration.builder().seed(0).updater(Sgd(0.1))
-                .graph_builder().add_inputs("in")
-                .add_layer("d", DenseLayer(n_out=8, activation="relu"), "in")
-                .add_layer("out", OutputLayer(n_out=3, activation="softmax",
-                                              loss="mcxent"), "d")
-                .set_outputs("out")
-                .set_input_types(InputType.feed_forward(5)).build())
-        net = ComputationGraph(conf).init()
+        return MultiLayerNetwork(conf).init()
+    conf = (b.graph_builder().add_inputs("in")
+            .add_layer("d", DenseLayer(n_out=8, activation="relu"), "in")
+            .add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                          loss="mcxent"), "d")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(5)).build())
+    return ComputationGraph(conf).init()
+
+
+@pytest.mark.parametrize("container", ["mln", "graph"])
+def test_remat_blocks_is_a_graphs_mode(container):
+    """Blocks are runs of graph nodes; a list of layers has none and says
+    so, where the mode would do nothing in silence."""
+    if container == "mln":
+        with pytest.raises(ValueError, match="a list of layers has"):
+            _small_net(container, remat="blocks")
+        return
+    from deeplearning4j_tpu.data.dataset import DataSet
+    net = _small_net(container, remat="blocks")
+    net.fit(DataSet(np.zeros((4, 5), np.float32),
+                    np.eye(3, dtype=np.float32)[[0, 1, 2, 0]]))
+    assert np.isfinite(net.get_score())
+
+
+@pytest.mark.parametrize("container", ["mln", "graph"])
+def test_streamed_fit_batch_is_split_over_four_devices(container):
+    """``fit(iterator)`` on a 4-device mesh: what the prefetcher hands the
+    step already has one shard per device along the batch axis."""
+    from deeplearning4j_tpu.data.iterators import ExistingDataSetIterator
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.exec import Executor, build_mesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    B, steps = 64, 4
+    net = _small_net(container)
     net._exec = Executor(mesh=build_mesh(jax.devices()[:4]))
 
     rs = np.random.RandomState(0)

@@ -163,7 +163,7 @@ class TestSingleDevicePath:
         net.fit(DataSet(x, y))
         net.fit(DataSet(x, y))
         assert net._compile_count == 1
-        step = net._train_step[next(iter(net._train_step))]
+        step = net._train_step_cache[(False, False)]
         assert not hasattr(step, "_dl4jtpu_exec_wrapper")
 
     @pytest.mark.mesh8
@@ -174,7 +174,7 @@ class TestSingleDevicePath:
         net.fit(DataSet(x, y))
         net.fit(DataSet(x, y))
         assert net._compile_count == 1
-        step = net._train_step[next(iter(net._train_step))]
+        step = net._train_step_cache[(False, False)]
         assert step._dl4jtpu_exec_wrapper
         assert set(step._exec_cache) == {False}
 
@@ -186,7 +186,7 @@ class TestSingleDevicePath:
         xl, yl = _batch(128)
         net.fit(DataSet(xl, yl))
         net.fit(DataSet(xl, yl))
-        step = net._train_step[next(iter(net._train_step))]
+        step = net._train_step_cache[(False, False)]
         assert set(step._exec_cache) == {False, True}
         assert net._compile_count == 2
 
@@ -209,7 +209,7 @@ class TestShardedParity:
             x, y = _batch(b, seed=i)
             net1.fit(DataSet(x, y))
             net8.fit(DataSet(x, y))
-        step = net8._train_step[next(iter(net8._train_step))]
+        step = net8._train_step_cache[(False, False)]
         assert set(step._exec_cache) == {True}
         for p1, p8 in zip(net1.params, net8.params):
             for k in p1:

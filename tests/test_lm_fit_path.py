@@ -20,6 +20,7 @@ from deeplearning4j_tpu.nn.layers import (DenseLayer, EmbeddingSequenceLayer,
 from deeplearning4j_tpu.nn.updaters import Adam
 from deeplearning4j_tpu.util import chunking
 from deeplearning4j_tpu.util.remat import remat_segments, block_of
+from deeplearning4j_tpu.util.timing import PipelineTimer
 from perfbench.lib import arch, scopes
 from perfbench.jobs import fit_lm
 
@@ -154,7 +155,9 @@ def test_raw_uint8_images_keep_their_chunk_in_a_container():
     net = MultiLayerNetwork(conf).init()
     ds = DataSet(np.zeros((128, 784), np.uint8),
                  np.zeros((128, 10), np.float32))
-    assert net._chunk_len(ds) == 64
+    kind, (xs, ys) = next(net._stream_chunks([ds] * 65, None,
+                                             PipelineTimer()))
+    assert kind == "chunk" and xs.shape == (64, 128, 784)
 
 
 def test_heavy_token_batches_go_singly_through_fit(monkeypatch):

@@ -324,7 +324,7 @@ def test_blocks_keep_the_gradient_and_the_state_what_they_are(
     got = {}
     for remat in (False, "blocks"):
         net, _, grad = _two_blocks(cfg, kind, mlp, remat)
-        (loss, state), grads = jax.jit(grad)(net.params)
+        (loss, (state, _)), grads = jax.jit(grad)(net.params)
         got[remat] = (loss, state, grads)
     assert float(got[False][0]) == pytest.approx(float(got["blocks"][0]),
                                                  rel=1e-6)

@@ -110,16 +110,10 @@ def _train(kind, net, data):
     else:
         for d in data:
             net.fit(d)
-        x, y = jnp.asarray(data[0].features), jnp.asarray(data[0].labels)
-        it = jnp.asarray(0, jnp.int32)
-        if kind == "graph":
-            fn = net._train_step_cache[(False, False)]
-            args = (net.params, net.state, net.opt_state, [x], [y], it,
-                    None, None)
-        else:
-            fn = net._train_step[(False, False)]
-            args = (net.params, net.state, net.opt_state, x, y, it,
-                    None, None, None)
+        x, y = net._batch_parts(data[0], jnp.asarray)[:2]
+        fn = net._train_step_cache[(False, False)]
+        args = (net.params, net.state, net.opt_state, x, y,
+                jnp.asarray(0, jnp.int32), None, None)
     return _lowerable(fn).lower(*args)
 
 
@@ -222,6 +216,39 @@ def test_registry_keeps_scopes_aot_seconds_and_counts_donation_once():
         - mem.alias_size_in_bytes)
 
 
+@pytest.mark.parametrize("chunked", [False, True])
+@pytest.mark.parametrize("kind", ["mln", "graph"])
+def test_streamed_fit_leaves_its_program_in_the_registry(kind, chunked):
+    """Whichever container, whichever program the chunk rule picks: what
+    ``step_program_hbm_share`` and ``train_compile_s`` read is there."""
+    net = _MAKE[kind]()
+    if not chunked:
+        net._CHUNK_MAX_BYTES = 1
+    net.fit(iter(_batches(3)))
+    rec = get_programs().last(net._prog_caller)
+    assert rec["key"] == ("fit_scan_k3_b4" if chunked else "train_step_b4")
+    assert rec["memory_bytes"] > 0 and rec["aot_seconds"] > 0
+    assert any(scopes.classify(o)[0] == "updater"
+               for o in rec["op_scopes"].values())
+
+
+@pytest.mark.parametrize("kind", ["mln", "graph"])
+def test_a_mixed_stream_is_cut_the_same_way_under_both_containers(kind):
+    """A run of one shape, a shape change, a masked batch, a last one alone:
+    ``_stream_chunks`` is one function, and a batch's form does not show in
+    where it cuts."""
+    from deeplearning4j_tpu.util.timing import PipelineTimer
+    masked = _batches(1)[0]
+    masked.labels_mask = np.ones(4, np.float32)
+    data = _batches(3) + _batches(2, b=2) + [masked] + _batches(1)
+    net = _MAKE[kind]()
+    got = [(k, [len(a) for a in jax.tree_util.tree_leaves(payload[:2])])
+           for k, payload in net._stream_chunks(iter(data), None,
+                                                PipelineTimer())]
+    assert got == [("chunk", [3, 3]), ("chunk", [2, 2]), ("batch", [4, 4]),
+                   ("batch", [4, 4])]
+
+
 # ------------------------------ B. the spans on the profiler's clock
 
 def _host_events(log_dir):
@@ -242,7 +269,7 @@ def _host_events(log_dir):
 @pytest.mark.parametrize("tracer_on", [True, False])
 def test_fit_spans_land_in_a_level1_profile(tracer_on, tmp_path):
     net = _conv_mln()
-    net._chunk_len = lambda ds: 1          # one train_step a batch
+    net._CHUNK_MAX_BYTES = 1               # one train_step a batch
     net.fit(_batches(1)[0])                # compile outside the capture
     options = jax.profiler.ProfileOptions()
     options.host_tracer_level = 1
@@ -307,10 +334,7 @@ def test_profile_scope_restores_the_tracer(tmp_path, monkeypatch):
 @pytest.mark.parametrize("kind", ["mln", "graph"])
 def test_pipeline_stats_count_steps_bytes_and_flight(kind):
     net = _MAKE[kind]()
-    if kind == "mln":
-        net._chunk_len = lambda ds: 1
-    else:
-        net._CHUNK_MAX_BYTES = 1
+    net._CHUNK_MAX_BYTES = 1
     data = _batches(5)
     net.fit(iter(data))
     st = net.last_pipeline_stats
@@ -324,8 +348,6 @@ def test_pipeline_stats_count_steps_bytes_and_flight(kind):
     assert st["dispatch_sec"] >= 0 and "step_sec" not in st
     # a chunked call is one dispatch of several steps
     net._CHUNK_MAX_BYTES = 256 << 20
-    if kind == "mln":
-        del net._chunk_len
     net.fit(iter(data))
     st = net.last_pipeline_stats
     assert st["steps"] == 5 and st["in_flight_max"] == 1
@@ -339,9 +361,7 @@ def test_fit_iterator_with_score_listeners_counts_flight(kind, chunked):
     from deeplearning4j_tpu.optimize.listeners import (
         CollectScoresIterationListener, ScoreIterationListener)
     net = _MAKE[kind]()
-    if not chunked and kind == "mln":
-        net._chunk_len = lambda ds: 1
-    elif not chunked:
+    if not chunked:
         net._CHUNK_MAX_BYTES = 1
     data = _batches(6)
     every, second = CollectScoresIterationListener(1), \
