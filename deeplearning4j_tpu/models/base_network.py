@@ -684,8 +684,11 @@ class BaseNetwork:
         timer.steps = self.iteration - it0
         self.last_pipeline_stats = timer.summary()
         timer.publish("fit")
+        # an expert layer's tokens: the (batch, time) positions of a step
+        shown = jax.tree_util.tree_leaves(self._last_input)
         self._mon.publish_expert_counters(
-            {k: self._layer(k) for k in _by_key(self.state)}, self.state)
+            {k: self._layer(k) for k in _by_key(self.state)}, self.state,
+            tokens=int(np.prod(shown[0].shape[:2])) if shown else 0)
 
     def _fit_batch(self, batch):
         """One step on one batch: a ``DataSet``, the container's own batch

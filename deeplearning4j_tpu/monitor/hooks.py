@@ -70,13 +70,14 @@ class TrainMonitor:
         else:
             self._hist[path].observe(seconds)
 
-    def publish_expert_counters(self, layers, state) -> None:
+    def publish_expert_counters(self, layers, state, tokens=0) -> None:
         """At the end of a streamed fit call: what the expert layers counted
         inside the steps (their state, which the step returns), as
         ``dl4jtpu_moe_*`` labelled by layer. ``layers``: key -> layer,
-        ``state``: the container's state under the same keys. One host read
-        of four scalars a layer; a model without expert layers reads
-        nothing."""
+        ``state``: the container's state under the same keys; ``tokens``:
+        the tokens a layer saw in the last step (0: not known, and the
+        rounds are not published). One host read of four scalars a layer;
+        a model without expert layers reads nothing."""
         keys = [k for k in layers if state[k] and "pairs_total" in state[k]]
         if not keys:
             return
@@ -100,7 +101,13 @@ class TrainMonitor:
                     lab),
                 "load_mean": reg.gauge(
                     "dl4jtpu_moe_expert_load_mean",
-                    "Pairs per held expert in the last step.", lab)}
+                    "Pairs per held expert in the last step.", lab),
+                "rounds_last": reg.gauge(
+                    "dl4jtpu_moe_rounds_last",
+                    "Rounds of the sorted-pair buffer that the last step's "
+                    "routing needed: 1 where the first round held every "
+                    "pair, above 1 where the step paid for later rounds.",
+                    lab)}
             self._moe_seen = {}
         got = jax.device_get({k: state[k] for k in keys})
         for k in keys:
@@ -113,3 +120,7 @@ class TrainMonitor:
             self._moe["load_max"].labels(**lab).set(int(got[k]["load_max"]))
             self._moe["load_mean"].labels(**lab).set(
                 int(got[k]["pairs"]) / layers[k].held[0])
+            if tokens:
+                rows, _ = layers[k].round_rows(tokens)
+                self._moe["rounds_last"].labels(**lab).set(
+                    max(1, -(-int(got[k]["pairs"]) // rows)))

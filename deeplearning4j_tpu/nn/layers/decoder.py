@@ -318,26 +318,39 @@ def route_top_k(x2, wr, k, norm_topk, routed_scale):
     return idx.astype(jnp.int32), val * routed_scale
 
 
-def _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts, ends,
+# a derivative taken inside a derivative rule wraps the first scope it meets
+# (``jvp(round)``): this one takes the wrapper, so that ``dispatch``,
+# ``experts`` and ``combine`` keep the names device traces are read by
+@jax.named_scope("round")
+def _expert_round(rows, k, r, x2, eg, eu, ed, pw, order, starts, ends, total,
                   kept=False):
-    """Round ``r`` of the routed part: rows ``r*rows .. (r+1)*rows`` of the
-    pairs sorted by expert go through the three grouped products and are
-    added to their tokens, weighted. x2: (N, C); eg, eu: (E, C, W); ed:
-    (E, W, C); wgt, tok: weight and token of every sorted row (token N for
-    a row past the last pair); starts, ends: each expert's rows. ``kept``:
-    the gate and up products are kept across a block's replay
-    (util/remat.py): round 0, which every step runs; the later rounds are
-    loops, where a name keeps nothing. Returns ((N, C) float32, the pairs
-    this round computed: rows inside an expert's group that carry a token,
-    int32)."""
+    """Round ``r`` of the routed part: ``rows`` of the pairs sorted by
+    expert go through the three grouped products and are added to their
+    tokens, weighted. x2: (N, C); eg, eu: (E, C, W); ed: (E, W, C); pw: the
+    pairs' weights, flat (N*k,); order: the pairs sorted by expert (N*k,),
+    of which the first ``total`` fall on experts held; starts, ends: each
+    expert's sorted rows. The round takes what it needs from ``order`` and
+    ``pw`` itself, so nothing is sized by the rounds there could be. It owns
+    sorted rows ``r*rows`` and up; its buffer is the window of ``order``
+    that starts there, or that ends with ``order`` if that comes first (the
+    last round of a routing that fills every round), and a row before its
+    own or past the last held pair points past the tokens: the gathers fill
+    it with zeros and the scatters drop it. ``kept``: the gate and up
+    products are kept across a block's replay (util/remat.py): round 0,
+    which every step runs; the later rounds are loops, where a name keeps
+    nothing. Returns ((N, C) float32, the pairs this round computed: rows
+    inside an expert's group that carry a token, int32)."""
     n, c = x2.shape
     lo = r * rows
     with jax.named_scope("dispatch"):
-        t_r = jax.lax.dynamic_slice(tok, (lo,), (rows,))
-        w_r = jax.lax.dynamic_slice(wgt, (lo,), (rows,))
-        sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
-        # a row past the last pair points past the tokens: the gather fills
-        # it with zeros and the scatter drops it
+        at = jnp.minimum(lo, n * k - rows)
+        o_r = jax.lax.dynamic_slice(order, (at,), (rows,))
+        rank = at + jnp.arange(rows, dtype=jnp.int32)
+        own = (rank >= lo) & (rank < total)
+        t_r = jnp.where(own, (o_r // k).astype(jnp.int32), n)
+        w_r = jnp.take(pw, jnp.where(own, o_r, n * k), mode="fill",
+                       fill_value=0)
+        sizes = jnp.clip(ends, at, at + rows) - jnp.clip(starts, at, at + rows)
         xs = jnp.take(x2, t_r, axis=0, mode="fill", fill_value=0)
     with jax.named_scope("experts"):
         def gmm(a, w, out=None):
@@ -356,48 +369,82 @@ def _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts, ends,
             done
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _later_rounds(rows, x2, eg, eu, ed, wgt, tok, starts, ends, total):
-    """Rounds 1.. of the routed part, as many as the pairs left need: none
-    under a routing that fits the first round. The trip count is the
-    data's, so both passes are loops of their own: nothing is kept per
-    round, and the backward pass runs each round again for its gradient.
-    Returns (y, the pairs these rounds computed)."""
-    return _later_fwd(rows, x2, eg, eu, ed, wgt, tok, starts, ends, total)[0]
+def _rounds_needed(rows, total):
+    return (total + rows - 1) // rows
 
 
-def _later_fwd(rows, x2, eg, eu, ed, wgt, tok, starts, ends, total):
+def _later_rounds(rows, rounds, k, first, diff, rest):
+    """Rounds 1.. added to round 0's ``first`` = (y, pairs computed): as
+    many as the pairs left need, none under a routing that fits round 0.
+    ``rounds``: how many the worst routing needs (``round_rows``): a layer
+    whose round 0 holds that traces no loop. ``diff``: x2, eg, eu, ed, pw;
+    ``rest``: order, starts, ends, total."""
+    if rounds == 1:
+        return first
+
     def body(r, acc):
-        y, done = _expert_round(rows, r, x2, eg, eu, ed, wgt, tok, starts,
-                                ends)
+        y, done = _expert_round(rows, k, r, *diff, *rest)
         return acc[0] + y, acc[1] + done
 
-    out = jax.lax.fori_loop(
-        1, (total + rows - 1) // rows, body,
-        (jnp.zeros(x2.shape, jnp.float32), jnp.zeros((), jnp.int32)))
-    return out, (x2, eg, eu, ed, wgt, tok, starts, ends, total)
+    return jax.lax.fori_loop(1, _rounds_needed(rows, rest[-1]), body, first)
 
 
-def _later_bwd(rows, res, ct):
-    x2, eg, eu, ed, wgt, tok, starts, ends, total = res
-    diff = (x2, eg, eu, ed, wgt)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _expert_rounds(rows, rounds, k, x2, eg, eu, ed, pw, order, starts, ends,
+                   total):
+    """Every round of the routed part that the routing needs: round 0, then
+    a loop whose trip count is the data's. One derivative rule over all of
+    them, so that a step whose routing fits round 0 pays for round 0 alone:
+    the later rounds' loop is carried from round 0's result, and their
+    gradients are added to round 0's only where one ran (a rule for the
+    later rounds alone would hand autodiff zero gradients in the shape of
+    x2, eg, eu and ed to write and add in every step). Returns (y, the
+    pairs computed)."""
+    diff, rest = (x2, eg, eu, ed, pw), (order, starts, ends, total)
+    return _later_rounds(rows, rounds, k,
+                         _expert_round(rows, k, 0, *diff, *rest), diff, rest)
+
+
+def _rounds_fwd(rows, rounds, k, x2, eg, eu, ed, pw, order, starts, ends,
+                total):
+    diff, rest = (x2, eg, eu, ed, pw), (order, starts, ends, total)
+    # round 0 is left to autodiff, whose residuals a block's replay keeps
+    # by name; the later rounds keep nothing and run again backward
+    y, back, done = jax.vjp(
+        lambda *d: _expert_round(rows, k, 0, *d, *rest, kept=True), *diff,
+        has_aux=True)
+    return _later_rounds(rows, rounds, k, (y, done), diff, rest), \
+        (back, diff, rest)
+
+
+def _rounds_bwd(rows, rounds, k, res, ct):
+    back, diff, rest = res
     dy = ct[0]                     # the count is an integer: no cotangent
+    grads = back(dy)
 
-    def body(r, acc):
-        _, vjp = jax.vjp(
-            lambda *d: _expert_round(rows, r, *d, tok, starts, ends)[0],
-            *diff)
-        return jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(jnp.float32), acc, vjp(dy))
+    def later(grads):
+        """Round 0's gradients plus those of rounds 1.., summed in
+        float32."""
+        def body(r, acc):
+            _, vjp = jax.vjp(
+                lambda *d: _expert_round(rows, k, r, *d, *rest)[0], *diff)
+            return jax.tree_util.tree_map(
+                lambda a, g: a + g.astype(jnp.float32), acc, vjp(dy))
 
-    acc = jax.lax.fori_loop(
-        1, (total + rows - 1) // rows, body,
-        tuple(jnp.zeros(d.shape, jnp.float32) for d in diff))
-    return tuple(a.astype(d.dtype) for a, d in zip(acc, diff)) \
-        + (None, None, None, None)
+        acc = jax.lax.fori_loop(
+            1, _rounds_needed(rows, rest[-1]), body,
+            tuple(g.astype(jnp.float32) for g in grads))
+        return tuple(a.astype(g.dtype) for a, g in zip(acc, grads))
+
+    # a branch, not the loop alone: seeded with round 0's gradients the
+    # loop would widen them to float32 and narrow them again in a step that
+    # runs no later round
+    if rounds > 1:
+        grads = jax.lax.cond(rest[-1] > rows, later, lambda g: g, grads)
+    return grads + (None,) * len(rest)
 
 
-_later_rounds.defvjp(_later_fwd, _later_bwd)
+_expert_rounds.defvjp(_rounds_fwd, _rounds_bwd)
 
 
 @register_layer
@@ -506,22 +553,10 @@ class ExpertLayer(Layer):
                 axis=0, dtype=jnp.int32), "routing")
             ends = jnp.cumsum(counts)
             starts, total = ends - counts, ends[-1]
-            pad = rounds * rows - n * k
-            rank = jnp.arange(rounds * rows, dtype=jnp.int32)
-            if pad > 0:
-                order = jnp.concatenate([order, jnp.zeros((pad,), order.dtype)])
-            order = order[:rounds * rows]
-            # a row past the last held pair points past the tokens: the
-            # gather fills it with zeros and the scatter drops it
-            tok = jnp.where(rank < total, (order // k).astype(jnp.int32), n)
-            wgt = jnp.where(rank < total, p.reshape(-1)[order], 0.0)
 
-        args = (x2, params["Eg"], params["Eu"], params["Ed"], wgt, tok,
-                starts, ends)
-        y, done = _expert_round(rows, 0, *args, kept=True)
-        if rounds > 1:
-            later, more = _later_rounds(rows, *args, total)
-            y, done = y + later, done + more
+        y, done = _expert_rounds(
+            rows, rounds, k, x2, params["Eg"], params["Eu"], params["Ed"],
+            p.reshape(-1), order, starts, ends, total)
         # ``done`` is counted by the rounds that ran, so a round left out
         # reads as pairs dropped
         return y, {"pairs": total, "pairs_dropped": total - done,

@@ -326,8 +326,8 @@ def validate_gqa_attention_case(b, hq, hkv, t, dh, window,
 
 def validate_expert_rounds_case(n, c, n_experts, top_k, width, count,
                                 dtype="bfloat16", tol=2e-2, time_it=True):
-    """The expert layer's overflow rounds (the ``custom_vjp`` of two
-    ``fori_loop``s, which an even routing never enters) against a plain
+    """The expert layer's overflow rounds (the two ``fori_loop``s of
+    ``_expert_rounds``, which an even routing never enters) against a plain
     loop over the experts held with a mask, in float32 at ``highest``:
     the router is set so that every token picks every expert held, the
     worst the layer can see, so every later round runs. Output and the

@@ -191,6 +191,98 @@ def test_a_round_left_out_reads_as_pairs_dropped(monkeypatch):
     assert int(cut["pairs_dropped"]) == last > 0
 
 
+# classes of tokens by the experts they pick of 16, top 3, experts 0-3 held:
+# three held experts, two, one, none. (tokens of each class) -> pairs held
+_PICKS = [(0, 1, 2), (0, 1, 8), (0, 8, 9), (8, 9, 10)]
+ROUTINGS = {"even": (4, 10, 16, 34),          # 48 pairs: round 0 alone
+            "one_over": (9, 23, 0, 32),       # 73: one pair in round 1
+            "worst": (64, 0, 0, 0)}           # 192: every round, to its end
+
+
+def _routed_case(tokens_by_class):
+    """64 tokens whose first four features say which experts they pick,
+    and a router that reads them; noise elsewhere so that no score ties."""
+    layer = _expert_layer((4, 0))
+    p = {k: v for k, v in layer.init(jax.random.PRNGKey(5)).items()
+         if k[0] != "S"}
+    cls = np.repeat(np.arange(4), tokens_by_class)
+    np.random.RandomState(3).shuffle(cls)
+    x = 0.1 * np.asarray(_rand((64, C), 11))
+    x[:, :4] = 10.0 * np.eye(4, dtype=np.float32)[cls]
+    wr = 0.05 * np.asarray(p["Wr"])
+    for c, picks in enumerate(_PICKS):
+        wr[c, list(picks)] += 1.0
+    p["Wr"] = jnp.asarray(wr)
+    pairs = int(np.dot(tokens_by_class, (3, 2, 1, 0)))
+    return layer, p, jnp.asarray(x), pairs
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_routed_value_and_gradients_under_one_round_two_and_all(routing):
+    """The routed part and its gradients for x, Wr, Eg, Eu, Ed against the
+    dense oracle in float32: the pair weights reach Wr through each round's
+    own gather, and the later rounds' gradients join round 0's only where a
+    later round runs."""
+    layer, p, x, pairs = _routed_case(ROUTINGS[routing])
+    rows, rounds = layer.round_rows(64)
+    assert (rows, rounds) == (72, 3) and rounds * rows > 64 * 3
+    y, seen = layer.routed(p, x)
+    assert int(seen["pairs"]) == pairs and int(seen["pairs_dropped"]) == 0
+    assert -(-pairs // rows) == {"even": 1, "one_over": 2, "worst": 3}[routing]
+    cot = _rand((64, C), 12)
+
+    def plain(p, x):
+        return ref.experts(x, p, top_k=3, held=(4, 0), routed_scale=2.5,
+                           norm_topk=True)[0]
+
+    _close(y, plain(p, x))
+    got = jax.jit(jax.grad(
+        lambda p, x: (layer.routed(p, x)[0] * cot).sum(), (0, 1)))(p, x)
+    want = jax.grad(lambda p, x: (plain(p, x) * cot).sum(), (0, 1))(p, x)
+    assert sorted(got[0]) == ["Ed", "Eg", "Eu", "Wr"]
+    assert float(jnp.abs(want[0]["Wr"]).max()) > 1e-3
+    jax.tree_util.tree_map(lambda a, b: _close(a, b, 1e-3), got, want)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` with the jaxpr it sits in, inner jaxprs
+    (a branch's, a loop's, a derivative rule's) included."""
+    for e in jaxpr.eqns:
+        yield jaxpr, e
+        for v in e.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else (v,)):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from _eqns(j)
+
+
+def test_the_routed_part_is_sized_by_the_round_and_zeroes_nothing():
+    """In the jaxpr of the routed part's value and gradient, at a shape
+    with three rounds: no array has a dimension of rounds * rows, and no
+    loop is handed float32 zeros in the shape of x, Eg, Eu or Ed (the
+    later rounds start from round 0's result and from its gradients)."""
+    layer, p, x, _ = _routed_case(ROUTINGS["even"])
+    rows, rounds = layer.round_rows(64)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, x: layer.routed(p, x)[0].sum(), (0, 1)))(p, x).jaxpr
+    big = {tuple(a.shape) for a in (x, p["Eg"], p["Eu"], p["Ed"])}
+    loops = 0
+    for inside, e in _eqns(jaxpr):
+        for v in e.outvars:
+            assert rounds * rows not in getattr(v.aval, "shape", ()), e
+        if e.primitive.name != "while":
+            continue
+        loops += 1
+        made_by = {id(v): q for q in inside.eqns for v in q.outvars}
+        for v in e.invars:
+            q = made_by.get(id(v))
+            assert not (q is not None
+                        and q.primitive.name == "broadcast_in_dim"
+                        and v.aval.dtype == jnp.float32
+                        and tuple(v.aval.shape) in big), q
+    assert loops == 2               # the later rounds, forward and backward
+
+
 def test_the_chip_screen_of_the_overflow_rounds_runs_small():
     from deeplearning4j_tpu.ops import validate
     r = validate.validate_expert_rounds_case(*validate.EXPERT_QUICK[0],
@@ -279,3 +371,27 @@ def test_expert_counters_at_the_fit_boundary(cfg):
     assert all(c.value == 0 for _, c in dropped.children())
     assert reg.get("dl4jtpu_moe_expert_load_max") is not None
     assert reg.get("dl4jtpu_moe_expert_load_mean") is not None
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+def test_the_rounds_gauge_says_when_a_step_paid_for_later_rounds(cfg, skewed):
+    """``dl4jtpu_moe_rounds_last`` after a streamed ``fit()``: 1 under the
+    even routing of random weights; above 1 with the first expert layer's
+    router turned so that half of the tokens pick three held experts."""
+    from deeplearning4j_tpu.monitor.metrics import get_registry
+    net = _net(cfg)
+    if skewed:
+        wr = np.zeros(net.params["b1.mlp"]["Wr"].shape, np.float32)
+        wr[:, :3] = 5.0
+        net.params["b1.mlp"]["Wr"] = jnp.asarray(wr)
+    pool = _batches(cfg, 2)
+    net.fit(iter([DataSet(*b) for b in pool]))
+    layer = net.conf.nodes["b1.mlp"].layer
+    rows, rounds = layer.round_rows(2 * 32)
+    pairs = int(net.state["b1.mlp"]["pairs"])
+    fam = get_registry().get("dl4jtpu_moe_rounds_last")
+    mine = {k: c.value for k, c in fam.children() if "b1.mlp" in k}
+    assert len(mine) == 1 and rounds > 1
+    assert list(mine.values()) == [max(1, -(-pairs // rows))]
+    assert (pairs > rows) == skewed
+    assert int(net.state["b1.mlp"]["pairs_dropped_total"]) == 0
