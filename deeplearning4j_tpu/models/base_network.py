@@ -686,9 +686,11 @@ class BaseNetwork:
         timer.publish("fit")
         # an expert layer's tokens: the (batch, time) positions of a step
         shown = jax.tree_util.tree_leaves(self._last_input)
+        layers = {k: self._layer(k) for k in _by_key(self.state)}
         self._mon.publish_expert_counters(
-            {k: self._layer(k) for k in _by_key(self.state)}, self.state,
+            layers, self.state,
             tokens=int(np.prod(shown[0].shape[:2])) if shown else 0)
+        self._mon.publish_selection_counters(layers, self.state)
 
     def _fit_batch(self, batch):
         """One step on one batch: a ``DataSet``, the container's own batch

@@ -193,7 +193,9 @@ class ComputationGraph(BaseNetwork):
                     p_out, pre_act_input, labels[oi], lm,
                     train=True, rng=lrng)
             for name, p in params.items():
-                total = total + self.conf.nodes[name].layer.reg_loss(p)
+                layer = self.conf.nodes[name].layer
+                total = total + (layer.reg_loss(p)
+                                 + layer.loss_term(new_state.get(name)))
             if self._compute_dtype(True) is not None:
                 total = total.astype(jnp.float32)
         return total, (new_state, new_carries)

@@ -134,8 +134,8 @@ class MultiLayerNetwork(BaseNetwork):
             loss = out_layer.compute_score(p_out, act, y, mask_l,
                                            train=True, rng=lrng)
             reg = 0.0
-            for l, p in zip(self.layers, params):
-                reg = reg + l.reg_loss(p)
+            for l, p, st in zip(self.layers, params, new_states):
+                reg = reg + (l.reg_loss(p) + l.loss_term(st))
             loss = loss + reg
             if self._compute_dtype(True) is not None:
                 loss = loss.astype(jnp.float32)

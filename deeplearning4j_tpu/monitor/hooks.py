@@ -27,6 +27,7 @@ class TrainMonitor:
         lab = {"model": model_kind}
         self._kind = model_kind
         self._moe = None              # dl4jtpu_moe_* families, on first use
+        self._sel = None              # dl4jtpu_sparse_attention_*, likewise
         self.steps = reg.counter(
             "dl4jtpu_train_steps_total",
             "Train steps executed (fit_scan counts every scanned step).",
@@ -124,3 +125,48 @@ class TrainMonitor:
                 rows, _ = layers[k].round_rows(tokens)
                 self._moe["rounds_last"].labels(**lab).set(
                     max(1, -(-int(got[k]["pairs"]) // rows)))
+
+    def publish_selection_counters(self, layers, state) -> None:
+        """At the end of a streamed fit call: what the attention layers
+        that select their keys counted inside the steps (their state), as
+        ``dl4jtpu_sparse_attention_keys_{selected,visible}_total`` and
+        ``dl4jtpu_index_loss`` labelled by layer. The totals are (low,
+        high) uint32 words in the state and exact here. One host read a
+        layer; a model without such layers reads nothing."""
+        keys = [k for k in layers
+                if state[k] and "keys_selected_total" in state[k]]
+        if not keys:
+            return
+        import jax
+        if self._sel is None:
+            reg = get_registry()
+            lab = ("model", "layer")
+            self._sel = {
+                "keys_selected_total": reg.counter(
+                    "dl4jtpu_sparse_attention_keys_selected_total",
+                    "(query, key) pairs the layer attended over training "
+                    "steps: the keys its indexer selected.", lab),
+                "keys_visible_total": reg.counter(
+                    "dl4jtpu_sparse_attention_keys_visible_total",
+                    "(query, key) pairs a causal layer without a selection "
+                    "would have attended over the same steps.", lab),
+                "index_loss": reg.gauge(
+                    "dl4jtpu_index_loss",
+                    "The indexer's own loss in the last step: the mean over "
+                    "queries of the KL from the attention's head-mean "
+                    "weights to the indexer's distribution on the selected "
+                    "keys.", lab)}
+            self._sel_seen = {}
+        got = jax.device_get({k: state[k] for k in keys})
+        for k in keys:
+            lab = {"model": self._kind, "layer": str(layers[k].name or k)}
+            for name in ("keys_selected_total", "keys_visible_total"):
+                lo, hi = (int(w) for w in got[k][name])
+                now = (hi << 32) | lo
+                last = self._sel_seen.get((k, name), 0)
+                # a state set back to zero starts the sum again
+                self._sel[name].labels(**lab).inc(
+                    now - last if now >= last else now)
+                self._sel_seen[(k, name)] = now
+            self._sel["index_loss"].labels(**lab).set(
+                float(got[k]["index_loss"]))

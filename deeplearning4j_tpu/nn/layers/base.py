@@ -105,6 +105,14 @@ class Layer:
     # dataclass field.
     positional_state_keys = ()
 
+    # A layer's own term of the step's loss: the key of the scalar in the
+    # state its training-mode ``apply`` returns, which both containers'
+    # ``_loss`` add to the output layers' scores (an attention layer's
+    # indexer loss; an expert layer's balancing loss would come the same
+    # way). None: no term. Plain class attribute or property, not a
+    # dataclass field.
+    loss_state = None
+
     def init_decode_state(self, params, batch: int, max_len: int,
                           dtype=jnp.float32):
         """Per-slot decode state for a batch of ``batch`` concurrent
@@ -271,7 +279,15 @@ class Layer:
         m = jax.random.bernoulli(rng, keep, x.shape)
         return jnp.where(m, x / keep, 0.0)
 
-    # ---- regularization: container sums these into the loss --------------
+    # ---- the layer's own loss term and regularization: the container sums
+    # ---- these into the loss
+    def loss_term(self, new_state):
+        """The scalar under ``loss_state`` of the state this layer's
+        training-mode ``apply`` returned, or 0.0."""
+        if self.loss_state and new_state:
+            return new_state[self.loss_state]
+        return 0.0
+
     def reg_loss(self, params):
         l1 = self.l1 or 0.0
         l2 = self.l2 or 0.0

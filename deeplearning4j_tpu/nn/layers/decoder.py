@@ -1,6 +1,7 @@
 """Layers of a sparse decoder block: RMSNorm, rotary grouped-query
-attention with a window and a head gate, a SwiGLU MLP, and a dropless
-top-k expert layer that holds a share of the experts.
+attention with a window, a head gate or a learned selection of keys (an
+indexer with a loss of its own), a SwiGLU MLP, and a dropless top-k expert
+layer that holds a share of the experts.
 
 The reference (DL4J 0.9.2) has none of them. Each is a plain ``Layer``: the
 containers hold it, ``model_serializer`` writes it, ``util/scopes.py`` names
@@ -172,6 +173,247 @@ def banded_attention(q, k, v, window=None):
     return jnp.einsum("bkgqs,bksd->bkgqd", p, v).reshape(b, hq, t, dh)
 
 
+# ---------------------------------------------- a learned selection of keys
+# An indexer (J small heads over one shared key head) scores every visible
+# key of a query, I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]); the query
+# attends the ``top_k`` keys of largest score (all of them while t < top_k;
+# a tie at the last place goes to the lower position). The selection is not
+# differentiable: the indexer learns from the KL of its distribution over
+# the selected keys to the main attention's head-mean weights there
+# (arXiv:2512.02556, the sparse training stage), and the main parameters
+# never see it. Scores exist a chunk of query rows at a time, and only over
+# the keys a chunk can see.
+
+_INDEX_ROWS = 512          # query rows scored at once
+_INDEX_GROUPS = 8          # row ranges, each scoring keys up to its end
+
+
+def _row_chunks(t, rows=None):
+    """(rows of a chunk, [(first chunk, chunks, key extent)]): the T query
+    rows in chunks, the chunks in at most ``_INDEX_GROUPS`` runs; a run
+    scores the keys before its own end, so that about half of the square
+    above the diagonal is never computed."""
+    if rows is None or t % rows:
+        rows = next((r for r in (_INDEX_ROWS, 256, 128, 64, 32, 16, 8)
+                     if t % r == 0), t)
+    n = t // rows
+    per = -(-n // _INDEX_GROUPS)
+    return rows, [(c, min(per, n - c), (c + min(per, n - c)) * rows)
+                  for c in range(0, n, per)]
+
+
+@jax.named_scope("index")
+def index_scores(qi, wi, ki):
+    """I = sum_j w_j relu(q_j . k): qi (R, J, D), wi (R, J) float32,
+    ki (S, D) -> (R, S) float32. Under the scope ``index`` wherever it is
+    called (for the selection, and for the indexer's loss with its
+    derivative), so that a trace finds every index product there."""
+    s = jnp.einsum("rjd,sd->jrs", qi, ki, preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * wi.T[:, :, None]).sum(axis=0)
+
+
+@jax.jit
+def _select_rows(scores, first, top_k):
+    """The selection of one chunk of query rows: scores (R, S) float32 of
+    rows ``first..first+R-1`` against keys 0..S-1 -> (R, S) int8, 1 on the
+    ``top_k`` visible keys of largest score of each row (every visible key
+    of a row that sees fewer), a tie at the last place to the lower
+    position. Exact, by bisection on the scores' bits: 32 counting passes
+    over the chunk find the top_k-th largest value of every row, and where
+    some row has more keys at that value than places left, 14 more find
+    the position up to which they are taken."""
+    r, s = scores.shape
+    kpos = jnp.arange(s, dtype=jnp.int32)[None, :]
+    vis = kpos <= first + jnp.arange(r, dtype=jnp.int32)[:, None]
+    # float32 -> uint32 of the same order (-0.0 made +0.0 first); an
+    # invisible key is 0, under every visible one
+    b = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.uint32)
+    u = jnp.where(vis, jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31)),
+                  jnp.uint32(0))
+
+    def count(ok):
+        return ok.sum(axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def value_bit(i, res):
+        cand = res | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(u >= cand) >= top_k, cand, res)
+
+    # the largest value that top_k or more keys reach (0: the row sees
+    # fewer than top_k)
+    at = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((r, 1), jnp.uint32))
+    over, tie = u > at, (u == at) & vis
+    need = top_k - count(over)
+
+    def lowest(tie):
+        bits = max(int(s - 1).bit_length(), 1)
+
+        def pos_bit(i, x):
+            cand = x | (jnp.int32(1) << (bits - 1 - i))
+            return jnp.where(count(tie & (kpos < cand)) < need, cand, x)
+
+        # the largest position with fewer than ``need`` ties before it is
+        # the need-th tie's own
+        x = jax.lax.fori_loop(0, bits, pos_bit, jnp.zeros((r, 1), jnp.int32))
+        return tie & (kpos <= x)
+
+    tie = jax.lax.cond(jnp.any(count(tie) > need), lowest, lambda t: t, tie)
+    return (over | tie).astype(jnp.int8)
+
+
+def selected_keys_mask(qi, wi, ki, top_k, rows=None):
+    """The selection as a mask: qi (B, T, J, D), wi (B, T, J), ki (B, T, D)
+    -> (B, T, T) int8, 1 where the query (row) attends the key (column),
+    0 above the diagonal; chunk by chunk, the scores of one chunk alive at
+    a time."""
+    t = qi.shape[1]
+    rows, groups = _row_chunks(t, rows)
+
+    def one(args):
+        q1, w1, k1 = args
+        out = []
+        for c0, n, extent in groups:
+            firsts = (c0 + jnp.arange(n, dtype=jnp.int32)) * rows
+
+            def chunk(xs, extent=extent):
+                qc, wc, first = xs
+                if extent <= top_k:      # these rows see top_k keys or fewer
+                    kpos = jnp.arange(extent, dtype=jnp.int32)[None, :]
+                    return (kpos <= first + jnp.arange(
+                        rows, dtype=jnp.int32)[:, None]).astype(jnp.int8)
+                return _select_rows(index_scores(qc, wc, k1[:extent]), first,
+                                    top_k)
+
+            m = jax.lax.map(chunk, (
+                q1[c0 * rows:(c0 + n) * rows].reshape(n, rows, *q1.shape[1:]),
+                w1[c0 * rows:(c0 + n) * rows].reshape(n, rows, -1), firsts))
+            out.append(jnp.pad(m.reshape(n * rows, extent),
+                               ((0, 0), (0, t - extent))))
+        return jnp.concatenate(out, axis=0)
+
+    return jax.lax.map(one, (qi, wi, ki))
+
+
+def _kl_rows(qc, wc, k1, mc, pc):
+    """Sum over a chunk's rows of KL(P || softmax of the index scores over
+    the selected keys)."""
+    sel = mc != 0
+    logq = jax.nn.log_softmax(
+        jnp.where(sel, index_scores(qc, wc, k1), -jnp.inf), axis=-1)
+    return jnp.where(sel, jax.scipy.special.xlogy(pc, pc)
+                     - pc * jnp.where(sel, logq, 0.0), 0.0).sum()
+
+
+def _index_kl(qi, wi, ki, mask, p, rows, grads):
+    """mean over the B*T queries of KL(P[t] || indexer[t]) on the selected
+    keys and, with ``grads``, its gradient by qi, wi, ki, chunk by chunk:
+    a chunk's scores are made, differentiated and dropped before the
+    next's."""
+    b, t = qi.shape[:2]
+    rows, groups = _row_chunks(t, rows)
+
+    def one(args):
+        q1, w1, k1, m1, p1 = args
+        total, dk = 0.0, jnp.zeros(k1.shape, jnp.float32)
+        dq, dw = [], []
+        for c0, n, extent in groups:
+            span = slice(c0 * rows, (c0 + n) * rows)
+            xs = (q1[span].reshape(n, rows, *q1.shape[1:]),
+                  w1[span].reshape(n, rows, -1),
+                  m1[span, :extent].reshape(n, rows, extent),
+                  p1[span, :extent].reshape(n, rows, extent))
+            ks = k1[:extent]
+            if not grads:
+                total = total + jax.lax.map(
+                    lambda x: _kl_rows(x[0], x[1], ks, x[2], x[3]), xs).sum()
+                continue
+
+            def chunk(acc, x):
+                val, back = jax.vjp(
+                    lambda a, w, k: _kl_rows(a, w, k, x[2], x[3]),
+                    x[0], x[1], ks)
+                ga, gw, gk = back(jnp.ones((), val.dtype))
+                return acc + gk.astype(jnp.float32), (val, ga, gw)
+
+            gk, (val, ga, gw) = jax.lax.scan(
+                chunk, jnp.zeros(ks.shape, jnp.float32), xs)
+            total = total + val.sum()
+            dk = dk.at[:extent].add(gk)
+            dq.append(ga.reshape(n * rows, *q1.shape[1:]))
+            dw.append(gw.reshape(n * rows, -1))
+        if not grads:
+            return total
+        return total, jnp.concatenate(dq), jnp.concatenate(dw), dk
+
+    out = jax.lax.map(one, (qi, wi, ki, mask, p))
+    scale = 1.0 / (b * t)
+    if not grads:
+        return out.sum() * scale
+    loss, dq, dw, dk = out
+    return loss.sum() * scale, (
+        (dq * scale).astype(qi.dtype), (dw * scale).astype(wi.dtype),
+        (dk * scale).astype(ki.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def index_loss(qi, wi, ki, mask, p, rows=None):
+    """The indexer's own loss: the mean over queries of the KL from the
+    main attention's head-mean weights ``p`` (B, T, T) to the softmax of the
+    index scores, both over the keys ``mask`` selects. qi, wi, ki as
+    ``selected_keys_mask``. Differentiable in qi, wi, ki alone. Its
+    gradient is taken chunk by chunk in the forward pass, where each chunk's
+    scores exist anyway, and kept by name (util/remat.py), so that neither
+    the backward pass nor a block's replay scores a key again."""
+    return _index_kl(qi, wi, ki, mask, p, rows, False)
+
+
+def _index_loss_fwd(qi, wi, ki, mask, p, rows):
+    loss, g = _index_kl(qi, wi, ki, mask, p, rows, True)
+    return loss, tuple(keep(a, "index_grads") for a in g)
+
+
+def _index_loss_bwd(rows, g, ct):
+    return tuple((ct * a.astype(jnp.float32)).astype(a.dtype) for a in g) \
+        + (None, None)
+
+
+index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
+
+
+def selected_attention(q, k, v, mask):
+    """The plain path of attention over selected keys: a masked softmax over
+    the whole (T, T) score matrix. Shapes as ``gqa_selected_attention``.
+    Returns (output, head-mean weights (B, T, T) float32)."""
+    b, hq, t, dh = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, t, dh)
+    s = jnp.einsum("bkgqd,bksd->bkgqs", qg, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(dh)
+    p = jax.nn.softmax(
+        jnp.where((mask != 0)[:, None, None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(v.dtype), v)
+    return o.reshape(b, hq, t, dh), p.mean(axis=(1, 2))
+
+
+def _wide(n):
+    """A count known at trace time as a (lo, hi) pair of uint32."""
+    return jnp.asarray([n & 0xFFFFFFFF, n >> 32], jnp.uint32)
+
+
+def _add_wide(a, b):
+    """The sum of two (lo, hi) pairs of uint32."""
+    lo = a[0] + b[0]
+    return jnp.stack([lo, a[1] + b[1] + (lo < a[0]).astype(jnp.uint32)])
+
+
+def _fold_wide(per):
+    """The sum of up to 65,536 uint32 counts as a (lo, hi) pair of uint32."""
+    lo = (per & 0xFFFF).sum(dtype=jnp.uint32)
+    hi = (per >> 16).sum(dtype=jnp.uint32)
+    return _add_wide(jnp.stack([lo, jnp.zeros_like(lo)]),
+                     jnp.stack([hi << 16, hi >> 16]))
+
+
 # below this length the score matrix is small and XLA's fused path is used
 _KERNEL_MIN_SEQ = 1024
 
@@ -185,10 +427,26 @@ class RotaryGQAttention(Layer):
     optional ``window`` (key j is seen from query i only if i - j < window)
     and an optional sigmoid gate per head on the attention output
     (arXiv:2505.06708). No biases. Param keys: Wq (n_in, H*Dh), Wk, Wv
-    (n_in, Hkv*Dh), Wo (H*Dh, n_out), Wgate (n_in, H) with ``head_gate``.
+    (n_in, Hkv*Dh), Wo (H*Dh, n_out), Wgate (n_in, H) with ``head_gate``,
+    q_gamma, k_gamma (Dh,) with ``qk_norm`` (an RMSNorm over each head of q
+    and of k before rotary).
 
     ``rotary``: a dict as ``rotary_inv_freq`` reads it, or None.
-    The head count is the layer's own: layers of one model may differ."""
+    The head count is the layer's own: layers of one model may differ.
+
+    ``indexer``: ``{"heads": J, "head_dim": D, "top_k": n}`` gives the layer
+    a learned selection of keys (see the section above): every query attends
+    the ``top_k`` visible keys its indexer scores highest, one selection for
+    all heads. Param keys WqI (n_in, J*D), WkI (n_in, D), WwI (n_in, J),
+    kI_gamma, kI_beta (D,: a LayerNorm on the one index key head). The
+    indexer reads the layer's input cut from the graph and learns from
+    ``index_loss`` alone, which the layer hands back in its state under
+    ``loss_state`` for the container to add to the step's loss; the other
+    parameters never see that term. State (training steps): ``index_loss``
+    of the last step, and ``keys_selected``, ``keys_visible`` of the last
+    step and their running sums ``keys_selected_total``,
+    ``keys_visible_total``, each count as (low, high) uint32 words. A
+    training step of a layer with an indexer needs that state."""
     n_in: int = 0
     n_out: int = 0          # model dim (defaults to n_in)
     n_heads: int = 4
@@ -197,6 +455,13 @@ class RotaryGQAttention(Layer):
     window: Optional[int] = None
     rotary: Optional[dict] = None
     head_gate: bool = False
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    indexer: Optional[dict] = None
+
+    @property
+    def loss_state(self):
+        return "index_loss" if self.indexer else None
 
     def set_n_in(self, input_type):
         if self.n_in == 0:
@@ -216,7 +481,10 @@ class RotaryGQAttention(Layer):
                              f"n_kv_heads={self.n_kv_heads}")
         if self.rotary and int(self.rotary["dims"]) > self.head_dim:
             raise ValueError("rotary dims exceed head_dim")
-        k = jax.random.split(rng, 5)
+        if self.indexer and self.window is not None:
+            raise ValueError("a layer selects its keys by an indexer or by "
+                             "a window, not both")
+        k = jax.random.split(rng, 8)
         hq, hkv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         p = {"Wq": _w(self, k[0], (self.n_in, hq), dtype),
              "Wk": _w(self, k[1], (self.n_in, hkv), dtype),
@@ -224,21 +492,80 @@ class RotaryGQAttention(Layer):
              "Wo": _w(self, k[3], (hq, self.n_out), dtype)}
         if self.head_gate:
             p["Wgate"] = _w(self, k[4], (self.n_in, self.n_heads), dtype)
+        if self.qk_norm:
+            p["q_gamma"] = jnp.ones((self.head_dim,), dtype)
+            p["k_gamma"] = jnp.ones((self.head_dim,), dtype)
+        if self.indexer:
+            j, d = int(self.indexer["heads"]), int(self.indexer["head_dim"])
+            p.update(WqI=_w(self, k[5], (self.n_in, j * d), dtype),
+                     WkI=_w(self, k[6], (self.n_in, d), dtype),
+                     WwI=_w(self, k[7], (self.n_in, j), dtype),
+                     kI_gamma=jnp.ones((d,), dtype),
+                     kI_beta=jnp.zeros((d,), dtype))
         return p
+
+    def init_state(self, dtype=jnp.float32):
+        if not self.indexer:
+            return {}
+        # one buffer each: the step donates its state
+        return {"index_loss": jnp.zeros((), jnp.float32),
+                "keys_selected": jnp.zeros((2,), jnp.uint32),
+                "keys_visible": jnp.zeros((2,), jnp.uint32),
+                "keys_selected_total": jnp.zeros((2,), jnp.uint32),
+                "keys_visible_total": jnp.zeros((2,), jnp.uint32)}
+
+    def _kernel_path(self, t):
+        from deeplearning4j_tpu import ops
+        from deeplearning4j_tpu.exec.executor import tracing_partitioned
+        from deeplearning4j_tpu.ops.flash_attention import gqa_supported
+        return (ops.helpers_enabled() and not tracing_partitioned()
+                and gqa_supported(t, self.head_dim, self.n_heads,
+                                  self.n_kv_heads)
+                and (ops.interpret_mode() or t >= _KERNEL_MIN_SEQ))
 
     def _attend(self, q, k, v):
         from deeplearning4j_tpu import ops
-        from deeplearning4j_tpu.exec.executor import tracing_partitioned
-        from deeplearning4j_tpu.ops.flash_attention import (
-            gqa_flash_attention, gqa_supported)
-        t = q.shape[2]
-        if (ops.helpers_enabled() and not tracing_partitioned()
-                and gqa_supported(t, self.head_dim, self.n_heads,
-                                  self.n_kv_heads)
-                and (ops.interpret_mode() or t >= _KERNEL_MIN_SEQ)):
+        from deeplearning4j_tpu.ops.flash_attention import gqa_flash_attention
+        if self._kernel_path(q.shape[2]):
             return gqa_flash_attention(q, k, v, self.window, None,
                                        ops.interpret_mode())
         return banded_attention(q, k, v, self.window)
+
+    def _attend_selected(self, q, k, v, mask):
+        """(output, head-mean weights) over the keys ``mask`` selects."""
+        from deeplearning4j_tpu import ops
+        from deeplearning4j_tpu.ops.flash_attention import (
+            gqa_head_mean_probs, gqa_selected_attention)
+        if not self._kernel_path(q.shape[2]):
+            return selected_attention(q, k, v, mask)
+        o, lse = gqa_selected_attention(q, k, v, mask, None,
+                                        ops.interpret_mode())
+        with jax.named_scope("index_loss"):
+            p = gqa_head_mean_probs(*map(jax.lax.stop_gradient, (q, k, lse)),
+                                    mask, None, ops.interpret_mode())
+        return o, p
+
+    def _index(self, params, x):
+        """The indexer's queries (B, T, J, D), head weights (B, T, J)
+        float32 and keys (B, T, D), from the layer's input cut from the
+        graph."""
+        b, t, _ = x.shape
+        j, d = int(self.indexer["heads"]), int(self.indexer["head_dim"])
+        x = jax.lax.stop_gradient(x)
+        qi = (x @ params["WqI"]).reshape(b, t, j, d).transpose(0, 2, 1, 3)
+        ki = (x @ params["WkI"]).astype(jnp.float32)
+        ki = ki - ki.mean(axis=-1, keepdims=True)
+        ki = ki * jax.lax.rsqrt(
+            jnp.mean(ki * ki, axis=-1, keepdims=True) + self.norm_eps)
+        ki = (ki * params["kI_gamma"].astype(jnp.float32)
+              + params["kI_beta"].astype(jnp.float32)).astype(x.dtype)
+        if self.rotary:
+            rot = dict(self.rotary, dims=d)
+            qi = apply_rotary(qi, rot)
+            ki = apply_rotary(ki[:, None], rot)[:, 0]
+        wi = jnp.dot(x, params["WwI"], preferred_element_type=jnp.float32) \
+            / math.sqrt(j * d)
+        return qi.transpose(0, 2, 1, 3), wi, ki
 
     def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
         if mask is not None:
@@ -246,30 +573,82 @@ class RotaryGQAttention(Layer):
                              "sequences to full length")
         b, t, _ = x.shape
 
-        def heads(w, n):
-            return (x @ w).reshape(b, t, n, self.head_dim).transpose(0, 2, 1, 3)
+        def heads(w, n, gamma=None):
+            h = (x @ w).reshape(b, t, n, self.head_dim).transpose(0, 2, 1, 3)
+            return h if gamma is None else rms_norm(h, gamma, self.norm_eps)
 
-        q = heads(params["Wq"], self.n_heads)
-        k = heads(params["Wk"], self.n_kv_heads)
+        norm = self.qk_norm
+        q = heads(params["Wq"], self.n_heads, params["q_gamma"] if norm else None)
+        k = heads(params["Wk"], self.n_kv_heads,
+                  params["k_gamma"] if norm else None)
         v = heads(params["Wv"], self.n_kv_heads)
         if self.rotary:
             q, k = apply_rotary(q, self.rotary), apply_rotary(k, self.rotary)
         # what the kernel's backward pass reads: a block's replay runs
         # neither the three projections nor rotary again (util/remat.py)
         q, k, v = (keep(a, "qkv") for a in (q, k, v))
-        with jax.named_scope("attend"):
-            o = self._attend(q, k, v).transpose(0, 2, 1, 3)  # (B, T, H, Dh)
+        if self.indexer:
+            o, state = self._apply_selected(params, x, q, k, v, state, train)
+        else:
+            with jax.named_scope("attend"):
+                o = self._attend(q, k, v).transpose(0, 2, 1, 3)  # (B, T, H, Dh)
         if self.head_gate:
             gate = jax.nn.sigmoid(jnp.dot(
                 x, params["Wgate"], preferred_element_type=jnp.float32))
             o = o * gate[..., None].astype(o.dtype)
         return o.reshape(b, t, -1) @ params["Wo"], state
 
+    def _apply_selected(self, params, x, q, k, v, state, train):
+        """Attention over the keys the indexer selects, and on a training
+        step the indexer's loss and the counters, in the layer's state."""
+        b, _, t, _ = q.shape
+        top_k = int(self.indexer["top_k"])
+        with jax.named_scope("index"):
+            qi, wi, ki = self._index(params, x)
+        if train and not state:
+            raise ValueError(
+                "a training step of a layer with an indexer needs the "
+                "layer's state (init_state()): without it the indexer's "
+                "loss is dropped and the indexer never learns")
+        visible = _wide(b * (t * (t + 1) // 2))
+        with jax.named_scope("select"):
+            if t <= top_k:          # every query attends every visible key
+                sel = jnp.tril(jnp.ones((t, t), jnp.int8))[None].repeat(b, 0)
+                selected = visible
+            else:
+                # the selection is kept across a block's replay
+                # (util/remat.py): no key is scored or counted again there
+                sel = keep(selected_keys_mask(
+                    *map(jax.lax.stop_gradient, (qi, wi, ki)), top_k),
+                    "selection")
+                # a sequence's own count fits one word, the batch's sum
+                # need not
+                selected = keep(_fold_wide(sel.sum(axis=(1, 2),
+                                                   dtype=jnp.uint32)),
+                                "selection")
+        with jax.named_scope("attend"):
+            if t <= top_k and not train:
+                return self._attend(q, k, v).transpose(0, 2, 1, 3), state
+            o, p = self._attend_selected(q, k, v, sel)
+            o = o.transpose(0, 2, 1, 3)
+        if not train:
+            return o, state
+        with jax.named_scope("index_loss"):
+            loss = index_loss(qi, wi, ki, sel, jax.lax.stop_gradient(p))
+        return o, {
+            "index_loss": loss.astype(jnp.float32),
+            "keys_selected": selected,
+            "keys_visible": visible,
+            "keys_selected_total": _add_wide(state["keys_selected_total"],
+                                             selected),
+            "keys_visible_total": _add_wide(state["keys_visible_total"],
+                                            visible)}
+
     def init_decode_state(self, params, batch, max_len, dtype=jnp.float32):
         raise NotImplementedError(
             "RotaryGQAttention trains through fit(); decoding it needs a "
             "cache that keeps a window for some layers and every position "
-            "for others (ROADMAP, Reach)")
+            "for others, and the indexer's keys beside it (ROADMAP, Reach)")
 
 
 # ------------------------------------------------------------ expert layer

@@ -278,8 +278,21 @@ def _visible(qpos, kpos, window):
     return ok
 
 
-def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-                    bq, bk, window, scale):
+def _block_visible(mask_ref, iq, kb, bq, bk, window):
+    """Which (query, key) pairs of one block are attended: by position, or
+    where a ``mask`` operand is given by the mask alone (a selection that is
+    data already holds causality: ``selected_keys_mask``)."""
+    if mask_ref is not None:
+        return mask_ref[...].astype(jnp.int32) != 0
+    qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return _visible(qpos, kpos, window)
+
+
+def _gqa_fwd_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, window, scale,
+                    masked=False):
+    mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
+    o_ref, lse_ref, m_s, l_s, acc_s = rest
     iq, j = pl.program_id(2), pl.program_id(3)
     lo, hi = _kv_range(iq, bq, bk, window)
     kb = lo + j
@@ -295,9 +308,8 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         q, k, v = q_ref[...], k_ref[...], v_ref[...]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(_visible(qpos, kpos, window), s, _NEG)
+        s = jnp.where(_block_visible(mask_ref, iq, kb, bq, bk, window), s,
+                      _NEG)
         m = m_s[...]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -313,8 +325,10 @@ def _gqa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         lse_ref[...] = m_s[...] + jnp.log(l_s[...])
 
 
-def _gqa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_s, *, bq, bk, window, scale):
+def _gqa_dq_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, window, scale,
+                   masked=False):
+    mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
+    do_ref, lse_ref, delta_ref, dq_ref, dq_s = rest
     iq, j = pl.program_id(2), pl.program_id(3)
     lo, hi = _kv_range(iq, bq, bk, window)
     kb = lo + j
@@ -328,9 +342,7 @@ def _gqa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        p = jnp.where(_visible(qpos, kpos, window),
+        p = jnp.where(_block_visible(mask_ref, iq, kb, bq, bk, window),
                       jnp.exp(s - lse_ref[...]), 0.0)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
@@ -342,8 +354,10 @@ def _gqa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[...] = dq_s[...].astype(dq_ref.dtype)
 
 
-def _gqa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_s, dv_s, *, bq, bk, window, scale, nq):
+def _gqa_dkv_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, window, scale, nq,
+                    masked=False):
+    mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s = rest
     jk, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     lo, hi = _q_range(jk, bq, bk, window, nq)
     qb = lo + i
@@ -358,9 +372,7 @@ def _gqa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        qpos = qb * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = jk * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        p = jnp.where(_visible(qpos, kpos, window),
+        p = jnp.where(_block_visible(mask_ref, qb, jk, bq, bk, window),
                       jnp.exp(s - lse_ref[...]), 0.0)
         dv_s[...] += lax.dot_general(p.astype(do.dtype), do,
                                      (((0,), (0,)), ((), ())),
@@ -410,7 +422,8 @@ def _gqa_specs(bq, bk, dh, group, window):
     """Block specs of the kernels whose grid is (B, Hq, query block, key
     step): a query block, the key/value block of the head's kv head at that
     step of the band (held at the last one needed, so that a skipped step
-    fetches nothing), and a per-row vector."""
+    fetches nothing), a per-row vector, and the (query block, key block)
+    tile of a (B, T, T) mask that all heads share."""
     def kv_index(b_, h, i, j):
         lo, hi = _kv_range(i, bq, bk, window)
         return b_, h // group, jnp.minimum(lo + j, hi), 0
@@ -418,28 +431,36 @@ def _gqa_specs(bq, bk, dh, group, window):
     def q_index(b_, h, i, j):
         return b_, h, i, 0
 
+    def mask_index(b_, h, i, j):
+        lo, hi = _kv_range(i, bq, bk, window)
+        return b_, i, jnp.minimum(lo + j, hi)
+
     return (pl.BlockSpec((None, None, bq, dh), q_index),
             pl.BlockSpec((None, None, bk, dh), kv_index),
-            pl.BlockSpec((None, None, bq, 1), q_index))
+            pl.BlockSpec((None, None, bq, 1), q_index),
+            pl.BlockSpec((None, bq, bk), mask_index))
 
 
-def _gqa_fwd_call(q, k, v, window, block, interpret):
+def _gqa_fwd_call(q, k, v, window, block, interpret, mask=None):
     b, hq, t, dh = q.shape
     group = hq // k.shape[1]
     bq = bk = block or _pick_gqa_block(t)
     scale = 1.0 / (dh ** 0.5)
+    masked = mask is not None
+    # a kernel without a mask is built from the arguments it always had
+    opts = {"masked": True} if masked else {}
 
-    qspec, kvspec, vec = _gqa_specs(bq, bk, dh, group, window)
+    qspec, kvspec, vec, mspec = _gqa_specs(bq, bk, dh, group, window)
     return _gqa_call(
         functools.partial(_gqa_fwd_kernel, bq=bq, bk=bk, window=window,
-                          scale=scale),
+                          scale=scale, **opts),
         (b, hq, t // bq, _band_blocks(t, window, bq, bk)),
-        [qspec, kvspec, kvspec], (qspec, vec),
+        [qspec, kvspec, kvspec] + [mspec] * masked, (qspec, vec),
         (jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct((b, hq, t, 1), jnp.float32)),
         [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
          pltpu.VMEM((bq, dh), jnp.float32)],
-        (q, k, v), interpret)
+        (q, k, v) + (mask,) * masked, interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -461,7 +482,7 @@ def _gqa_fwd(q, k, v, window, block, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _gqa_bwd(window, block, interpret, res, do):
+def _gqa_bwd(window, block, interpret, res, do, mask=None):
     q, k, v, o, lse = res
     b, hq, t, dh = q.shape
     hkv = k.shape[1]
@@ -469,37 +490,133 @@ def _gqa_bwd(window, block, interpret, res, do):
     bq = bk = block or _pick_gqa_block(t)
     nq = t // bq
     scale = 1.0 / (dh ** 0.5)
+    masked = mask is not None
+    opts = {"masked": True} if masked else {}
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
         axis=-1, keepdims=True)                      # (B, Hq, T, 1)
 
-    qspec, kvspec, vec = _gqa_specs(bq, bk, dh, group, window)
+    qspec, kvspec, vec, mspec = _gqa_specs(bq, bk, dh, group, window)
     dq = _gqa_call(
         functools.partial(_gqa_dq_kernel, bq=bq, bk=bk, window=window,
-                          scale=scale),
+                          scale=scale, **opts),
         (b, hq, nq, _band_blocks(t, window, bq, bk)),
-        [qspec, kvspec, kvspec, qspec, vec, vec], qspec,
+        [qspec, kvspec, kvspec] + [mspec] * masked + [qspec, vec, vec], qspec,
         jax.ShapeDtypeStruct(q.shape, q.dtype),
         [pltpu.VMEM((bq, dh), jnp.float32)],
-        (q, k, v, do, lse, delta), interpret)
+        (q, k, v) + (mask,) * masked + (do, lse, delta), interpret)
 
     def q_index(b_, h, jk, g, i):
         lo, hi = _q_range(jk, bq, bk, window, nq)
         return b_, h * group + g, jnp.minimum(lo + i, hi), 0
 
+    def mask_index(b_, h, jk, g, i):
+        lo, hi = _q_range(jk, bq, bk, window, nq)
+        return b_, jnp.minimum(lo + i, hi), jk
+
     qspec2 = pl.BlockSpec((None, None, bq, dh), q_index)
     vec2 = pl.BlockSpec((None, None, bq, 1), q_index)
     kvspec2 = pl.BlockSpec((None, None, bk, dh),
                            lambda b_, h, jk, g, i: (b_, h, jk, 0))
+    mspec2 = pl.BlockSpec((None, bq, bk), mask_index)
     dk, dv = _gqa_call(
         functools.partial(_gqa_dkv_kernel, bq=bq, bk=bk, window=window,
-                          scale=scale, nq=nq),
+                          scale=scale, nq=nq, **opts),
         (b, hkv, t // bk, group, _band_blocks(t, window, bk, bq)),
-        [qspec2, kvspec2, kvspec2, qspec2, vec2, vec2], (kvspec2, kvspec2),
+        [qspec2, kvspec2, kvspec2] + [mspec2] * masked + [qspec2, vec2, vec2],
+        (kvspec2, kvspec2),
         (jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)),
         [pltpu.VMEM((bk, dh), jnp.float32), pltpu.VMEM((bk, dh), jnp.float32)],
-        (q, k, v, do, lse, delta), interpret)
+        (q, k, v) + (mask,) * masked + (do, lse, delta), interpret)
     return dq, dk, dv
 
 
 gqa_flash_attention.defvjp(_gqa_fwd, _gqa_bwd)
+
+
+# ============================================== attention over selected keys
+# The same three kernels under a mask that is data: ``mask[b, i, j] != 0``
+# where query i attends key j, one mask for all heads (int8, read a (query
+# block, key block) tile at a time beside the key block). The mask holds
+# causality itself, so only blocks on or under the diagonal are visited and
+# a pair's position is not computed again. Every query must select at least
+# one key.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def gqa_selected_attention(q, k, v, mask, block=None, interpret=False):
+    """Grouped-query attention over the keys ``mask`` selects. q, k, v as
+    ``gqa_flash_attention``; mask: (B, T, T) int8, nonzero where the query
+    (row) attends the key (column), zero above the diagonal. Returns
+    ((B, Hq, T, Dh) in q's dtype, the log-sum-exp of each row's selected
+    scores (B, Hq, T, 1) float32, which ``gqa_head_mean_probs`` reads)."""
+    return _gqa_fwd_call(q, k, v, None, block, interpret, mask)
+
+
+def _gqa_sel_fwd(q, k, v, mask, block, interpret):
+    o, lse = (keep(a, "attn_out")
+              for a in _gqa_fwd_call(q, k, v, None, block, interpret, mask))
+    return (o, lse), (q, k, v, o, lse, mask)
+
+
+def _gqa_sel_bwd(block, interpret, res, ct):
+    *res, mask = res
+    # the log-sum-exp leaves for a pass that takes no gradient
+    return _gqa_bwd(None, block, interpret, res, ct[0], mask) + (None,)
+
+
+gqa_selected_attention.defvjp(_gqa_sel_fwd, _gqa_sel_bwd)
+
+
+def _head_mean_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, acc_s, *, scale,
+                      heads):
+    i, j, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(h == 0)
+    def _():
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        s = lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        acc_s[...] += jnp.where(mask_ref[...].astype(jnp.int32) != 0,
+                                jnp.exp(s - lse_ref[...]), 0.0)
+
+    @pl.when(h == heads - 1)
+    def _():
+        p_ref[...] = acc_s[...] * (1.0 / heads)
+
+
+def gqa_head_mean_probs(q, k, lse, mask, block=None, interpret=False):
+    """The mean over the query heads of the attention weights of
+    ``gqa_selected_attention``: (B, T, T) float32, zero where ``mask`` is.
+    q: (B, Hq, T, Dh), k: (B, Hkv, T, Dh), lse: (B, Hq, T, 1) as that call
+    returned it. One (query block, key block) tile is summed over the heads
+    in VMEM, so no (Hq, T, T) tensor exists. Takes no gradient."""
+    b, hq, t, dh = q.shape
+    group = hq // k.shape[1]
+    blk = block or _pick_gqa_block(t)
+    low = lambda j, i: jnp.minimum(j, i)       # above the diagonal: no fetch
+    return pl.pallas_call(
+        functools.partial(_head_mean_kernel, scale=1.0 / (dh ** 0.5),
+                          heads=hq),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=(b, t // blk, t // blk, hq),
+            in_specs=[
+                pl.BlockSpec((None, None, blk, dh),
+                             lambda b_, i, j, h: (b_, h, i, 0)),
+                pl.BlockSpec((None, None, blk, dh),
+                             lambda b_, i, j, h: (b_, h // group, low(j, i),
+                                                  0)),
+                pl.BlockSpec((None, None, blk, 1),
+                             lambda b_, i, j, h: (b_, h, i, 0)),
+                pl.BlockSpec((None, blk, blk),
+                             lambda b_, i, j, h: (b_, i, low(j, i)))],
+            out_specs=pl.BlockSpec((None, blk, blk),
+                                   lambda b_, i, j, h: (b_, i, j)),
+            scratch_shapes=[pltpu.VMEM((blk, blk), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
+        interpret=interpret,
+    )(q, k, lse, mask)

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 
 import numpy as np
 
@@ -396,6 +397,166 @@ def validate_expert_rounds_case(n, c, n_experts, top_k, width, count,
     return res
 
 
+def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
+                                     dtype="bfloat16", rtol=2e-2, atol=2e-2,
+                                     time_it=True):
+    """Attention over a learned selection of keys at one layer's shapes: the
+    selection (``selected_keys_mask``: exact counts, and the share of keys
+    that differ from ``jax.lax.top_k`` over the same scores), the kernels
+    under the mask (``gqa_selected_attention`` forward and its three
+    gradients, ``gqa_head_mean_probs``) and the indexer's loss with its
+    gradients (``index_loss``), each against a float32 loop over chunks of
+    query rows at ``highest`` precision on the same inputs, and the time of
+    each beside XLA's form of the masked attention in the same dtype."""
+    from deeplearning4j_tpu.nn.layers.decoder import (
+        index_loss, index_scores, selected_keys_mask)
+    from deeplearning4j_tpu.ops.flash_attention import (
+        gqa_head_mean_probs, gqa_selected_attention)
+    from deeplearning4j_tpu import ops
+    assert gqa_supported(t, dh, hq, hkv), (t, dh, hq, hkv)
+    interp = ops.interpret_mode()       # off the chip: kernels interpreted
+    dt = jnp.dtype(dtype)
+    rs = np.random.RandomState(t + hq)
+    q = jnp.asarray(rs.randn(b, hq, t, dh), dt)
+    k, v = (jnp.asarray(rs.randn(b, hkv, t, dh), dt) for _ in range(2))
+    qi = jnp.asarray(rs.randn(b, t, j, di), dt)
+    ki = jnp.asarray(rs.randn(b, t, di), dt)
+    wi = jnp.asarray(rs.randn(b, t, j) / math.sqrt(j * di), jnp.float32)
+    cot = jnp.asarray(rs.randn(b, hq, t, dh), jnp.float32)
+    rows = min(256, t)
+    group = hq // hkv
+
+    def chunks(fn, *full):
+        """``fn(start, *full)`` over the chunks of query rows of every
+        sequence, one chunk's scores alive at a time."""
+        def one(args):
+            return jax.lax.map(jax.checkpoint(lambda s: fn(s, *args)),
+                               jnp.arange(0, t, rows))
+        return jax.lax.map(one, full)
+
+    def cut(a, start, axis):
+        return jax.lax.dynamic_slice_in_dim(a, start, rows, axis)
+
+    def loop(q, k, v, mask, f32=True):
+        """(output, head-mean weights) by a masked softmax, chunk by
+        chunk."""
+        cast = (lambda a: a.astype(jnp.float32)) if f32 else (lambda a: a)
+
+        def rows_of(start, q1, k1, v1, m1):
+            qc = cast(cut(q1, start, 1)).reshape(hkv, group, rows, dh)
+            s = jnp.einsum("kgqd,ksd->kgqs", qc, cast(k1),
+                           preferred_element_type=jnp.float32) / math.sqrt(dh)
+            p = jax.nn.softmax(jnp.where(
+                (cut(m1, start, 0) != 0)[None, None], s, -jnp.inf), axis=-1)
+            o = jnp.einsum("kgqs,ksd->kgqd", p.astype(v1.dtype) if not f32
+                           else p, cast(v1))
+            return o.reshape(hq, rows, dh), p.mean(axis=(0, 1))
+
+        o, pm = chunks(rows_of, q, k, v, mask)
+        return (o.transpose(0, 2, 1, 3, 4).reshape(b, hq, t, dh),
+                pm.reshape(b, t, t))
+
+    def kl_loop(qi, wi, ki, mask, pm):
+        def rows_of(start, q1, w1, k1, m1, p1):
+            sel, pc = cut(m1, start, 0) != 0, cut(p1, start, 0)
+            logq = jax.nn.log_softmax(jnp.where(sel, index_scores(
+                cut(q1, start, 0).astype(jnp.float32), cut(w1, start, 0),
+                k1.astype(jnp.float32)), -jnp.inf), axis=-1)
+            return jnp.where(sel, jax.scipy.special.xlogy(pc, pc)
+                             - pc * jnp.where(sel, logq, 0.0), 0.0).sum()
+        return chunks(rows_of, qi, wi, ki, mask, pm).sum() / (b * t)
+
+    def top_k_loop(qi, wi, ki):
+        def rows_of(start, q1, w1, k1):
+            sc = index_scores(cut(q1, start, 0), cut(w1, start, 0), k1)
+            vis = jnp.arange(t)[None, :] <= start + jnp.arange(rows)[:, None]
+            _, idx = jax.lax.top_k(jnp.where(vis, sc, -jnp.inf), top_k)
+            return (jnp.zeros((rows, t), bool).at[
+                jnp.arange(rows)[:, None], idx].set(True) & vis
+            ).astype(jnp.int8)
+        return chunks(rows_of, qi, wi, ki).reshape(b, t, t)
+
+    # every array is an argument of the jitted calls: one closed over would
+    # be baked into the executable as a constant (268 MB of mask at the
+    # layer's shapes)
+    select = jax.jit(lambda *a: selected_keys_mask(*a, top_k))
+    mask = select(qi, wi, ki)
+    want = b * sum(min(i + 1, top_k) for i in range(t))
+    assert int(mask.sum(dtype=jnp.int32)) == want, "selection count"
+    assert bool((mask.sum(axis=-1, dtype=jnp.int32)
+                 == jnp.minimum(jnp.arange(t) + 1, top_k)).all())
+    differ = float(jax.jit(lambda m, *a: (m != top_k_loop(*a)).sum())(
+        mask, qi, wi, ki)) / want
+    assert differ <= 1e-4, f"selection differs from top_k on {differ} of keys"
+
+    def grad_of(attend):
+        return jax.jit(jax.grad(
+            lambda q, k, v, m, c: jnp.sum(
+                attend(q, k, v, m).astype(jnp.float32) * c),
+            argnums=(0, 1, 2)))
+
+    with jax.default_matmul_precision("highest"):
+        ref_fwd = jax.jit(loop)
+        ref_g = grad_of(lambda q, k, v, m: loop(q, k, v, m)[0])
+        o_ref, p_ref = ref_fwd(q, k, v, mask)
+        g_ref = ref_g(q, k, v, mask, cot)
+        kl_ref, kl_g_ref = jax.jit(jax.value_and_grad(
+            kl_loop, argnums=(0, 1, 2)))(qi, wi, ki, mask, p_ref)
+    fa_fwd = jax.jit(lambda q, k, v, m: gqa_selected_attention(
+        q, k, v, m, None, interp))
+    fa_g = grad_of(lambda q, k, v, m: gqa_selected_attention(
+        q, k, v, m, None, interp)[0])
+    probs = jax.jit(lambda q, k, lse, m: gqa_head_mean_probs(
+        q, k, lse, m, None, interp))
+    kl = jax.jit(jax.value_and_grad(index_loss, argnums=(0, 1, 2)))
+    o, lse = fa_fwd(q, k, v, mask)
+    errs = {"o": _max_err(o.astype(jnp.float32), o_ref),
+            "p": _max_err(probs(q, k, lse, mask), p_ref) * top_k}
+    assert errs["o"] <= atol + rtol and errs["p"] <= 20 * (atol + rtol), errs
+    for name, a, b_ in zip("qkv", fa_g(q, k, v, mask, cot), g_ref):
+        a, b_ = a.astype(jnp.float32), b_.astype(jnp.float32)
+        errs["d" + name] = _max_err(a, b_)
+        scale = float(jnp.max(jnp.abs(b_))) + 1.0
+        assert errs["d" + name] <= atol + rtol * scale, \
+            f"selected attention T={t}: d{name} err {errs['d' + name]} " \
+            f"(scale {scale})"
+    del g_ref, o_ref
+    val, grads = kl(qi, wi, ki, mask, p_ref)
+    errs["kl"] = abs(float(val) - float(kl_ref)) / abs(float(kl_ref))
+    assert errs["kl"] <= rtol, errs
+    for name, a, b_ in zip(("qi", "wi", "ki"), grads, kl_g_ref):
+        a, b_ = a.astype(jnp.float32), b_.astype(jnp.float32)
+        gap = float(jnp.linalg.norm(a - b_) / jnp.linalg.norm(b_))
+        errs["d" + name] = gap
+        assert gap <= 5 * rtol, f"index loss T={t}: d{name} norm gap {gap}"
+    res = {"kernel": "gqa_selected_attention", "B": b, "Hq": hq, "Hkv": hkv,
+           "T": t, "Dh": dh, "index_heads": j, "index_dim": di,
+           "top_k": top_k, "dtype": dtype, "keys_selected": want,
+           "selection_differs_from_top_k": differ, "index_loss": float(val),
+           "errs": {n: round(e, 6) for n, e in errs.items()},
+           "max_err": round(max(errs["o"], errs["dq"], errs["dk"],
+                                errs["dv"]), 6)}
+    if time_it:
+        xla = lambda q, k, v, m: loop(q, k, v, m, f32=False)[0]
+        xla_fwd, xla_g = jax.jit(xla), grad_of(xla)
+        us = lambda fn, *a: round(_time(fn, *a) * 1e6, 1)
+        res.update(select_us=us(select, qi, wi, ki),
+                   fwd_us=us(fa_fwd, q, k, v, mask),
+                   grad_us=us(fa_g, q, k, v, mask, cot),
+                   head_mean_us=us(probs, q, k, lse, mask),
+                   index_loss_grad_us=us(kl, qi, wi, ki, mask, p_ref),
+                   fwd_xla_us=us(xla_fwd, q, k, v, mask),
+                   grad_xla_us=us(xla_g, q, k, v, mask, cot))
+    return res
+
+
+# (B, Hq, Hkv, T, Dh, index heads, index dim, top_k): one layer of the
+# benchmark's decoder with a learned selection at its step's one sequence
+# of 16,384 positions, and a small case
+SELECTED_SWEEP = [(1, 32, 4, 16384, 128, 16, 64, 2048),
+                  (2, 4, 2, 1024, 64, 2, 32, 256)]
+SELECTED_QUICK = SELECTED_SWEEP[1:]
+
 # (N, C, experts, top_k, width, held): one chip's share of the benchmark's
 # sparse decoder at its step's 16,384 tokens (18 rounds), and a small case
 EXPERT_SWEEP = [(16384, 3072, 256, 10, 1024, 8), (256, 64, 16, 3, 32, 4)]
@@ -485,6 +646,15 @@ def run(quick=False, time_it=True):
             print(json.dumps(r))
         except Exception as e:  # noqa: BLE001
             failures.append({"kernel": "expert_rounds", "case": case,
+                             "error": f"{type(e).__name__}: {e}"[:300]})
+            print(json.dumps(failures[-1]))
+    for case in (SELECTED_QUICK if quick else SELECTED_SWEEP):
+        try:
+            r = validate_selected_attention_case(*case, time_it=time_it)
+            results.append(r)
+            print(json.dumps(r))
+        except Exception as e:  # noqa: BLE001
+            failures.append({"kernel": "gqa_selected_attention", "case": case,
                              "error": f"{type(e).__name__}: {e}"[:300]})
             print(json.dumps(failures[-1]))
     summary = {"backend": jax.default_backend(),

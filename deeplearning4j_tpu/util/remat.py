@@ -54,12 +54,18 @@ def remat_loss(loss_fn, mode):
 # and log-sum-exp; the router's product, its top k with their indices, the
 # sort of the pairs and the group sizes; round 0's grouped gate and up
 # products; a SwiGLU's gate and up products (a dense layer's and a shared
-# expert's). Each costs the replay a kernel, a sort or a matrix product and
-# is small beside what a step holds. Left to the replay: what is cheap to
+# expert's); of an attention layer with an indexer the selection (the int8
+# mask and its count: without it a replay scores and selects every key
+# again) and the indexer loss's gradient by the indexer's queries, head
+# weights and keys, taken in the forward pass (``index_loss``), so that the
+# index products need no name: nothing reads them again. Each costs the
+# replay a kernel, a sort or a matrix product and is small beside what a
+# step holds. Left to the replay: what is cheap to
 # compute again (norms, the head gate, silu(g) * u, the gather of the expert
 # rows) and the output projection, whose result is as large as q and spares
 # one product.
-BLOCK_KEPT = ("qkv", "attn_out", "routing", "expert_gate_up", "gate_up")
+BLOCK_KEPT = ("qkv", "attn_out", "routing", "expert_gate_up", "gate_up",
+              "selection", "index_grads")
 
 _counting = threading.local()
 

@@ -18,8 +18,16 @@ from deeplearning4j_tpu.zoo.zoo_model import ZooModel
 
 
 def rotary_settings(config, layer_type):
-    """``RotaryGQAttention.rotary`` from ``rope_parameters[layer_type]``."""
-    r = config["rope_parameters"][layer_type]
+    """``RotaryGQAttention.rotary`` from ``rope_parameters[layer_type]``,
+    or from the scalar ``rope_theta`` / ``rope_scaling`` of a configuration
+    whose layers are all of one kind. ``mrope_section`` (rotary frequencies
+    shared out over position axes) is plain rotary while the axes are equal,
+    as they are for text."""
+    if "rope_parameters" not in config:
+        r = dict(config.get("rope_scaling") or {},
+                 rope_theta=config["rope_theta"])
+    else:
+        r = config["rope_parameters"][layer_type]
     out = {"theta": r["rope_theta"],
            "dims": int(config["head_dim"]
                        * r.get("partial_rotary_factor", 1))}
@@ -35,16 +43,22 @@ def rotary_settings(config, layer_type):
 class SparseDecoder(ZooModel):
     """``config``: a dict with the keys of the model's public
     ``config.json``: ``vocab_size``, ``hidden_size``, ``head_dim``,
-    ``num_key_value_heads``, ``rms_norm_eps``, ``sliding_window``,
-    ``rope_parameters``, ``gating``, per layer ``layer_types``
-    (``full_attention`` | ``sliding_attention``),
-    ``num_attention_heads_per_layer`` and ``mlp_layer_types`` (``dense`` |
-    ``sparse``), ``intermediate_size``, and for the expert layers
-    ``num_experts``, ``num_experts_per_tok``, ``moe_intermediate_size``,
-    ``shared_expert_intermediate_size``, ``norm_topk_prob``,
-    ``moe_routed_scaling_factor``. As many layers are built as
-    ``layer_types`` lists. ``experts_held=(count, first)`` gives the
-    expert layers a share of the experts (None: all)."""
+    ``num_key_value_heads``, ``rms_norm_eps``, ``intermediate_size``, and
+    for the expert layers ``num_experts``, ``num_experts_per_tok``,
+    ``moe_intermediate_size``, ``shared_expert_intermediate_size`` (absent:
+    no shared expert), ``norm_topk_prob``, ``moe_routed_scaling_factor``.
+    Two families of keys say what each layer is. Per-layer lists:
+    ``layer_types`` (``full_attention`` | ``sliding_attention``; as many
+    layers are built as it lists), ``num_attention_heads_per_layer``,
+    ``mlp_layer_types`` (``dense`` | ``sparse``), with ``sliding_window``,
+    ``rope_parameters`` and ``gating``. Or scalars, every layer of one kind:
+    ``num_hidden_layers``, ``num_attention_heads``, ``rope_theta`` /
+    ``rope_scaling``, ``mlp_only_layers`` + ``decoder_sparse_step`` for
+    which layers are sparse, ``sa_config`` (``indexer_num_heads``,
+    ``indexer_head_dim``, ``topk``: attention over a learned selection of
+    keys) and ``qk_norm`` (an RMSNorm on each head of q and k).
+    ``experts_held=(count, first)`` gives the expert layers a share of the
+    experts (None: all)."""
     name = "sparsedecoder"
 
     def __init__(self, config, seed: int = 123, experts_held=None, **kwargs):
@@ -72,27 +86,42 @@ class SparseDecoder(ZooModel):
         g.add_layer("embed", EmbeddingSequenceLayer(
             n_in=vocab, n_out=hidden, activation="identity"), "tokens")
         prev = "embed"
-        for i, kind in enumerate(c["layer_types"]):
+        kinds = c.get("layer_types") \
+            or ["full_attention"] * c["num_hidden_layers"]
+        heads = c.get("num_attention_heads_per_layer") \
+            or [c["num_attention_heads"]] * len(kinds)
+        step, dense = c.get("decoder_sparse_step", 1), c.get("mlp_only_layers", ())
+        mlps = c.get("mlp_layer_types") or [
+            "dense" if i in dense or (i + 1) % step else "sparse"
+            for i in range(len(kinds))]
+        sa = c.get("sa_config")
+        extra = {"qk_norm": True, "norm_eps": eps} if c.get("qk_norm") else {}
+        if sa:
+            extra["indexer"] = {"heads": sa["indexer_num_heads"],
+                                "head_dim": sa["indexer_head_dim"],
+                                "top_k": sa["topk"]}
+        for i, kind in enumerate(kinds):
             b = f"b{i}"
             g.add_layer(f"{b}.norm1", RMSNorm(eps=eps), prev)
             g.add_layer(f"{b}.attn", RotaryGQAttention(
-                n_heads=c["num_attention_heads_per_layer"][i],
+                n_heads=heads[i],
                 n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
                 window=(c["sliding_window"] if kind == "sliding_attention"
                         else None),
                 rotary=rotary_settings(c, kind),
-                head_gate=c.get("gating") == "per-head"), f"{b}.norm1")
+                head_gate=c.get("gating") == "per-head", **extra),
+                f"{b}.norm1")
             g.add_vertex(f"{b}.add1", ElementWiseVertex(op="add"),
                          f"{b}.attn", prev)
             g.add_layer(f"{b}.norm2", RMSNorm(eps=eps), f"{b}.add1")
-            if c["mlp_layer_types"][i] == "dense":
+            if mlps[i] == "dense":
                 mlp = SwiGLU(width=c["intermediate_size"])
             else:
                 mlp = ExpertLayer(
                     n_experts=c["num_experts"],
                     experts_per_token=c["num_experts_per_tok"],
                     expert_width=c["moe_intermediate_size"],
-                    shared_width=c.get("shared_expert_intermediate_size", 0),
+                    shared_width=c.get("shared_expert_intermediate_size") or 0,
                     routed_scale=c.get("moe_routed_scaling_factor", 1.0),
                     norm_topk=c.get("norm_topk_prob", True),
                     experts_held=self.experts_held)

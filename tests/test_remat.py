@@ -246,6 +246,7 @@ def _replays(wrap, name):
 
 @pytest.mark.parametrize("name", ["conv_out", "bn_stats", "qkv", "attn_out",
                                   "routing", "expert_gate_up", "gate_up",
+                                  "selection", "index_grads",
                                   "anything_else"])
 def test_each_policy_keeps_its_own_names_and_no_other(name):
     """``save_convs`` keeps what it kept before ``blocks`` had names of its
@@ -374,7 +375,9 @@ def test_the_registry_says_what_the_blocks_keep(cfg, kernels, kind, mlp):
     want = {"qkv": 2 * n * (HEADS + 2 * KV) * HD * f32,
             "attn_out": 2 * n * HEADS * (HD + 1) * f32,     # o and lse
             "routing": 0, "expert_gate_up": 0,
-            "gate_up": 2 * 2 * n * 64 * f32}
+            "gate_up": 2 * 2 * n * 64 * f32,
+            # a layer without an indexer keeps no selection
+            "selection": 0, "index_grads": 0}
     if mlp == "sparse":
         rows, _ = net.conf.nodes["b0.mlp"].layer.round_rows(n)
         want.update(

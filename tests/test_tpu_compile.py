@@ -192,6 +192,33 @@ def test_gqa_flash_attention_fwd_and_grad(one_chip, hq, window):
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
+# the benchmark's cell with a learned selection of keys: one sequence of
+# 16,384 positions, 32 query heads over 4 kv heads of 128, one int8 mask
+# for all heads
+SELECTED = ((1, 32, 16384, 128), (1, 4, 16384, 128))
+
+
+def test_gqa_selected_attention_fwd_and_grad(one_chip):
+    def loss(q, k, v, mask):
+        return flash_attention.gqa_selected_attention(
+            q, k, v, mask)[0].astype(jnp.float32).sum()
+
+    q, kv = SELECTED
+    shapes = _shapes(one_chip, (q, jnp.bfloat16), (kv, jnp.bfloat16),
+                     (kv, jnp.bfloat16), ((1, 16384, 16384), jnp.int8))
+    _agree(flash_attention.gqa_supported(16384, 128, 32, 4),
+           jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
+
+
+def test_gqa_head_mean_probs(one_chip):
+    q, kv = SELECTED
+    shapes = _shapes(one_chip, (q, jnp.bfloat16), (kv, jnp.bfloat16),
+                     ((1, 32, 16384, 1), jnp.float32),
+                     ((1, 16384, 16384), jnp.int8))
+    _agree(flash_attention.gqa_supported(16384, 128, 32, 4),
+           flash_attention.gqa_head_mean_probs, shapes)
+
+
 def test_ragged_dot_is_one_grouped_product_on_the_chip(one_chip):
     """The expert layer's grouped product at the cell's shape: the TPU
     compiler keeps it one kernel of M x K x N multiply-adds, not one dense
