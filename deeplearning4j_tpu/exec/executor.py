@@ -364,14 +364,16 @@ class Executor:
         return get_programs()
 
     def register_program(self, caller, key, fn, args, compile_seconds=None,
-                         scopes=False, remat_kept_bytes=None):
+                         scopes=False, remat_kept_bytes=None,
+                         index_scores_calls=None):
         """Record a program built by :meth:`jit` (single-device ``jax.jit``
         results and mesh wrappers both work); see
         ``programs.ProgramRegistry.record``."""
         return self.programs.record(caller, key, fn, args,
                                     compile_seconds=compile_seconds,
                                     scopes=scopes,
-                                    remat_kept_bytes=remat_kept_bytes)
+                                    remat_kept_bytes=remat_kept_bytes,
+                                    index_scores_calls=index_scores_calls)
 
 
 # ------------------------------------------------------- process default

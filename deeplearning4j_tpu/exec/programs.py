@@ -22,6 +22,11 @@ What this buys:
   the bytes its blocks keep for the backward pass beside their inputs, by
   name (util/remat.py), and the ``dl4jtpu_remat_kept_bytes`` gauge
   labelled ``{caller,key,name}``: a name that reads 0 is replayed.
+- ``index_scores_calls``, a step program's count of the index-score calls
+  it traced by the form each took (nn/layers/decoder.py:index_scores:
+  ``kernel``, the Pallas kernel of ops/index_scores.py, or ``xla``, the
+  einsum), and the ``dl4jtpu_index_scores_calls`` gauge labelled
+  ``{caller,key,form}``.
 - ``bench.py`` MFU rows read flops from here instead of re-deriving them
   with a private lowering helper.
 
@@ -199,6 +204,7 @@ class ProgramRegistry:
         self._programs = {}        # (caller, key) -> record dict
         self._gauges = None
         self._kept = None          # the remat_kept_bytes gauge family
+        self._index_calls = None   # the index_scores_calls gauge family
 
     def _metric(self, record):
         if self._gauges is None:
@@ -229,6 +235,11 @@ class ProgramRegistry:
                 "bytes a step program's blocks keep for the backward pass "
                 "beside their inputs under remat='blocks', by name",
                 labelnames=("caller", "key", "name"))
+            self._index_calls = reg.gauge(
+                "dl4jtpu_index_scores_calls",
+                "index-score calls a step program traced, by the form each "
+                "took (kernel: Pallas, tile by tile in VMEM; xla: einsum)",
+                labelnames=("caller", "key", "form"))
         lbl = {"caller": record["caller"], "key": record["key"]}
         for field, fam in self._gauges.items():
             v = record.get(field)
@@ -236,11 +247,14 @@ class ProgramRegistry:
                 fam.labels(**lbl).set(v)
         for name, v in (record.get("remat_kept_bytes") or {}).items():
             self._kept.labels(name=name, **lbl).set(v)
+        for form, v in (record.get("index_scores_calls") or {}).items():
+            self._index_calls.labels(form=form, **lbl).set(v)
 
     def record(self, caller: str, key: str, fn, args,
                compile_seconds: Optional[float] = None,
                scopes: bool = False,
-               remat_kept_bytes: Optional[dict] = None) -> Optional[dict]:
+               remat_kept_bytes: Optional[dict] = None,
+               index_scores_calls: Optional[dict] = None) -> Optional[dict]:
         """Register program ``(caller, key)``; re-registration of a known
         key is a no-op (returns the existing record). Analysis failures
         degrade to a record with None fields rather than raising into
@@ -248,7 +262,8 @@ class ProgramRegistry:
         table (the containers' step programs ask for it; a serving bucket
         has no reader for one). ``remat_kept_bytes``: what the caller
         counted while it traced the program (a graph's step under
-        ``remat="blocks"``), kept as the record's field of that name."""
+        ``remat="blocks"``), kept as the record's field of that name;
+        ``index_scores_calls`` likewise (a step with an indexer)."""
         caller, key = str(caller), str(key)
         with self._lock:
             existing = self._programs.get((caller, key))
@@ -273,6 +288,10 @@ class ProgramRegistry:
                                 else fields["aot_seconds"]),
             "remat_kept_bytes": (dict(remat_kept_bytes)
                                  if remat_kept_bytes is not None else None),
+            "index_scores_calls": (dict(index_scores_calls)
+                                   if index_scores_calls
+                                   and any(index_scores_calls.values())
+                                   else None),
         }
         with self._lock:
             # lost a race: keep the first registration

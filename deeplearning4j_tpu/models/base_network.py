@@ -86,6 +86,7 @@ class BaseNetwork:
         self._update_step = None      # standalone donated update program
         self._compile_count = 0       # train programs traced (see _note_compile)
         self._remat_kept = None       # remat='blocks': bytes kept, by name
+        self._index_calls = None      # index-score calls traced, by form
         self._flight = None           # FlightRecorder (monitor/flight.py)
         self._train_mon = None        # lazy TrainMonitor (metric children)
         self._exec = None             # execution core (lazy; exec/executor.py)
@@ -258,6 +259,14 @@ class BaseNetwork:
         return self._train_mon
 
     # ----------------------------------------------------------- train step
+    def _counting_index_calls(self):
+        """Open around the trace of a step program's loss and gradient: the
+        index-score calls it traces are counted by form, for the program's
+        registry record."""
+        from deeplearning4j_tpu.ops.index_scores import FORMS, counting_calls
+        self._index_calls = dict.fromkeys(FORMS, 0)
+        return counting_calls(self._index_calls)
+
     def _loss_for_grad(self):
         """The differentiated loss: jax.checkpoint-wrapped when remat is
         configured (recompute activations in the backward — faster AND
@@ -280,9 +289,10 @@ class BaseNetwork:
             self._note_compile()
             rng = jax.random.fold_in(
                 jax.random.PRNGKey(self.conf.global_conf.seed), it)
-            (loss, (new_state, new_carries)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, state, inputs, labels, rng,
-                                       masks, label_masks, carries)
+            with self._counting_index_calls():
+                (loss, (new_state, new_carries)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, state, inputs, labels, rng,
+                                           masks, label_masks, carries)
             new_params, new_opt = self._dp_apply_updates(params, opt_state, grads)
             out = (new_params, new_state, new_opt, loss)
             if with_carries:
@@ -338,9 +348,10 @@ class BaseNetwork:
                     x, y = inp
                     rng = jax.random.fold_in(
                         jax.random.PRNGKey(self.conf.global_conf.seed), it)
-                    (loss, (new_state, _)), grads = jax.value_and_grad(
-                        loss_fn, has_aux=True)(params, state, x, y, rng,
-                                               None, None)
+                    with self._counting_index_calls():
+                        (loss, (new_state, _)), grads = jax.value_and_grad(
+                            loss_fn, has_aux=True)(params, state, x, y, rng,
+                                                   None, None)
                     new_params, opt_state = self._dp_apply_updates(
                         params, opt_state, grads)
                     if rec is None:
@@ -396,7 +407,8 @@ class BaseNetwork:
                 (self.params, self.state, self.opt_state, xs, ys,
                  jnp.asarray(self.iteration, jnp.int32)),
                 compile_seconds=time.perf_counter() - t0, scopes=True,
-                remat_kept_bytes=self._remat_kept)
+                remat_kept_bytes=self._remat_kept,
+                index_scores_calls=self._index_calls)
         if self.listeners:
             with trace.span("callback"):
                 for lst in self.listeners:
@@ -733,7 +745,8 @@ class BaseNetwork:
                      jnp.asarray(self.iteration, jnp.int32), masks,
                      label_masks),
                     compile_seconds=self._last_fit_time, scopes=True,
-                    remat_kept_bytes=self._remat_kept)
+                    remat_kept_bytes=self._remat_kept,
+                    index_scores_calls=self._index_calls)
         self.iteration += 1
         self._epoch_batch += 1
         self._mon.record(seconds=self._last_fit_time, steps=1,

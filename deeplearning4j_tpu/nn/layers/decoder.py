@@ -173,6 +173,20 @@ def banded_attention(q, k, v, window=None):
     return jnp.einsum("bkgqs,bksd->bkgqd", p, v).reshape(b, hq, t, dh)
 
 
+# below this length the score matrix is small and XLA's fused path is used
+_KERNEL_MIN_SEQ = 1024
+
+
+def _kernels_on(t):
+    """The rule that routes this module's attention to its Pallas kernels,
+    at ``t`` positions: helpers on, no partitioned trace (XLA will not
+    partition a Mosaic call), and a length that pays (any, interpreted)."""
+    from deeplearning4j_tpu import ops
+    from deeplearning4j_tpu.exec.executor import tracing_partitioned
+    return (ops.helpers_enabled() and not tracing_partitioned()
+            and (ops.interpret_mode() or t >= _KERNEL_MIN_SEQ))
+
+
 # ---------------------------------------------- a learned selection of keys
 # An indexer (J small heads over one shared key head) scores every visible
 # key of a query, I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]); the query
@@ -202,14 +216,32 @@ def _row_chunks(t, rows=None):
                   for c in range(0, n, per)]
 
 
+def index_scores_xla(qi, wi, ki):
+    """The plain form of ``index_scores``: the per-head product as one
+    (J, R, S) float32 array, then ``relu``, the head weights and the sum
+    over heads."""
+    s = jnp.einsum("rjd,sd->jrs", qi, ki, preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * wi.T[:, :, None]).sum(axis=0)
+
+
 @jax.named_scope("index")
 def index_scores(qi, wi, ki):
     """I = sum_j w_j relu(q_j . k): qi (R, J, D), wi (R, J) float32,
     ki (S, D) -> (R, S) float32. Under the scope ``index`` wherever it is
     called (for the selection, and for the indexer's loss with its
-    derivative), so that a trace finds every index product there."""
-    s = jnp.einsum("rjd,sd->jrs", qi, ki, preferred_element_type=jnp.float32)
-    return (jax.nn.relu(s) * wi.T[:, :, None]).sum(axis=0)
+    derivative), so that a trace finds every index product there. By the
+    kernel of ops/index_scores.py, which makes each tile in VMEM, where the
+    layer's rule for kernels holds at this key extent and the kernel's
+    shape screen accepts; else by ``index_scores_xla``. Which form a step
+    traced is counted (``index_scores.counting_calls``)."""
+    from deeplearning4j_tpu import ops
+    from deeplearning4j_tpu.ops import index_scores as kernel
+    (r, j, d), s = qi.shape, ki.shape[0]
+    if _kernels_on(s) and kernel.supported(r, j, d, s, qi.dtype.itemsize):
+        kernel.note_call("kernel")
+        return kernel.index_scores(qi, wi, ki, ops.interpret_mode())
+    kernel.note_call("xla")
+    return index_scores_xla(qi, wi, ki)
 
 
 @jax.jit
@@ -368,8 +400,10 @@ def index_loss(qi, wi, ki, mask, p, rows=None):
 
 
 def _index_loss_fwd(qi, wi, ki, mask, p, rows):
+    # the value is kept beside its gradient: a replay that reads it (the
+    # layer ties its output to it) does not take the loss again
     loss, g = _index_kl(qi, wi, ki, mask, p, rows, True)
-    return loss, tuple(keep(a, "index_grads") for a in g)
+    return keep(loss, "index_grads"), tuple(keep(a, "index_grads") for a in g)
 
 
 def _index_loss_bwd(rows, g, ct):
@@ -412,10 +446,6 @@ def _fold_wide(per):
     hi = (per >> 16).sum(dtype=jnp.uint32)
     return _add_wide(jnp.stack([lo, jnp.zeros_like(lo)]),
                      jnp.stack([hi << 16, hi >> 16]))
-
-
-# below this length the score matrix is small and XLA's fused path is used
-_KERNEL_MIN_SEQ = 1024
 
 
 @register_layer
@@ -515,13 +545,9 @@ class RotaryGQAttention(Layer):
                 "keys_visible_total": jnp.zeros((2,), jnp.uint32)}
 
     def _kernel_path(self, t):
-        from deeplearning4j_tpu import ops
-        from deeplearning4j_tpu.exec.executor import tracing_partitioned
         from deeplearning4j_tpu.ops.flash_attention import gqa_supported
-        return (ops.helpers_enabled() and not tracing_partitioned()
-                and gqa_supported(t, self.head_dim, self.n_heads,
-                                  self.n_kv_heads)
-                and (ops.interpret_mode() or t >= _KERNEL_MIN_SEQ))
+        return _kernels_on(t) and gqa_supported(
+            t, self.head_dim, self.n_heads, self.n_kv_heads)
 
     def _attend(self, q, k, v):
         from deeplearning4j_tpu import ops
@@ -635,6 +661,10 @@ class RotaryGQAttention(Layer):
             return o, state
         with jax.named_scope("index_loss"):
             loss = index_loss(qi, wi, ki, sel, jax.lax.stop_gradient(p))
+            # the layer goes on when its loss is taken: left free, the
+            # compiler may schedule it after later layers' forward passes
+            # and hold p, (B, T, T) float32, until then
+            o, loss = jax.lax.optimization_barrier((o, loss))
         return o, {
             "index_loss": loss.astype(jnp.float32),
             "keys_selected": selected,

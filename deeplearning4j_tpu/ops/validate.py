@@ -17,6 +17,7 @@ Emits one JSON line per case plus a summary line.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 
@@ -407,11 +408,16 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
     gradients, ``gqa_head_mean_probs``) and the indexer's loss with its
     gradients (``index_loss``), each against a float32 loop over chunks of
     query rows at ``highest`` precision on the same inputs, and the time of
-    each beside XLA's form of the masked attention in the same dtype."""
+    each beside XLA's form of the masked attention in the same dtype. The
+    index scores in both forms (the kernel of ops/index_scores.py where its
+    screen accepts the shapes, and the einsum) on one chunk of rows against
+    all T keys: the scores, and the chunk's KL with its three gradients,
+    each against the plain form in float32, and the time of each pass."""
     from deeplearning4j_tpu.nn.layers.decoder import (
-        index_loss, index_scores, selected_keys_mask)
+        index_loss, index_scores, index_scores_xla, selected_keys_mask)
     from deeplearning4j_tpu.ops.flash_attention import (
         gqa_head_mean_probs, gqa_selected_attention)
+    from deeplearning4j_tpu.ops import index_scores as index_kernel
     from deeplearning4j_tpu import ops
     assert gqa_supported(t, dh, hq, hkv), (t, dh, hq, hkv)
     interp = ops.interpret_mode()       # off the chip: kernels interpreted
@@ -456,14 +462,21 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
         return (o.transpose(0, 2, 1, 3, 4).reshape(b, hq, t, dh),
                 pm.reshape(b, t, t))
 
+    def kl_rows(scores, qc, wc, k1, mc, pc):
+        """A chunk's KL from ``pc`` to the softmax of ``scores(qc, wc, k1)``
+        over the keys ``mc`` selects."""
+        sel = mc != 0
+        logq = jax.nn.log_softmax(
+            jnp.where(sel, scores(qc, wc, k1), -jnp.inf), axis=-1)
+        return jnp.where(sel, jax.scipy.special.xlogy(pc, pc)
+                         - pc * jnp.where(sel, logq, 0.0), 0.0).sum()
+
     def kl_loop(qi, wi, ki, mask, pm):
         def rows_of(start, q1, w1, k1, m1, p1):
-            sel, pc = cut(m1, start, 0) != 0, cut(p1, start, 0)
-            logq = jax.nn.log_softmax(jnp.where(sel, index_scores(
-                cut(q1, start, 0).astype(jnp.float32), cut(w1, start, 0),
-                k1.astype(jnp.float32)), -jnp.inf), axis=-1)
-            return jnp.where(sel, jax.scipy.special.xlogy(pc, pc)
-                             - pc * jnp.where(sel, logq, 0.0), 0.0).sum()
+            return kl_rows(index_scores_xla,
+                           cut(q1, start, 0).astype(jnp.float32),
+                           cut(w1, start, 0), k1.astype(jnp.float32),
+                           cut(m1, start, 0), cut(p1, start, 0))
         return chunks(rows_of, qi, wi, ki, mask, pm).sum() / (b * t)
 
     def top_k_loop(qi, wi, ki):
@@ -529,9 +542,40 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
         gap = float(jnp.linalg.norm(a - b_) / jnp.linalg.norm(b_))
         errs["d" + name] = gap
         assert gap <= 5 * rtol, f"index loss T={t}: d{name} norm gap {gap}"
+    # the index scores by themselves: the last chunk of rows of sequence 0
+    # against all T keys, both forms beside the plain form in float32
+    irows = min(512, t)                 # the layer's chunk
+    chunk = (qi[0, -irows:], wi[0, -irows:], ki[0], mask[0, -irows:],
+             p_ref[0, -irows:])
+    forms = {"xla": index_scores_xla}
+    if index_kernel.supported(irows, j, di, t, dt.itemsize):
+        forms["kernel"] = lambda q, w, k: index_kernel.index_scores(
+            q, w, k, interp)
+    f32 = lambda a: a.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        sc_ref = jax.jit(index_scores_xla)(f32(chunk[0]), chunk[1],
+                                           f32(chunk[2]))
+        ckl_ref = jax.jit(jax.grad(functools.partial(
+            kl_rows, index_scores_xla), argnums=(0, 1, 2)))(
+                f32(chunk[0]), chunk[1], f32(chunk[2]), *chunk[3:])
+    index_fns = {}
+    for form, fn in forms.items():
+        fwd = jax.jit(fn)
+        grad = jax.jit(jax.value_and_grad(functools.partial(kl_rows, fn),
+                                          argnums=(0, 1, 2)))
+        index_fns[form] = (fwd, grad)
+        errs[f"index_{form}"] = _max_err(fwd(*chunk[:3]), sc_ref) \
+            / float(jnp.max(jnp.abs(sc_ref)))
+        assert errs[f"index_{form}"] <= rtol, errs
+        for name, a, b_ in zip(("qi", "wi", "ki"), grad(*chunk)[1], ckl_ref):
+            gap = float(jnp.linalg.norm(f32(a) - b_) / jnp.linalg.norm(b_))
+            errs[f"index_{form}_d{name}"] = gap
+            assert gap <= 5 * rtol, \
+                f"index scores ({form}) T={t}: d{name} norm gap {gap}"
     res = {"kernel": "gqa_selected_attention", "B": b, "Hq": hq, "Hkv": hkv,
            "T": t, "Dh": dh, "index_heads": j, "index_dim": di,
            "top_k": top_k, "dtype": dtype, "keys_selected": want,
+           "index_forms": sorted(forms), "index_rows": irows,
            "selection_differs_from_top_k": differ, "index_loss": float(val),
            "errs": {n: round(e, 6) for n, e in errs.items()},
            "max_err": round(max(errs["o"], errs["dq"], errs["dk"],
@@ -547,6 +591,15 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
                    index_loss_grad_us=us(kl, qi, wi, ki, mask, p_ref),
                    fwd_xla_us=us(xla_fwd, q, k, v, mask),
                    grad_xla_us=us(xla_g, q, k, v, mask, cot))
+        # summed, so that the timing loop's one scalar needs every output
+        # whole (it reads one element: a gradient it does not read is dead
+        # code, and a slice of a product is a smaller product)
+        whole = lambda fn: jax.jit(lambda *a: sum(
+            jnp.sum(x.astype(jnp.float32))
+            for x in jax.tree_util.tree_leaves(fn(*a))))
+        for form, (fwd, grad) in index_fns.items():
+            res[f"index_fwd_{form}_us"] = us(whole(fwd), *chunk[:3])
+            res[f"index_kl_grad_{form}_us"] = us(whole(grad), *chunk)
     return res
 
 

@@ -58,7 +58,8 @@ def remat_loss(loss_fn, mode):
 # mask and its count: without it a replay scores and selects every key
 # again) and the indexer loss's gradient by the indexer's queries, head
 # weights and keys, taken in the forward pass (``index_loss``), so that the
-# index products need no name: nothing reads them again. Each costs the
+# index products need no name: nothing reads them again (the loss's own
+# value rides with them: the layer ties its output to it). Each costs the
 # replay a kernel, a sort or a matrix product and is small beside what a
 # step holds. Left to the replay: what is cheap to
 # compute again (norms, the head gate, silu(g) * u, the gather of the expert

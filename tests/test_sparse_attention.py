@@ -18,6 +18,7 @@ from deeplearning4j_tpu.nn.layers import (DenseLayer, ExpertLayer,
                                           OutputLayer, RotaryGQAttention)
 from deeplearning4j_tpu.nn.layers import decoder
 from deeplearning4j_tpu.nn.updaters import Sgd
+from deeplearning4j_tpu.ops import index_scores as index_kernel
 from perfbench.lib import arch, reference_lm, reference_sparse_lm as ref
 from perfbench.jobs import fit_lm, fit_sparse_lm as job
 
@@ -152,21 +153,99 @@ def test_a_tie_at_the_last_place_goes_to_the_lower_position():
     assert got.sum(axis=1).tolist() == [1, 2, 3, 3]
 
 
-def test_selection_by_chunks_is_the_selection_of_top_k(kernels):
+@pytest.mark.parametrize("t,di,rows,forms", [
+    (48, DI, 8, {"xla"}),               # under the kernel's screen
+    (256, 8, 128, {"kernel"}),          # extents 128 and 256: whole blocks
+    (256, 8, 64, {"kernel", "xla"}),    # extents 64 and 192 fall back
+])
+def test_selection_by_chunks_is_the_selection_of_top_k(kernels, t, di, rows,
+                                                       forms):
     """``selected_keys_mask`` over uneven row groups against ``top_k`` of
-    the whole score matrix."""
+    the whole score matrix, with the scores of the chunks from the kernel,
+    from the einsum, and from both."""
     rs = np.random.default_rng(5)
-    t = 48
-    qi = jnp.asarray(rs.normal(size=(1, t, J, DI)).astype(np.float32))
+    qi = jnp.asarray(rs.normal(size=(1, t, J, di)).astype(np.float32))
     wi = jnp.asarray(rs.normal(size=(1, t, J)).astype(np.float32))
-    ki = jnp.asarray(rs.normal(size=(1, t, DI)).astype(np.float32))
-    got = np.asarray(decoder.selected_keys_mask(qi, wi, ki, TOP, rows=8))[0]
-    score = np.asarray(decoder.index_scores(qi[0], wi[0], ki[0]))
+    ki = jnp.asarray(rs.normal(size=(1, t, di)).astype(np.float32))
+    with index_kernel.counting_calls({}) as calls:
+        got = np.asarray(decoder.selected_keys_mask(qi, wi, ki, TOP,
+                                                    rows=rows))[0]
+    assert set(calls) == forms
+    score = np.asarray(decoder.index_scores_xla(qi[0], wi[0], ki[0]))
     vis = np.tril(np.ones((t, t), bool))
     _, idx = jax.lax.top_k(jnp.where(vis, score, -jnp.inf), TOP)
     want = np.zeros((t, t), bool)
     np.put_along_axis(want, np.asarray(idx), True, axis=1)
     np.testing.assert_array_equal(got != 0, want & vis)
+
+
+# ------------------------------------------- the index scores' two forms
+
+def _index_case(r, s, di, dtype, seed=0):
+    rs = np.random.default_rng(seed)
+    return (jnp.asarray(rs.normal(size=(r, J, di)), dtype),
+            jnp.asarray(rs.normal(size=(r, J)), jnp.float32),
+            jnp.asarray(rs.normal(size=(s, di)), dtype),
+            jnp.asarray(rs.normal(size=(r, s)), jnp.float32))
+
+
+@pytest.mark.parametrize("r,s,di,dtype,form", [
+    (32, 128, 8, "float32", "kernel"),      # one key block
+    (64, 384, 16, "float32", "kernel"),     # three blocks of 128
+    (512, 512, 8, "float32", "kernel"),     # the layer's chunk of rows
+    (16, 256, 8, "bfloat16", "kernel"),
+    (32, 192, 8, "float32", "xla"),         # not a whole number of blocks
+    (32, 128, 4, "float32", "xla"),         # the head dim under a tile
+    (36, 128, 8, "float32", "xla"),         # rows that are no whole tiles
+])
+def test_index_scores_and_their_gradients_in_both_forms(kernels, r, s, di,
+                                                        dtype, form):
+    """``index_scores`` against the einsum in float32 at ``highest``: the
+    value and the gradients by qi, wi, ki under a cotangent; the form it
+    took is the one the shape screen says, and is counted."""
+    qi, wi, ki, g = _index_case(r, s, di, jnp.dtype(dtype))
+    f32 = lambda a: a.astype(jnp.float32)
+    assert index_kernel.supported(r, J, di, s, qi.dtype.itemsize) \
+        == (form == "kernel")
+    with jax.default_matmul_precision("highest"):
+        with index_kernel.counting_calls({}) as calls:
+            got, back = jax.vjp(decoder.index_scores, qi, wi, ki)
+        want, plain = jax.vjp(decoder.index_scores_xla, f32(qi), wi, f32(ki))
+        assert calls == {form: 1}
+        tol = dict(rtol=2e-2, atol=2e-1) if dtype == "bfloat16" \
+            else dict(rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got, want, **tol)
+        for a, b in zip(back(g), plain(g)):
+            np.testing.assert_allclose(f32(a), b, **tol)
+
+
+def test_the_indexers_loss_over_chunks_past_the_first_in_both_forms():
+    """``index_loss`` and its three gradients at 256 positions in chunks of
+    64 rows (the chunks after the first start past row 0; the extents 128
+    and 256 take the kernel, 64 and 192 the einsum) against the same loss
+    with the kernels off."""
+    rs = np.random.default_rng(11)
+    t, di = 256, 8
+    qi = jnp.asarray(rs.normal(size=(1, t, J, di)).astype(np.float32))
+    wi = jnp.asarray(rs.normal(size=(1, t, J)).astype(np.float32))
+    ki = jnp.asarray(rs.normal(size=(1, t, di)).astype(np.float32))
+    p = jnp.asarray(rs.uniform(size=(1, t, t)).astype(np.float32))
+    got = {}
+    for on in (False, True):
+        prev = ops.set_helpers_enabled(on, interpret=on)
+        try:
+            with jax.default_matmul_precision("highest"), \
+                    index_kernel.counting_calls({}) as calls:
+                mask = decoder.selected_keys_mask(qi, wi, ki, TOP, rows=64)
+                got[on] = jax.value_and_grad(
+                    lambda *a: decoder.index_loss(*a, mask, p, 64),
+                    argnums=(0, 1, 2))(qi, wi, ki)
+        finally:
+            ops.set_helpers_enabled(prev[0], interpret=prev[1])
+        assert set(calls) == ({"kernel", "xla"} if on else {"xla"})
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7),
+        got[True], got[False])
 
 
 # --------------------------------------------------- the layer-loss door
@@ -248,17 +327,27 @@ def test_a_layers_own_loss_term_reaches_the_score_and_the_gradient(
 
 # ------------------------------------------------------ the model, fit()
 
-def _net(cfg, layers=None, **kw):
+def _net(cfg, layers=None, index_dim=None, **kw):
     cfg = dict(cfg, program=dict(cfg["program"], kwargs=dict(
         cfg["program"]["kwargs"], **kw)))
+    model = dict(cfg["rehearsal"]["model"])
     if layers:
-        cfg["rehearsal"] = dict(cfg["rehearsal"], model=dict(
-            cfg["rehearsal"]["model"], num_hidden_layers=layers))
+        model["num_hidden_layers"] = layers
+    if index_dim:
+        model["sa_config"] = dict(model["sa_config"],
+                                  indexer_head_dim=index_dim)
+    cfg["rehearsal"] = dict(cfg["rehearsal"], model=model)
     return cfg, job.build_net(cfg)
 
 
-def _batches(cfg, n, seed=0):
-    return job.make_pool(cfg, {"pool_batches": n}, seed, 2, 32)
+def _batches(cfg, n, seed=0, seq=32):
+    return job.make_pool(cfg, {"pool_batches": n}, seed, 2, seq)
+
+
+# (positions, index head dim, the form the index scores take with kernels
+# on): the rehearsal's own size is under the kernel's shape screen; 128
+# positions of index heads of 8 are one key block
+SIZES = [(32, None, "xla"), (128, 8, "kernel")]
 
 
 def test_sparse_decoder_from_this_familys_keys_and_from_lagunas(cfg):
@@ -389,31 +478,70 @@ def _count(jaxpr, into, inside=None, within=False):
     return into
 
 
-def test_a_block_replay_selects_no_key_and_scores_no_index_again(cfg, kernels):
-    """The gradient's jaxpr under ``remat='blocks'``: the four kernels of a
-    layer (selected attention forward, the head-mean weights, dq, dk/dv)
-    once each, as without replay; and inside the replayed blocks no
-    selection, none of the loops over row chunks that score the index
-    (``lax.map`` / ``scan``: the selection's and the indexer loss's, whose
-    gradient was taken in the forward pass) and of the kernels only the two
-    backward ones."""
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (a kernel's
+    body too)."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else (v,)):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from _eqns(j)
+
+
+def _grad_jaxpr(cfg, remat, seq, index_dim):
+    cfg2, net = _net(cfg, layers=2, remat=remat, index_dim=index_dim)
+    ids, labels = (jnp.asarray(a) for a in _batches(cfg2, 1, seq=seq)[0])
+    loss = net._loss_for_grad()
+    return jax.make_jaxpr(jax.grad(
+        lambda p: loss(p, net.state, [ids], [labels], jax.random.PRNGKey(0),
+                       None, None)[0]))(net.params).jaxpr
+
+
+@pytest.mark.parametrize("seq,index_dim,form", SIZES)
+def test_a_block_replay_selects_no_key_and_scores_no_index_again(
+        cfg, kernels, seq, index_dim, form):
+    """The gradient's jaxpr under ``remat='blocks'``: the kernels of a
+    layer (selected attention forward, the head-mean weights, dq, dk/dv,
+    and where the index scores take the kernel their forward for the
+    selection and their forward and backward under the loss) once each, as
+    without replay; and inside the replayed blocks no selection, none of
+    the loops over row chunks that score the index (``lax.map`` / ``scan``:
+    the selection's and the indexer loss's, whose gradient was taken in the
+    forward pass) and of the kernels only the attention's two backward
+    ones: no index kernel."""
     seen = {}
     for remat in (None, "blocks"):
-        cfg2, net = _net(cfg, layers=2, remat=remat)
-        pool = _batches(cfg2, 1)
-        ids, labels = (jnp.asarray(a) for a in pool[0])
-        loss = net._loss_for_grad()
-        jaxpr = jax.make_jaxpr(jax.grad(
-            lambda p: loss(p, net.state, [ids], [labels],
-                           jax.random.PRNGKey(0), None, None)[0]))(
-                               net.params).jaxpr
+        jaxpr = _grad_jaxpr(cfg, remat, seq, index_dim)
         seen[remat] = (_count(jaxpr, {}), _count(jaxpr, {}, "remat2"))
     (plain, _), (blocks, replay) = seen[None], seen["blocks"]
     assert plain["jit:_select_rows"] >= 2 and plain["scan"] >= 4
-    assert blocks["pallas_call"] == plain["pallas_call"] == 4 * 2
+    per_layer = 4 + 3 * (form == "kernel")
+    assert blocks["pallas_call"] == plain["pallas_call"] == per_layer * 2
     assert blocks["remat2"] == 2 and "remat2" not in plain
     assert "jit:_select_rows" not in replay and "scan" not in replay
     assert replay["pallas_call"] == 2 * 2
+
+
+def test_no_heads_by_rows_by_keys_array_on_the_kernel_path(cfg):
+    """The step's gradient at 128 positions with index heads of 8: with the
+    kernels on no matrix product anywhere in it (the kernels' bodies
+    included) makes an array with the index heads over (rows, keys); with
+    them off the einsum and its derivative do."""
+    def heads_over_pairs(jaxpr):
+        return [e.outvars[0].aval.shape for e in _eqns(jaxpr)
+                if e.primitive.name == "dot_general"
+                and sorted(e.outvars[0].aval.shape) == [J, 128, 128]]
+
+    assert len(heads_over_pairs(_grad_jaxpr(cfg, "blocks", 128, 8))) >= 2 * 2
+    prev = ops.set_helpers_enabled(True, interpret=True)
+    try:
+        jaxpr = _grad_jaxpr(cfg, "blocks", 128, 8)
+    finally:
+        ops.set_helpers_enabled(prev[0], interpret=prev[1])
+    assert heads_over_pairs(jaxpr) == []
+    assert any(e.primitive.name == "pallas_call" for e in _eqns(jaxpr))
 
 
 def test_eight_shares_without_a_shared_expert_add_up_to_the_uncut_layer():
@@ -501,25 +629,46 @@ def test_a_training_step_without_the_layers_state_raises():
     assert s is None and y.shape == x.shape
 
 
-def test_the_new_scopes_are_in_the_compiled_step(cfg, kernels):
+@pytest.mark.parametrize("seq,index_dim,form", SIZES)
+def test_the_new_scopes_are_in_the_compiled_step(cfg, kernels, seq,
+                                                 index_dim, form):
+    """The compiled step's ``op_scopes`` name the four scopes; the index
+    scores' instructions (the kernel's, where they take it) sit under
+    ``index``, innermost under ``select`` and under ``index_loss``; and the
+    record says which form the step's index-score calls took."""
     from deeplearning4j_tpu.exec.programs import get_programs
-    cfg2, net = _net(cfg, layers=2, remat="blocks")
-    pool = _batches(cfg2, 1)
+    from deeplearning4j_tpu.monitor.metrics import get_registry
+    cfg2, net = _net(cfg, layers=2, remat="blocks", index_dim=index_dim)
+    pool = _batches(cfg2, 1, seq=seq)
     net.fit(iter([DataSet(*pool[0])]))
     recs = [e for e in get_programs().entries()
             if e["caller"] == net._prog_caller
             and e["key"].startswith("train_step")]
     table = get_programs().get(net._prog_caller, recs[-1]["key"])["op_scopes"]
-    paths = set(table.values())
+    paths = {p.replace("jvp(", "").replace("transpose(", "").replace(")", "")
+             for p in table.values()}
     for scope in ("index", "select", "attend", "index_loss"):
-        assert any(f"RotaryGQAttention/{scope}" in p.replace("jvp(", "")
-                   .replace(")", "") or f"/{scope}/" in p or
-                   p.endswith(f"/{scope}") for p in paths), scope
+        assert any(f"RotaryGQAttention/{scope}" in p or f"/{scope}/" in p
+                   or p.endswith(f"/{scope}") for p in paths), scope
+    for outer in ("select", "index_loss"):
+        assert any(f"/{outer}/" in p and "/index" in p.split(f"/{outer}/")[1]
+                   for p in paths), outer
+    # every index-score call of the step took one form: per layer the
+    # selection's one row group and the loss's (traced as the loss and as
+    # its forward rule)
+    calls = recs[-1]["index_scores_calls"]
+    assert calls == {"kernel": 0, "xla": 0, form: 2 * 3}
+    fam = get_registry().get("dl4jtpu_index_scores_calls")
+    mine = {k: c.value for k, c in fam.children() if net._prog_caller in k}
+    assert sorted(mine.values()) == [0, 2 * 3]
     kept = recs[-1].get("remat_kept_bytes") or {}
-    # 2 layers x (2 x 32 x 32 int8 mask + its count, two uint32 words)
-    assert kept.get("selection") == 2 * (2 * 32 * 32 + 8)
-    # 2 layers x float32 (qI 2 x 32 x 2 x 4, wI 2 x 32 x 2, kI 2 x 32 x 4)
-    assert kept.get("index_grads") == 2 * 4 * (512 + 128 + 256)
+    # 2 layers x (2 x T x T int8 mask + its count, two uint32 words)
+    assert kept.get("selection") == 2 * (2 * seq * seq + 8)
+    # 2 layers x float32 (qI 2 x T x 2 x D, wI 2 x T x 2, kI 2 x T x D, and
+    # the loss's own value)
+    di = index_dim or DI
+    assert kept.get("index_grads") \
+        == 2 * 4 * (2 * seq * (2 * di + 2 + di) + 1)
 
 
 def test_the_chip_screen_of_the_selected_attention_runs_small(kernels):
@@ -527,7 +676,14 @@ def test_the_chip_screen_of_the_selected_attention_runs_small(kernels):
     under a mask, at a size the CPU takes, kernels interpreted."""
     from deeplearning4j_tpu.ops import validate
     r = validate.validate_selected_attention_case(
-        1, 4, 2, 64, 8, 2, 4, 16, dtype="float32", time_it=False)
-    assert r["keys_selected"] == sum(min(i + 1, 16) for i in range(64))
+        1, 4, 2, 128, 8, 2, 8, 16, dtype="float32", time_it=False)
+    assert r["keys_selected"] == sum(min(i + 1, 16) for i in range(128))
     assert r["selection_differs_from_top_k"] == 0
     assert r["max_err"] < 1e-3 and r["errs"]["kl"] < 1e-4
+    # the index scores' rows: both forms at this size, each against the
+    # plain form in float32
+    assert r["index_forms"] == ["kernel", "xla"] and r["index_rows"] == 128
+    for form in r["index_forms"]:
+        assert r["errs"][f"index_{form}"] < 1e-5
+        assert all(r["errs"][f"index_{form}_d{n}"] < 1e-4
+                   for n in ("qi", "wi", "ki"))

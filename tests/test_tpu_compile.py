@@ -35,6 +35,7 @@ from deeplearning4j_tpu.ops import lstm_pallas
 flash_attention = importlib.import_module(
     "deeplearning4j_tpu.ops.flash_attention")
 flash_decode = importlib.import_module("deeplearning4j_tpu.ops.flash_decode")
+index_scores = importlib.import_module("deeplearning4j_tpu.ops.index_scores")
 
 
 @pytest.fixture(scope="module")
@@ -217,6 +218,28 @@ def test_gqa_head_mean_probs(one_chip):
                      ((1, 16384, 16384), jnp.int8))
     _agree(flash_attention.gqa_supported(16384, 128, 32, 4),
            flash_attention.gqa_head_mean_probs, shapes)
+
+
+# a chunk of the same cell's indexer: 512 query rows of 16 heads of 64
+# against the keys up to the end of the first and of the last row group, in
+# the cell's bfloat16; and the most rows that are one block within the
+# VMEM the kernels ask for, with the next size up, in each dtype
+@pytest.mark.parametrize("r,s,dtype", [(512, 2048, "bfloat16"),
+                                       (512, 16384, "bfloat16"),
+                                       (896, 2048, "bfloat16"),
+                                       (1024, 2048, "bfloat16"),
+                                       (640, 2048, "float32"),
+                                       (896, 2048, "float32")])
+def test_index_scores_fwd_and_grad(one_chip, r, s, dtype):
+    def loss(q, w, k, g):
+        return (index_scores.index_scores(q, w, k) * g).sum()
+
+    shapes = _shapes(one_chip, ((r, 16, 64), jnp.dtype(dtype)),
+                     ((r, 16), jnp.float32), ((s, 64), jnp.dtype(dtype)),
+                     ((r, s), jnp.float32))
+    _agree(index_scores.supported(r, 16, 64, s,
+                                  jnp.dtype(dtype).itemsize),
+           jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=2)
 
 
 def test_ragged_dot_is_one_grouped_product_on_the_chip(one_chip):
