@@ -703,6 +703,7 @@ class BaseNetwork:
             layers, self.state,
             tokens=int(np.prod(shown[0].shape[:2])) if shown else 0)
         self._mon.publish_selection_counters(layers, self.state)
+        self._mon.publish_ssm_counters(layers, self.state)
 
     def _fit_batch(self, batch):
         """One step on one batch: a ``DataSet``, the container's own batch

@@ -19,6 +19,17 @@ from deeplearning4j_tpu.monitor.metrics import (
 __all__ = ["TrainMonitor"]
 
 
+def _inc_wide(counter, seen, key, words):
+    """Advance ``counter`` to a layer's running total kept as (low, high)
+    uint32 words in its state; ``seen[key]`` is the total at the last call.
+    A state set back to zero starts the sum again."""
+    lo, hi = (int(w) for w in words)
+    now = (hi << 32) | lo
+    last = seen.get(key, 0)
+    counter.inc(now - last if now >= last else now)
+    seen[key] = now
+
+
 class TrainMonitor:
     """Cached metric children for one model container instance."""
 
@@ -28,6 +39,7 @@ class TrainMonitor:
         self._kind = model_kind
         self._moe = None              # dl4jtpu_moe_* families, on first use
         self._sel = None              # dl4jtpu_sparse_attention_*, likewise
+        self._ssm = None              # dl4jtpu_ssm_*, likewise
         self.steps = reg.counter(
             "dl4jtpu_train_steps_total",
             "Train steps executed (fit_scan counts every scanned step).",
@@ -83,6 +95,7 @@ class TrainMonitor:
         if not keys:
             return
         import jax
+        names = ("pairs_total", "pairs_dropped_total", "pairs", "load_max")
         if self._moe is None:
             reg = get_registry()
             lab = ("model", "layer")
@@ -110,7 +123,8 @@ class TrainMonitor:
                     "pair, above 1 where the step paid for later rounds.",
                     lab)}
             self._moe_seen = {}
-        got = jax.device_get({k: state[k] for k in keys})
+        got = jax.device_get({k: {n: state[k][n] for n in names}
+                              for k in keys})
         for k in keys:
             lab = {"model": self._kind, "layer": str(layers[k].name or k)}
             for name in ("pairs_total", "pairs_dropped_total"):
@@ -161,12 +175,40 @@ class TrainMonitor:
         for k in keys:
             lab = {"model": self._kind, "layer": str(layers[k].name or k)}
             for name in ("keys_selected_total", "keys_visible_total"):
-                lo, hi = (int(w) for w in got[k][name])
-                now = (hi << 32) | lo
-                last = self._sel_seen.get((k, name), 0)
-                # a state set back to zero starts the sum again
-                self._sel[name].labels(**lab).inc(
-                    now - last if now >= last else now)
-                self._sel_seen[(k, name)] = now
+                _inc_wide(self._sel[name].labels(**lab), self._sel_seen,
+                          (k, name), got[k][name])
             self._sel["index_loss"].labels(**lab).set(
                 float(got[k]["index_loss"]))
+
+    def publish_ssm_counters(self, layers, state) -> None:
+        """At the end of a streamed fit call: what the state-space mixers
+        counted inside the steps (their state), as
+        ``dl4jtpu_ssm_tokens_total`` and ``dl4jtpu_ssm_decay_mean`` labelled
+        by layer. The total is (low, high) uint32 words in the state and
+        exact here. One host read a layer; a model without such layers
+        reads nothing."""
+        keys = [k for k in layers if state[k] and "decay_mean" in state[k]]
+        if not keys:
+            return
+        import jax
+        if self._ssm is None:
+            reg = get_registry()
+            lab = ("model", "layer")
+            self._ssm = {
+                "tokens_total": reg.counter(
+                    "dl4jtpu_ssm_tokens_total",
+                    "Positions the mixer's recurrence ran over in training "
+                    "steps.", lab),
+                "decay_mean": reg.gauge(
+                    "dl4jtpu_ssm_decay_mean",
+                    "Mean over positions and heads of the state's decay "
+                    "exp(delta a) in the last step: near 1 the state "
+                    "remembers far back, near 0 it forgets at once.", lab)}
+            self._ssm_seen = {}
+        got = jax.device_get({k: state[k] for k in keys})
+        for k in keys:
+            lab = {"model": self._kind, "layer": str(layers[k].name or k)}
+            _inc_wide(self._ssm["tokens_total"].labels(**lab),
+                      self._ssm_seen, k, got[k]["tokens_total"])
+            self._ssm["decay_mean"].labels(**lab).set(
+                float(got[k]["decay_mean"]))

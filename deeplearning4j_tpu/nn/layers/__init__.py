@@ -33,6 +33,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
 from deeplearning4j_tpu.nn.layers.decoder import (
     RMSNorm, SwiGLU, RotaryGQAttention, ExpertLayer,
 )
+from deeplearning4j_tpu.nn.layers.ssm import Mamba2Mixer
 from deeplearning4j_tpu.nn.layers.pretrain import RBM
 
 __all__ = [
@@ -50,5 +51,5 @@ __all__ = [
     "GlobalPoolingLayer", "AutoEncoder", "VariationalAutoencoder",
     "CenterLossOutputLayer", "Yolo2OutputLayer", "FrozenLayer",
     "MultiHeadAttention", "LayerNormalization", "PositionalEmbedding", "RBM",
-    "RMSNorm", "SwiGLU", "RotaryGQAttention", "ExpertLayer",
+    "RMSNorm", "SwiGLU", "RotaryGQAttention", "ExpertLayer", "Mamba2Mixer",
 ]

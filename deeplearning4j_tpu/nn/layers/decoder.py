@@ -1,7 +1,9 @@
 """Layers of a sparse decoder block: RMSNorm, rotary grouped-query
 attention with a window, a head gate or a learned selection of keys (an
 indexer with a loss of its own), a SwiGLU MLP, and a dropless top-k expert
-layer that holds a share of the experts.
+layer that holds a share of the experts (SwiGLU or relu^2 experts, softmax
+or sigmoid scores, on the hidden width or inside a latent). The state-space
+mixer of a hybrid decoder is nn/layers/ssm.py.
 
 The reference (DL4J 0.9.2) has none of them. Each is a plain ``Layer``: the
 containers hold it, ``model_serializer`` writes it, ``util/scopes.py`` names
@@ -712,16 +714,29 @@ def _top_k_bwd(k, res, ct):
 _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
-def route_top_k(x2, wr, k, norm_topk, routed_scale):
-    """Softmax over all experts, the k largest, their weights. x2: (N, C).
-    Returns (idx (N, k) int32, p (N, k) float32). The router's product is
-    kept across a block's replay (util/remat.py), named before the softmax
-    because softmax's derivative reads its own result, not a name put on
-    it; the top k are kept by ``_top_k``."""
-    s = jax.nn.softmax(keep(
-        jnp.dot(x2, wr, preferred_element_type=jnp.float32), "routing"),
-        axis=-1)
-    val, idx = _top_k(s, k)
+def route_top_k(x2, wr, k, norm_topk, routed_scale, score="softmax",
+                bias=None):
+    """Scores over all experts, the k chosen, their weights. x2: (N, C).
+    ``score``: ``"softmax"`` over the experts, the k largest chosen and
+    weighed by their scores; or ``"sigmoid"``, a score an expert, the k
+    largest of score + ``bias`` chosen (a selection bias (E,), None: zero)
+    and weighed by the score alone: the bias chooses and does not weigh,
+    and no gradient reaches it. Returns (idx (N, k) int32, p (N, k)
+    float32). The router's product is kept across a block's replay
+    (util/remat.py), named before the softmax or sigmoid because their
+    derivatives read their own results, not a name put on them; the top k
+    are kept by ``_top_k``, or, where nothing differentiates the choice
+    (``sigmoid``), the indices by name."""
+    logits = keep(jnp.dot(x2, wr, preferred_element_type=jnp.float32),
+                  "routing")
+    if score == "softmax":
+        val, idx = _top_k(jax.nn.softmax(logits, axis=-1), k)
+    else:
+        s = jax.nn.sigmoid(logits)
+        pick = s if bias is None else s + bias.astype(jnp.float32)
+        idx = keep(jax.lax.top_k(jax.lax.stop_gradient(pick), k)[1],
+                   "routing")
+        val = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
         val = val / val.sum(axis=-1, keepdims=True)
     return idx.astype(jnp.int32), val * routed_scale
@@ -731,22 +746,24 @@ def route_top_k(x2, wr, k, norm_topk, routed_scale):
 # (``jvp(round)``): this one takes the wrapper, so that ``dispatch``,
 # ``experts`` and ``combine`` keep the names device traces are read by
 @jax.named_scope("round")
-def _expert_round(rows, k, r, x2, eg, eu, ed, pw, order, starts, ends, total,
+def _expert_round(rows, k, r, x2, ws, pw, order, starts, ends, total,
                   kept=False):
     """Round ``r`` of the routed part: ``rows`` of the pairs sorted by
-    expert go through the three grouped products and are added to their
-    tokens, weighted. x2: (N, C); eg, eu: (E, C, W); ed: (E, W, C); pw: the
-    pairs' weights, flat (N*k,); order: the pairs sorted by expert (N*k,),
-    of which the first ``total`` fall on experts held; starts, ends: each
+    expert go through the grouped products and are added to their tokens,
+    weighted. x2: (N, C); ``ws``: the experts' stacked weights, (Eg, Eu
+    (E, C, W), Ed (E, W, C)) for SwiGLU experts or (E1 (E, C, W), E2
+    (E, W, C)) for relu^2 experts, which have no gate; pw: the pairs'
+    weights, flat (N*k,); order: the pairs sorted by expert (N*k,), of
+    which the first ``total`` fall on experts held; starts, ends: each
     expert's sorted rows. The round takes what it needs from ``order`` and
     ``pw`` itself, so nothing is sized by the rounds there could be. It owns
     sorted rows ``r*rows`` and up; its buffer is the window of ``order``
     that starts there, or that ends with ``order`` if that comes first (the
     last round of a routing that fills every round), and a row before its
     own or past the last held pair points past the tokens: the gathers fill
-    it with zeros and the scatters drop it. ``kept``: the gate and up
-    products are kept across a block's replay (util/remat.py): round 0,
-    which every step runs; the later rounds are loops, where a name keeps
+    it with zeros and the scatters drop it. ``kept``: the products into the
+    experts' width are kept across a block's replay (util/remat.py): round
+    0, which every step runs; the later rounds are loops, where a name keeps
     nothing. Returns ((N, C) float32, the pairs this round computed: rows
     inside an expert's group that carry a token, int32)."""
     n, c = x2.shape
@@ -764,11 +781,19 @@ def _expert_round(rows, k, r, x2, eg, eu, ed, pw, order, starts, ends, total,
     with jax.named_scope("experts"):
         def gmm(a, w, out=None):
             return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=out)
-        g, u = gmm(xs, eg), gmm(xs, eu)
-        if kept:
-            g, u = keep(g, "expert_gate_up"), keep(u, "expert_gate_up")
-        h = (jax.nn.silu(g.astype(jnp.float32))
-             * u.astype(jnp.float32)).astype(x2.dtype)
+        if len(ws) == 3:
+            eg, eu, ed = ws
+            g, u = gmm(xs, eg), gmm(xs, eu)
+            if kept:
+                g, u = keep(g, "expert_gate_up"), keep(u, "expert_gate_up")
+            h = (jax.nn.silu(g.astype(jnp.float32))
+                 * u.astype(jnp.float32)).astype(x2.dtype)
+        else:
+            e1, ed = ws
+            u = gmm(xs, e1)
+            if kept:
+                u = keep(u, "expert_gate_up")
+            h = jnp.square(jax.nn.relu(u.astype(jnp.float32))).astype(x2.dtype)
         ys = gmm(h, ed, jnp.float32)
     with jax.named_scope("combine"):
         ys = jnp.where((t_r < n)[:, None], ys * w_r[:, None], 0.0)
@@ -786,7 +811,7 @@ def _later_rounds(rows, rounds, k, first, diff, rest):
     """Rounds 1.. added to round 0's ``first`` = (y, pairs computed): as
     many as the pairs left need, none under a routing that fits round 0.
     ``rounds``: how many the worst routing needs (``round_rows``): a layer
-    whose round 0 holds that traces no loop. ``diff``: x2, eg, eu, ed, pw;
+    whose round 0 holds that traces no loop. ``diff``: x2, ws, pw;
     ``rest``: order, starts, ends, total."""
     if rounds == 1:
         return first
@@ -799,24 +824,22 @@ def _later_rounds(rows, rounds, k, first, diff, rest):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _expert_rounds(rows, rounds, k, x2, eg, eu, ed, pw, order, starts, ends,
-                   total):
+def _expert_rounds(rows, rounds, k, x2, ws, pw, order, starts, ends, total):
     """Every round of the routed part that the routing needs: round 0, then
     a loop whose trip count is the data's. One derivative rule over all of
     them, so that a step whose routing fits round 0 pays for round 0 alone:
     the later rounds' loop is carried from round 0's result, and their
     gradients are added to round 0's only where one ran (a rule for the
     later rounds alone would hand autodiff zero gradients in the shape of
-    x2, eg, eu and ed to write and add in every step). Returns (y, the
-    pairs computed)."""
-    diff, rest = (x2, eg, eu, ed, pw), (order, starts, ends, total)
+    x2 and the experts' weights to write and add in every step). Returns
+    (y, the pairs computed)."""
+    diff, rest = (x2, ws, pw), (order, starts, ends, total)
     return _later_rounds(rows, rounds, k,
                          _expert_round(rows, k, 0, *diff, *rest), diff, rest)
 
 
-def _rounds_fwd(rows, rounds, k, x2, eg, eu, ed, pw, order, starts, ends,
-                total):
-    diff, rest = (x2, eg, eu, ed, pw), (order, starts, ends, total)
+def _rounds_fwd(rows, rounds, k, x2, ws, pw, order, starts, ends, total):
+    diff, rest = (x2, ws, pw), (order, starts, ends, total)
     # round 0 is left to autodiff, whose residuals a block's replay keeps
     # by name; the later rounds keep nothing and run again backward
     y, back, done = jax.vjp(
@@ -830,6 +853,7 @@ def _rounds_bwd(rows, rounds, k, res, ct):
     back, diff, rest = res
     dy = ct[0]                     # the count is an integer: no cotangent
     grads = back(dy)
+    tmap = jax.tree_util.tree_map
 
     def later(grads):
         """Round 0's gradients plus those of rounds 1.., summed in
@@ -837,13 +861,12 @@ def _rounds_bwd(rows, rounds, k, res, ct):
         def body(r, acc):
             _, vjp = jax.vjp(
                 lambda *d: _expert_round(rows, k, r, *d, *rest)[0], *diff)
-            return jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(jnp.float32), acc, vjp(dy))
+            return tmap(lambda a, g: a + g.astype(jnp.float32), acc, vjp(dy))
 
         acc = jax.lax.fori_loop(
             1, _rounds_needed(rows, rest[-1]), body,
-            tuple(g.astype(jnp.float32) for g in grads))
-        return tuple(a.astype(g.dtype) for a, g in zip(acc, grads))
+            tmap(lambda g: g.astype(jnp.float32), grads))
+        return tmap(lambda a, g: a.astype(g.dtype), acc, grads)
 
     # a branch, not the loop alone: seeded with round 0's gradients the
     # loop would widen them to float32 and narrow them again in a step that
@@ -859,8 +882,8 @@ _expert_rounds.defvjp(_rounds_fwd, _rounds_bwd)
 @register_layer
 @dataclass
 class ExpertLayer(Layer):
-    """Top-k mixture of SwiGLU experts plus an ungated shared expert, for a
-    chip that holds a share of the experts.
+    """Top-k mixture of experts plus an ungated shared expert, for a chip
+    that holds a share of the experts.
 
     The router scores all ``n_experts`` and picks ``experts_per_token``;
     this layer holds ``experts_held = (count, first)``: experts
@@ -874,12 +897,25 @@ class ExpertLayer(Layer):
     further rounds run only when the routing is so uneven that pairs are
     left, so nothing is dropped.
 
-    Param keys: Wr (n_in, n_experts); Eg, Eu (count, n_in, expert_width),
-    Ed (count, expert_width, n_in); Sg, Su, Sd the shared expert.
+    What a configuration sets beside the sizes: ``expert_form``,
+    ``"swiglu"`` ((silu(x Wg) * (x Wu)) Wd) or ``"relu2"`` (relu(x W1)^2 W2,
+    no gate), the routed experts and the shared one alike; ``score``,
+    ``"softmax"`` over the experts or ``"sigmoid"`` with a selection bias
+    that chooses and does not weigh (``route_top_k``); ``latent_width``
+    (0: none): the routed experts act inside a latent of that width, x
+    projected down before the dispatch and the combined result up after it,
+    while the router and the shared expert read the full width.
+
+    Param keys: Wr (n_in, n_experts); Eg, Eu (count, C, expert_width), Ed
+    (count, expert_width, C) or, for ``relu2``, E1, E2 of those shapes,
+    with C the latent width where there is one; Sg, Su, Sd or S1, S2 the
+    shared expert; Wdown (n_in, latent_width), Wup (latent_width, n_in).
     State (updated on training steps, read at the fit loop's boundary):
     ``pairs_total`` and ``pairs_dropped_total`` (int32, wrapping; dropped
     is the pairs routed here minus the pairs the rounds that ran counted as
-    computed), ``pairs`` and ``load_max`` of the last step."""
+    computed), ``pairs`` and ``load_max`` of the last step; with
+    ``sigmoid`` also ``select_bias`` (n_experts,), which no step changes
+    and the updater never sees: it is state, not a parameter."""
     n_in: int = 0
     n_experts: int = 8
     experts_per_token: int = 2
@@ -888,6 +924,9 @@ class ExpertLayer(Layer):
     routed_scale: float = 1.0
     norm_topk: bool = True
     experts_held: Optional[tuple] = None     # (count, first index)
+    expert_form: str = "swiglu"
+    score: str = "softmax"
+    latent_width: int = 0
 
     def set_n_in(self, input_type):
         if self.n_in == 0:
@@ -902,32 +941,56 @@ class ExpertLayer(Layer):
         return tuple(self.experts_held) if self.experts_held \
             else (self.n_experts, 0)
 
+    @property
+    def _leaves(self):
+        """The names of the routed experts' stacked weights and of the
+        shared expert's, the projection out of the width last."""
+        if self.expert_form == "relu2":
+            return ("E1", "E2"), ("S1", "S2")
+        return ("Eg", "Eu", "Ed"), ("Sg", "Su", "Sd")
+
     def init(self, rng, dtype=jnp.float32):
         require_dims(self, n_in=self.n_in, expert_width=self.expert_width)
+        if self.expert_form not in ("swiglu", "relu2") \
+                or self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"expert_form={self.expert_form!r} (swiglu | "
+                             f"relu2), score={self.score!r} (softmax | "
+                             "sigmoid)")
         count, first = self.held
         if not (0 <= first and first + count <= self.n_experts and count > 0):
             raise ValueError(f"experts_held={self.experts_held} lies outside "
                              f"0..{self.n_experts}")
         k = jax.random.split(rng, 7)
         c, w = self.n_in, self.expert_width
+        inner = self.latent_width or c
 
         def stack(key, shape):
             return jnp.stack([_w(self, kk, shape, dtype)
                               for kk in jax.random.split(key, count)])
 
-        p = {"Wr": _w(self, k[0], (c, self.n_experts), dtype),
-             "Eg": stack(k[1], (c, w)), "Eu": stack(k[2], (c, w)),
-             "Ed": stack(k[3], (w, c))}
+        routed, shared = self._leaves
+        p = {"Wr": _w(self, k[0], (c, self.n_experts), dtype)}
+        for i, name in enumerate(routed):
+            p[name] = stack(k[1 + i], (w, inner) if name == routed[-1]
+                            else (inner, w))
         if self.shared_width:
-            p["Sg"] = _w(self, k[4], (c, self.shared_width), dtype)
-            p["Su"] = _w(self, k[5], (c, self.shared_width), dtype)
-            p["Sd"] = _w(self, k[6], (self.shared_width, c), dtype)
+            for i, name in enumerate(shared):
+                p[name] = _w(self, k[4 + i], (self.shared_width, c)
+                             if name == shared[-1] else (c, self.shared_width),
+                             dtype)
+        if self.latent_width:
+            kd, ku = jax.random.split(jax.random.fold_in(rng, 7))
+            p["Wdown"] = _w(self, kd, (c, inner), dtype)
+            p["Wup"] = _w(self, ku, (inner, c), dtype)
         return p
 
     def init_state(self, dtype=jnp.float32):
         # one buffer each: the step donates its state
-        return {k: jnp.zeros((), jnp.int32) for k in
-                ("pairs_total", "pairs_dropped_total", "pairs", "load_max")}
+        state = {k: jnp.zeros((), jnp.int32) for k in
+                 ("pairs_total", "pairs_dropped_total", "pairs", "load_max")}
+        if self.score == "sigmoid":
+            state["select_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
+        return state
 
     # -- the routed part ---------------------------------------------------
     def round_rows(self, n_tokens):
@@ -939,10 +1002,11 @@ class ExpertLayer(Layer):
         rows = min(worst, -(-int(math.ceil(_ROUND_SLACK * even)) // 8) * 8)
         return rows, -(-worst // rows)
 
-    def routed(self, params, x2, first=None):
+    def routed(self, params, x2, first=None, bias=None):
         """Sum over the held experts of weight * expert(x) for the pairs
         routed to them. x2: (N, C). ``first``: index of the first expert
         held (a traced value under ``shard_map``; None: the layer's own).
+        ``bias``: the selection bias of a ``sigmoid`` router (None: zero).
         Returns (y (N, C) float32, counters dict)."""
         n, c = x2.shape
         count = self.held[0]
@@ -951,7 +1015,10 @@ class ExpertLayer(Layer):
         rows, rounds = self.round_rows(n)
         with jax.named_scope("route"):
             idx, p = route_top_k(x2, params["Wr"], k, self.norm_topk,
-                                 self.routed_scale)
+                                 self.routed_scale, self.score, bias)
+        if self.latent_width:
+            with jax.named_scope("latent_down"):
+                x2 = x2 @ params["Wdown"]
         with jax.named_scope("dispatch"):
             local = (idx - first).reshape(-1)
             key = jnp.where((local >= 0) & (local < count), local, count)
@@ -964,8 +1031,12 @@ class ExpertLayer(Layer):
             starts, total = ends - counts, ends[-1]
 
         y, done = _expert_rounds(
-            rows, rounds, k, x2, params["Eg"], params["Eu"], params["Ed"],
+            rows, rounds, k, x2, tuple(params[w] for w in self._leaves[0]),
             p.reshape(-1), order, starts, ends, total)
+        if self.latent_width:
+            with jax.named_scope("latent_up"):
+                y = jnp.dot(y.astype(x2.dtype), params["Wup"],
+                            preferred_element_type=jnp.float32)
         # ``done`` is counted by the rounds that ran, so a round left out
         # reads as pairs dropped
         return y, {"pairs": total, "pairs_dropped": total - done,
@@ -973,18 +1044,26 @@ class ExpertLayer(Layer):
 
     def shared(self, params, x2):
         with jax.named_scope("shared"):
-            return swiglu(x2, params["Sg"], params["Su"], params["Sd"])
+            if self.expert_form == "swiglu":
+                return swiglu(x2, params["Sg"], params["Su"], params["Sd"])
+            u = keep(jnp.dot(x2, params["S1"]), "gate_up")
+            h = jnp.square(jax.nn.relu(u.astype(jnp.float32)))
+            return jnp.dot(h.astype(x2.dtype), params["S2"])
 
     def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
         b, t, c = x.shape
         x2 = x.reshape(b * t, c)
-        y, seen = self.routed(params, x2)
+        bias = state.get("select_bias") if state else None
+        y, seen = self.routed(params, x2, bias=bias)
         if self.shared_width:
             y = y + self.shared(params, x2)
         if train and state:
-            state = {
+            new = {
                 "pairs_total": state["pairs_total"] + seen["pairs"],
                 "pairs_dropped_total": (state["pairs_dropped_total"]
                                         + seen["pairs_dropped"]),
                 "pairs": seen["pairs"], "load_max": seen["load_max"]}
+            if bias is not None:
+                new["select_bias"] = bias
+            state = new
         return y.astype(x.dtype).reshape(b, t, c), state

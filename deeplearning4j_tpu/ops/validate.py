@@ -603,6 +603,80 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
     return res
 
 
+def validate_ssd_scan_case(b, t, h, p, g, n, chunk, dtype="bfloat16", tol=3e-2,
+                           time_it=True):
+    """The state-space mixer's chunked scan (``nn/layers/ssm.py:ssd_scan``)
+    at one layer's shapes against the recurrence itself, a float32 loop
+    over the T positions (a state (H, P, N) a step, kept every ``chunk``
+    positions for the gradient), on the same inputs: the output and the
+    gradients of x, dt, a, B and C by relative norm gap, and the time of a
+    forward-and-gradient pass of each. Steps are log-uniform in [0.001,
+    0.1] and a in 1..16, as the layer starts them."""
+    from deeplearning4j_tpu.nn.layers.ssm import ssd_scan
+    dt_ = jnp.dtype(dtype)
+    rs = np.random.RandomState(t + h)
+    x = jnp.asarray(rs.randn(b, t, h, p), dt_)
+    bm = jnp.asarray(rs.randn(b, t, g, n), dt_)
+    cm = jnp.asarray(rs.randn(b, t, g, n) / np.sqrt(n), dt_)
+    step = jnp.asarray(np.exp(rs.uniform(np.log(1e-3), np.log(1e-1),
+                                         (b, t, h))), jnp.float32)
+    a = -jnp.asarray(rs.uniform(1.0, 16.0, (h,)), jnp.float32)
+    cot = jnp.asarray(rs.randn(b, t, h, p), jnp.float32)
+
+    def ours(x, step, a, bm, cm):
+        return jnp.sum(ssd_scan(x, step, a, bm, cm, chunk) * cot)
+
+    def plain(x, step, a, bm, cm):
+        f32 = lambda v: v.astype(jnp.float32)
+        rep = h // g
+        xs = (jnp.exp(step * a), step[..., None] * f32(x),
+              jnp.repeat(f32(bm), rep, axis=2), jnp.repeat(f32(cm), rep, axis=2))
+        xs = jax.tree_util.tree_map(
+            lambda v: jnp.moveaxis(v, 1, 0).reshape(
+                t // chunk, chunk, b, *v.shape[2:]), xs)
+
+        def position(s, v):
+            dec, dx, bb, cc = v
+            s = dec[..., None, None] * s + dx[..., None] * bb[..., None, :]
+            return s, (s * cc[..., None, :]).sum(axis=-1)
+
+        _, y = jax.lax.scan(
+            jax.checkpoint(lambda s, v: jax.lax.scan(position, s, v)),
+            jnp.zeros((b, h, p, n), jnp.float32), xs)
+        y = jnp.moveaxis(y.reshape(t, b, h, p), 0, 1)
+        return jnp.sum(y * cot)
+
+    args = (x, step, a, bm, cm)
+    ours_g = jax.jit(jax.value_and_grad(ours, argnums=(0, 1, 2, 3, 4)))
+    plain_g = jax.jit(jax.value_and_grad(plain, argnums=(0, 1, 2, 3, 4)))
+    got, g_got = ours_g(*args)
+    want, g_want = plain_g(*args)
+
+    def gap(u, v):
+        u, v = u.astype(jnp.float32), v.astype(jnp.float32)
+        return float(jnp.linalg.norm(u - v) / (jnp.linalg.norm(v) + 1e-30))
+
+    gaps = {"y": abs(float(got) - float(want)) / (abs(float(want)) + 1e-30)}
+    for name, u, v in zip(("dx", "ddt", "da", "dB", "dC"), g_got, g_want):
+        gaps[name] = gap(u, v)
+    for name, v in gaps.items():
+        assert v <= tol or name == "y", \
+            f"ssd_scan T={t} H={h} chunk={chunk}: {name} gap {v}"
+    res = {"kernel": "ssd_scan", "B": b, "T": t, "H": h, "P": p, "G": g,
+           "N": n, "chunk": chunk, "dtype": dtype,
+           "gaps": {k: round(v, 6) for k, v in gaps.items()},
+           "max_err": round(max(v for k, v in gaps.items() if k != "y"), 6)}
+    if time_it:
+        res.update(grad_us=round(_time(ours_g, *args) * 1e6, 1),
+                   grad_ref_us=round(_time(plain_g, *args) * 1e6, 1))
+    return res
+
+
+# (B, T, H, P, G, N, chunk): one chip's share of a Mamba-2 mixer of the
+# benchmark's hybrid decoder at its step's 8192 positions, and a small case
+SSD_SWEEP = [(1, 8192, 16, 64, 1, 128, 128), (2, 256, 4, 16, 2, 16, 32)]
+SSD_QUICK = SSD_SWEEP[1:]
+
 # (B, Hq, Hkv, T, Dh, index heads, index dim, top_k): one layer of the
 # benchmark's decoder with a learned selection at its step's one sequence
 # of 16,384 positions, and a small case
@@ -708,6 +782,15 @@ def run(quick=False, time_it=True):
             print(json.dumps(r))
         except Exception as e:  # noqa: BLE001
             failures.append({"kernel": "gqa_selected_attention", "case": case,
+                             "error": f"{type(e).__name__}: {e}"[:300]})
+            print(json.dumps(failures[-1]))
+    for case in (SSD_QUICK if quick else SSD_SWEEP):
+        try:
+            r = validate_ssd_scan_case(*case, time_it=time_it)
+            results.append(r)
+            print(json.dumps(r))
+        except Exception as e:  # noqa: BLE001
+            failures.append({"kernel": "ssd_scan", "case": case,
                              "error": f"{type(e).__name__}: {e}"[:300]})
             print(json.dumps(failures[-1]))
     summary = {"backend": jax.default_backend(),

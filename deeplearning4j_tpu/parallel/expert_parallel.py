@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-EXPERT_LEAVES = ("Eg", "Eu", "Ed")
+EXPERT_LEAVES = ("Eg", "Eu", "Ed", "E1", "E2")
 
 
 def shard_expert_params(params, mesh: Mesh, axis: str = "expert"):

@@ -59,14 +59,16 @@ def remat_loss(loss_fn, mode):
 # again) and the indexer loss's gradient by the indexer's queries, head
 # weights and keys, taken in the forward pass (``index_loss``), so that the
 # index products need no name: nothing reads them again (the loss's own
-# value rides with them: the layer ties its output to it). Each costs the
-# replay a kernel, a sort or a matrix product and is small beside what a
-# step holds. Left to the replay: what is cheap to
+# value rides with them: the layer ties its output to it); of a Mamba mixer
+# (nn/layers/ssm.py) the input projection's result [z | x B C | dt], two
+# thirds of the layer's operations in 38 MB a layer at 8192 positions. Each
+# costs the replay a kernel, a sort or a matrix product and is small beside
+# what a step holds. Left to the replay: what is cheap to
 # compute again (norms, the head gate, silu(g) * u, the gather of the expert
 # rows) and the output projection, whose result is as large as q and spares
 # one product.
 BLOCK_KEPT = ("qkv", "attn_out", "routing", "expert_gate_up", "gate_up",
-              "selection", "index_grads")
+              "selection", "index_grads", "ssm_proj")
 
 _counting = threading.local()
 

@@ -2,11 +2,13 @@
 configuration's own keys.
 
 No counterpart in the reference zoo (which tops out at recurrent text
-models). The graph is ``EmbeddingSequenceLayer`` in, per layer
-``RMSNorm -> RotaryGQAttention -> add -> RMSNorm -> SwiGLU | ExpertLayer ->
-add``, a final ``RMSNorm`` and an untied ``RnnOutputLayer`` that takes
-integer labels. Nodes of layer i are named ``b<i>.<node>``, so that
-``remat='blocks'`` replays one layer at a time.
+models). The graph is ``EmbeddingSequenceLayer`` in, the layers, a final
+``RMSNorm`` and an untied ``RnnOutputLayer`` that takes integer labels. A
+layer is an attention + MLP pair, ``RMSNorm -> RotaryGQAttention -> add ->
+RMSNorm -> SwiGLU | ExpertLayer -> add``, or, in a hybrid stack, one mixer
+alone, ``RMSNorm -> Mamba2Mixer | ExpertLayer | RotaryGQAttention -> add``.
+Nodes of layer i are named ``b<i>.<node>``, so that ``remat='blocks'``
+replays one layer at a time.
 """
 
 from __future__ import annotations
@@ -57,8 +59,21 @@ class SparseDecoder(ZooModel):
     which layers are sparse, ``sa_config`` (``indexer_num_heads``,
     ``indexer_head_dim``, ``topk``: attention over a learned selection of
     keys) and ``qk_norm`` (an RMSNorm on each head of q and k).
+    A third family, a hybrid stack of one mixer a layer:
+    ``hybrid_override_pattern``, a character a layer (``M`` a Mamba-2
+    mixer from ``mamba_num_heads``, ``mamba_head_dim``, ``n_groups``,
+    ``ssm_state_size``, ``conv_kernel``, ``chunk_size``; ``E`` an expert
+    layer of ``n_routed_experts`` experts of ``moe_intermediate_size`` in
+    the form ``mlp_hidden_act`` names (``relu2`` | ``silu``), sigmoid
+    scores, top ``num_experts_per_tok`` times ``routed_scaling_factor``,
+    inside ``moe_latent_size`` where that is given, beside
+    ``n_shared_experts`` shared ones of
+    ``moe_shared_expert_intermediate_size``; ``*`` attention without
+    positional rotation), with ``layer_norm_epsilon``.
     ``experts_held=(count, first)`` gives the expert layers a share of the
-    experts (None: all)."""
+    experts (None: all): ``first`` picks the router's columns. A share of
+    the heads of attention or of the Mamba mixers (whole groups) is the
+    configuration's own counts."""
     name = "sparsedecoder"
 
     def __init__(self, config, seed: int = 123, experts_held=None, **kwargs):
@@ -70,12 +85,12 @@ class SparseDecoder(ZooModel):
         self.experts_held = experts_held
 
     def conf(self):
-        from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
         from deeplearning4j_tpu.nn.layers import (
-            EmbeddingSequenceLayer, RnnOutputLayer, RMSNorm, SwiGLU,
-            RotaryGQAttention, ExpertLayer)
+            EmbeddingSequenceLayer, RnnOutputLayer, RMSNorm)
         c = self.config
-        vocab, hidden, eps = c["vocab_size"], c["hidden_size"], c["rms_norm_eps"]
+        hybrid = "hybrid_override_pattern" in c
+        vocab, hidden = c["vocab_size"], c["hidden_size"]
+        eps = c["layer_norm_epsilon" if hybrid else "rms_norm_eps"]
         g = (NeuralNetConfiguration.builder()
              .seed(self.seed)
              .updater(self.updater(Adam(1e-4)))
@@ -85,7 +100,65 @@ class SparseDecoder(ZooModel):
              .set_input_types(InputType.recurrent(vocab)))
         g.add_layer("embed", EmbeddingSequenceLayer(
             n_in=vocab, n_out=hidden, activation="identity"), "tokens")
-        prev = "embed"
+        layers = self._hybrid_layers if hybrid else self._paired_layers
+        g.add_layer("final_norm", RMSNorm(eps=eps), layers(g, "embed", eps))
+        g.add_layer("head", RnnOutputLayer(
+            n_out=vocab, activation="softmax", loss="mcxent",
+            has_bias=False), "final_norm")
+        return g.set_outputs("head").build()
+
+    def _hybrid_layers(self, g, prev, eps):
+        """``b<i>.norm -> b<i>.mixer -> b<i>.add`` for every character of
+        ``hybrid_override_pattern``; returns the last node."""
+        from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
+        from deeplearning4j_tpu.nn.layers import (
+            ExpertLayer, Mamba2Mixer, RMSNorm, RotaryGQAttention)
+        c = self.config
+        pattern = c["hybrid_override_pattern"]
+        if set(pattern) - set("ME*"):
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: a layer is M (Mamba-2), "
+                "E (experts) or * (attention)")
+        forms = {"relu2": "relu2", "silu": "swiglu"}
+        for i, kind in enumerate(pattern):
+            b = f"b{i}"
+            g.add_layer(f"{b}.norm", RMSNorm(eps=eps), prev)
+            if kind == "M":
+                mixer = Mamba2Mixer(
+                    n_heads=c["mamba_num_heads"], head_dim=c["mamba_head_dim"],
+                    n_groups=c["n_groups"], state_size=c["ssm_state_size"],
+                    conv_kernel=c["conv_kernel"], chunk_size=c["chunk_size"],
+                    norm_eps=eps)
+            elif kind == "E":
+                mixer = ExpertLayer(
+                    n_experts=c["n_routed_experts"],
+                    experts_per_token=c["num_experts_per_tok"],
+                    expert_width=c["moe_intermediate_size"],
+                    shared_width=(c.get("moe_shared_expert_intermediate_size", 0)
+                                  * c.get("n_shared_experts", 0)),
+                    routed_scale=c.get("routed_scaling_factor", 1.0),
+                    norm_topk=c.get("norm_topk_prob", True),
+                    experts_held=self.experts_held,
+                    expert_form=forms[c.get("mlp_hidden_act", "relu2")],
+                    score="sigmoid",
+                    latent_width=c.get("moe_latent_size") or 0)
+            else:
+                mixer = RotaryGQAttention(
+                    n_heads=c["num_attention_heads"],
+                    n_kv_heads=c["num_key_value_heads"],
+                    head_dim=c["head_dim"], rotary=None)
+            g.add_layer(f"{b}.mixer", mixer, f"{b}.norm")
+            g.add_vertex(f"{b}.add", ElementWiseVertex(op="add"),
+                         f"{b}.mixer", prev)
+            prev = f"{b}.add"
+        return prev
+
+    def _paired_layers(self, g, prev, eps):
+        """An attention + MLP pair a layer; returns the last node."""
+        from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
+        from deeplearning4j_tpu.nn.layers import (
+            RMSNorm, SwiGLU, RotaryGQAttention, ExpertLayer)
+        c = self.config
         kinds = c.get("layer_types") \
             or ["full_attention"] * c["num_hidden_layers"]
         heads = c.get("num_attention_heads_per_layer") \
@@ -129,8 +202,4 @@ class SparseDecoder(ZooModel):
             g.add_vertex(f"{b}.add2", ElementWiseVertex(op="add"),
                          f"{b}.mlp", f"{b}.add1")
             prev = f"{b}.add2"
-        g.add_layer("final_norm", RMSNorm(eps=eps), prev)
-        g.add_layer("head", RnnOutputLayer(
-            n_out=vocab, activation="softmax", loss="mcxent",
-            has_bias=False), "final_norm")
-        return g.set_outputs("head").build()
+        return prev

@@ -5,6 +5,7 @@ through each of its chosen experts, one at a time."""
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import Mesh
 
 from deeplearning4j_tpu.nn.layers import ExpertLayer
@@ -67,14 +68,19 @@ def test_nothing_dropped_under_skew():
                                    rtol=1e-3, atol=1e-4, err_msg=k)
 
 
-def test_sharded_over_expert_axis_equals_unsharded():
+# the second form too: relu^2 experts inside a latent behind a sigmoid
+# router, whose stacked leaves are E1, E2
+@pytest.mark.parametrize("form,leaf", [
+    ({}, "Eg"),
+    ({"expert_form": "relu2", "score": "sigmoid", "latent_width": 4}, "E1")])
+def test_sharded_over_expert_axis_equals_unsharded(form, leaf):
     mesh = Mesh(np.array(jax.devices()[:4]), ("expert",))
-    layer = _layer()
+    layer = _layer(**form)
     p = layer.init(jax.random.PRNGKey(3))
     x = _x(32, 3)
     want, _ = layer.apply(p, x[None])
     sharded = shard_expert_params(p, mesh)
-    assert len(sharded["Eg"].sharding.device_set) == 4
+    assert len(sharded[leaf].sharding.device_set) == 4
     y, seen = jax.jit(lambda p, x: expert_parallel_apply(layer, p, x, mesh))(
         sharded, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want[0]),

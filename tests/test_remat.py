@@ -376,8 +376,9 @@ def test_the_registry_says_what_the_blocks_keep(cfg, kernels, kind, mlp):
             "attn_out": 2 * n * HEADS * (HD + 1) * f32,     # o and lse
             "routing": 0, "expert_gate_up": 0,
             "gate_up": 2 * 2 * n * 64 * f32,
-            # a layer without an indexer keeps no selection
-            "selection": 0, "index_grads": 0}
+            # a layer without an indexer keeps no selection, a stack
+            # without a state-space mixer no input projection
+            "selection": 0, "index_grads": 0, "ssm_proj": 0}
     if mlp == "sparse":
         rows, _ = net.conf.nodes["b0.mlp"].layer.round_rows(n)
         want.update(

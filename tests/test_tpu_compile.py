@@ -254,6 +254,25 @@ def test_ragged_dot_is_one_grouped_product_on_the_chip(one_chip):
     assert "ragged-dot" in compiled.as_text()
 
 
+def test_ssd_scan_fwd_and_grad_at_the_cells_shapes(one_chip):
+    """The state-space mixer's chunked scan (XLA's form, no kernel) with its
+    gradient at one chip's share of the hybrid cell's layer: it compiles
+    for the chip, and holds no (T, T) array: the largest temporaries are
+    the (chunks, heads, 128, 128) float32 tiles, 67 MB each."""
+    from deeplearning4j_tpu.nn.layers.ssm import ssd_scan
+
+    def loss(x, dt, a, b, c):
+        return ssd_scan(x, dt, a, b, c, 128).sum()
+
+    shapes = _shapes(one_chip, ((1, 8192, 16, 64), jnp.bfloat16),
+                     ((1, 8192, 16), jnp.float32), ((16,), jnp.float32),
+                     ((1, 8192, 1, 128), jnp.bfloat16),
+                     ((1, 8192, 1, 128), jnp.bfloat16))
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4))).lower(*shapes).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8192 * 8192 * 4
+
+
 # ----------------------------------------------------------- flash decode
 @pytest.mark.parametrize("b,h,dh,c", [(4, 4, 32, 512), (8, 8, 128, 1024)])
 def test_flash_decode_dense(one_chip, b, h, dh, c):
