@@ -16,6 +16,9 @@ f32 accumulation.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -240,35 +243,88 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 # ===================================================== grouped-query, banded
 # Causal attention for decoder training: K and V are read by kv head (a
-# query head h reads kv head h // group, no copy repeated in memory), keys
-# are streamed block by block over the grid with the running max, sum and
-# accumulator in VMEM scratch, operands stay in their own dtype (bfloat16 on
-# the training path) with float32 accumulation, and with a ``window`` only
-# the key blocks inside the band are visited: the grid's last axis is as long
-# as the band, not the sequence.
+# query head h reads kv head h // group, no copy repeated in memory) and a
+# grid step holds the WHOLE query group of one kv head on one (query block,
+# key block) tile: the key block, the value block and the mask tile are
+# fetched once a step and serve all ``group`` heads, and what is visible on
+# the tile is worked out once a step. Keys are streamed tile by tile with
+# the running max, sum and accumulator in VMEM scratch; operands stay in
+# their own dtype (bfloat16 on the training path) with float32 accumulation.
+# The grid's last axis is the list of the tiles that hold a visible pair
+# (``_tile_pairs``, prefetched to SMEM): above the diagonal and outside a
+# ``window``'s band there is no step at all.
 
-def _band_blocks(t, window, rows, cols):
-    """How many blocks of ``cols`` a block of ``rows`` can need: the whole
-    sequence without a window, else the band's width."""
-    if window is None:
-        return t // cols
-    return min(t // cols, (rows + window - 2) // cols + 2)
+# what a step's query-side blocks may hold in VMEM (q, do and dq double
+# buffered, dq's float32 accumulator, and the log-sum-exp and delta columns,
+# which pad to 128 lanes): 2,048 rows at a head of 128 in bfloat16. With the
+# key-side blocks and a product's temporaries the dq pass then holds about
+# 14 MiB, inside the 16 MiB Mosaic scopes a kernel to on v5e by default
+_GQA_STEP_BYTES = 8 * 1024 * 1024
+# the rows of one product inside a step: a 512 x 512 tile's float32 scores
+# and their temporaries are 4 MiB. On the chip 256 rows ran 17 % slower,
+# 1,024 a slower forward pass, and all 2,048 rows 6 % faster for a 64 MiB
+# VMEM limit and four times the kernels' code (PERF.md §6, PR 37)
+_GQA_PRODUCT_ROWS = 512
 
 
-def _kv_range(iq, bq, bk, window):
-    """First and last key block a query block needs."""
-    hi = ((iq + 1) * bq - 1) // bk
-    if window is None:
-        return 0, hi
-    return jnp.maximum(iq * bq - window + 1, 0) // bk, hi
+class GqaPlan(NamedTuple):
+    """How the three kernels tile one sequence (``gqa_plan``)."""
+    bq: int         # query rows of a tile
+    bk: int         # keys of a tile
+    heads: int      # query heads stacked as the rows of one product
+    rows: int       # query rows a grid step holds: group * bq
+    steps: int      # grid steps a pass: kv heads * tiles with a visible pair
+    skipped: int    # steps a (query block, band of key blocks) grid adds
 
 
-def _q_range(jk, bq, bk, window, nq):
-    """First and last query block that sees a key block."""
-    lo = (jk * bk) // bq
-    if window is None:
-        return lo, nq - 1
-    return lo, jnp.minimum(((jk + 1) * bk + window - 2) // bq, nq - 1)
+def _pick_gqa_block(t):
+    for b in (512, 256, 128, 64, 32, 16, 8):
+        if t % b == 0:
+            return b
+    return None
+
+
+def _tile_pairs(t, bq, bk, window, by_key=False):
+    """The (query block, key block) tiles that hold a visible pair, as int32
+    rows (outer block, inner block, first of its outer, last of its outer):
+    query-major for the forward and dq passes, key-major (``by_key``) for
+    dk/dv, whose outer block is the key block."""
+    tiles = []
+    for i in range(t // bq):
+        lo = 0 if window is None else max(i * bq - window + 1, 0) // bk
+        tiles += [(i, j) for j in range(lo, ((i + 1) * bq - 1) // bk + 1)]
+    if by_key:
+        tiles = sorted((j, i) for i, j in tiles)
+    outer, inner = np.asarray(tiles).T
+    edge = np.flatnonzero(np.diff(outer)) + 1
+    first, last = np.zeros_like(outer), np.zeros_like(outer)
+    first[np.r_[0, edge]] = 1
+    last[np.r_[edge - 1, len(outer) - 1]] = 1
+    return np.stack([outer, inner, first, last]).astype(np.int32)
+
+
+def gqa_plan(t, n_heads, n_kv_heads, dh, window, block=None, itemsize=2):
+    """The tile of ``gqa_flash_attention`` and ``gqa_selected_attention`` at
+    ``t`` positions, from the shapes alone. The key block is the largest of
+    512..8 that divides ``t``; the query block is the largest of the key
+    block's halvings whose ``group * bq`` rows keep a step's query-side
+    blocks within ``_GQA_STEP_BYTES``; as many heads as fit
+    ``_GQA_PRODUCT_ROWS`` rows are stacked in one product, and the step
+    walks the group in such sub-groups. ``block`` sets both blocks."""
+    group = n_heads // n_kv_heads
+    bq = bk = block or _pick_gqa_block(t)
+    if not block:
+        lanes = -(-dh // 128) * 128
+        row = lanes * (6 * itemsize + 4) + 2 * 2 * 128 * 4
+        while bq > 8 and group * bq * row > _GQA_STEP_BYTES:
+            bq //= 2
+    heads = max(h for h in range(1, group + 1)
+                if group % h == 0 and (h == 1 or h * bq <= _GQA_PRODUCT_ROWS))
+    tiles = _tile_pairs(t, bq, bk, window).shape[1]
+    band = (t // bq) * (t // bk if window is None
+                        else min(t // bk, (bq + window - 2) // bk + 2))
+    return GqaPlan(bq, bk, heads, group * bq, n_kv_heads * tiles,
+                   n_kv_heads * (band - tiles))
 
 
 def _visible(qpos, kpos, window):
@@ -278,113 +334,148 @@ def _visible(qpos, kpos, window):
     return ok
 
 
-def _block_visible(mask_ref, iq, kb, bq, bk, window):
-    """Which (query, key) pairs of one block are attended: by position, or
-    where a ``mask`` operand is given by the mask alone (a selection that is
-    data already holds causality: ``selected_keys_mask``)."""
+def _tile_bias(mask_ref, iq, kb, bq, bk, window):
+    """0 where a (query, key) pair of one tile is attended and -1e30 where
+    not: by position, or where a ``mask`` operand is given by the mask alone
+    (a selection that is data already holds causality:
+    ``selected_keys_mask``). Added to float32 scores it leaves a visible
+    score as it is and makes a hidden one exactly -1e30."""
     if mask_ref is not None:
-        return mask_ref[...].astype(jnp.int32) != 0
-    qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return _visible(qpos, kpos, window)
+        ok = mask_ref[...].astype(jnp.int32) != 0
+    else:
+        qpos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = kb * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        ok = _visible(qpos, kpos, window)
+    return jnp.where(ok, 0.0, _NEG)
 
 
-def _gqa_fwd_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, window, scale,
-                    masked=False):
+def _each_sub_group(ref, heads, body):
+    """``body(rows)`` for every sub-group of ``heads`` heads of the group
+    that ``ref``'s leading axis holds, in a rolled loop."""
+    n = ref.shape[0] // heads
+    if n == 1:
+        body(pl.ds(0, heads))
+    else:
+        lax.fori_loop(0, n, lambda c, _: body(pl.ds(c * heads, heads)), None)
+
+
+def _stacked(ref, rows):
+    """The heads ``rows`` of a (group, block, Dh) ref as one operand."""
+    return ref[rows].reshape(-1, ref.shape[-1])
+
+
+def _scores(q, k, bias_s, scale):
+    """(heads, bq, bk) float32 scores of stacked query heads, hidden pairs
+    at -1e30."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    return s.reshape(-1, *bias_s.shape) + bias_s[...]
+
+
+def _gqa_fwd_kernel(tiles_ref, q_ref, k_ref, v_ref, *rest, bq, bk, heads,
+                    window, scale, masked=False):
     mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
-    o_ref, lse_ref, m_s, l_s, acc_s = rest
-    iq, j = pl.program_id(2), pl.program_id(3)
-    lo, hi = _kv_range(iq, bq, bk, window)
-    kb = lo + j
+    o_ref, lse_ref, m_s, l_s, acc_s, bias_s = rest
+    step = pl.program_id(2)
+    iq, kb = tiles_ref[0, step], tiles_ref[1, step]
 
-    @pl.when(j == 0)
+    @pl.when(tiles_ref[2, step] == 1)
     def _():
         m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    @pl.when(kb <= hi)
-    def _():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(_block_visible(mask_ref, iq, kb, bq, bk, window), s,
-                      _NEG)
-        m = m_s[...]
+    bias_s[...] = _tile_bias(mask_ref, iq, kb, bq, bk, window)
+
+    def sub_group(rows):
+        k, v = k_ref[...], v_ref[...]
+        s = _scores(_stacked(q_ref, rows), k, bias_s, scale)
+        m = m_s[rows]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_s[...] = l_s[...] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_s[...] = acc_s[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_s[...] = m_new
+        l_s[rows] = l_s[rows] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_s[rows] = acc_s[rows] * alpha + jnp.dot(
+            p.reshape(-1, bk).astype(v.dtype), v,
+            preferred_element_type=jnp.float32).reshape(heads, bq, -1)
+        m_s[rows] = m_new
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    _each_sub_group(q_ref, heads, sub_group)
+
+    @pl.when(tiles_ref[3, step] == 1)
     def _():
         o_ref[...] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
         lse_ref[...] = m_s[...] + jnp.log(l_s[...])
 
 
-def _gqa_dq_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, window, scale,
-                   masked=False):
-    mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
-    do_ref, lse_ref, delta_ref, dq_ref, dq_s = rest
-    iq, j = pl.program_id(2), pl.program_id(3)
-    lo, hi = _kv_range(iq, bq, bk, window)
-    kb = lo + j
+def _probs_and_ds(q, k, v, do, lse_ref, delta_ref, rows, bias_s, scale):
+    """A sub-group's attention weights and score gradients on one tile,
+    (heads * bq, bk) float32 each."""
+    p = jnp.exp(_scores(q, k, bias_s, scale) - lse_ref[rows])
+    dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    ds = p * (dp.reshape(p.shape) - delta_ref[rows]) * scale
+    return p.reshape(dp.shape), ds.reshape(dp.shape)
 
-    @pl.when(j == 0)
+
+def _gqa_dq_kernel(tiles_ref, q_ref, k_ref, v_ref, *rest, bq, bk, heads,
+                   window, scale, masked=False):
+    mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
+    do_ref, lse_ref, delta_ref, dq_ref, dq_s, bias_s = rest
+    step = pl.program_id(2)
+    iq, kb = tiles_ref[0, step], tiles_ref[1, step]
+
+    @pl.when(tiles_ref[2, step] == 1)
     def _():
         dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
 
-    @pl.when(kb <= hi)
-    def _():
-        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        p = jnp.where(_block_visible(mask_ref, iq, kb, bq, bk, window),
-                      jnp.exp(s - lse_ref[...]), 0.0)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[...]) * scale).astype(k.dtype)
-        dq_s[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+    bias_s[...] = _tile_bias(mask_ref, iq, kb, bq, bk, window)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    def sub_group(rows):
+        k = k_ref[...]
+        _, ds = _probs_and_ds(
+            _stacked(q_ref, rows), k, v_ref[...], _stacked(do_ref, rows),
+            lse_ref, delta_ref, rows, bias_s, scale)
+        dq_s[rows] += jnp.dot(
+            ds.astype(k.dtype), k,
+            preferred_element_type=jnp.float32).reshape(heads, bq, -1)
+
+    _each_sub_group(q_ref, heads, sub_group)
+
+    @pl.when(tiles_ref[3, step] == 1)
     def _():
         dq_ref[...] = dq_s[...].astype(dq_ref.dtype)
 
 
-def _gqa_dkv_kernel(q_ref, k_ref, v_ref, *rest, bq, bk, window, scale, nq,
-                    masked=False):
+def _gqa_dkv_kernel(tiles_ref, q_ref, k_ref, v_ref, *rest, bq, bk, heads,
+                    window, scale, masked=False):
     mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
-    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s = rest
-    jk, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    lo, hi = _q_range(jk, bq, bk, window, nq)
-    qb = lo + i
+    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, bias_s = rest
+    step = pl.program_id(2)
+    jk, qb = tiles_ref[0, step], tiles_ref[1, step]
 
-    @pl.when(jnp.logical_and(g == 0, i == 0))
+    @pl.when(tiles_ref[2, step] == 1)
     def _():
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    @pl.when(qb <= hi)
-    def _():
-        q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        p = jnp.where(_block_visible(mask_ref, qb, jk, bq, bk, window),
-                      jnp.exp(s - lse_ref[...]), 0.0)
+    bias_s[...] = _tile_bias(mask_ref, qb, jk, bq, bk, window)
+
+    def sub_group(rows):
+        q, do = _stacked(q_ref, rows), _stacked(do_ref, rows)
+        p, ds = _probs_and_ds(q, k_ref[...], v_ref[...], do, lse_ref,
+                              delta_ref, rows, bias_s, scale)
+        # the group's heads are summed by the products' contraction
         dv_s[...] += lax.dot_general(p.astype(do.dtype), do,
                                      (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[...]) * scale).astype(q.dtype)
-        dk_s[...] += lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+        dk_s[...] += lax.dot_general(ds.astype(q.dtype), q,
+                                     (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
 
-    @pl.when(jnp.logical_and(g == pl.num_programs(3) - 1,
-                             i == pl.num_programs(4) - 1))
+    _each_sub_group(q_ref, heads, sub_group)
+
+    @pl.when(tiles_ref[3, step] == 1)
     def _():
         dk_ref[...] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
@@ -396,70 +487,63 @@ def gqa_supported(t, dh, n_heads, n_kv_heads):
             and n_heads % n_kv_heads == 0)
 
 
-def _pick_gqa_block(t):
-    for b in (512, 256, 128, 64, 32, 16, 8):
-        if t % b == 0:
-            return b
-    return None
-
-
-def _gqa_call(kernel, grid, in_specs, out_specs, out_shape, scratch, args,
-              interpret):
+def _gqa_call(kernel, plan, tiles, b, hkv, in_specs, out_specs, out_shape,
+              scratch, args, interpret):
+    """One pass over the grid (B, Hkv, tile of ``tiles``)."""
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=grid, in_specs=in_specs,
-            out_specs=out_specs, scratch_shapes=scratch),
+            num_scalar_prefetch=1, grid=(b, hkv, tiles.shape[1]),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch
+            + [pltpu.VMEM((plan.bq, plan.bk), jnp.float32)]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3
-            + ("arbitrary",) * (len(grid) - 3)),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(jnp.asarray(tiles), *args)
 
 
-def _gqa_specs(bq, bk, dh, group, window):
-    """Block specs of the kernels whose grid is (B, Hq, query block, key
-    step): a query block, the key/value block of the head's kv head at that
-    step of the band (held at the last one needed, so that a skipped step
-    fetches nothing), a per-row vector, and the (query block, key block)
-    tile of a (B, T, T) mask that all heads share."""
-    def kv_index(b_, h, i, j):
-        lo, hi = _kv_range(i, bq, bk, window)
-        return b_, h // group, jnp.minimum(lo + j, hi), 0
+def _gqa_specs(plan, group, dh, by_key=False):
+    """Block specs over the grid (B, Hkv, tile): the query block of the kv
+    head's ``group`` heads (of q, o, do, dq: (B, Hq, T, Dh) in blocks of
+    ``group`` heads), the kv head's key/value block, the group's per-row
+    vector, and the tile of a (B, T, T) mask that all heads share, each at
+    the blocks the step's tile names."""
+    qrow, krow = (1, 0) if by_key else (0, 1)
 
-    def q_index(b_, h, i, j):
-        return b_, h, i, 0
+    def q_index(b_, h, s, tiles):
+        return b_, h, tiles[qrow, s], 0
 
-    def mask_index(b_, h, i, j):
-        lo, hi = _kv_range(i, bq, bk, window)
-        return b_, i, jnp.minimum(lo + j, hi)
-
-    return (pl.BlockSpec((None, None, bq, dh), q_index),
-            pl.BlockSpec((None, None, bk, dh), kv_index),
-            pl.BlockSpec((None, None, bq, 1), q_index),
-            pl.BlockSpec((None, bq, bk), mask_index))
+    return (pl.BlockSpec((None, group, plan.bq, dh), q_index),
+            pl.BlockSpec((None, None, plan.bk, dh),
+                         lambda b_, h, s, tiles: (b_, h, tiles[krow, s], 0)),
+            pl.BlockSpec((None, group, plan.bq, 1), q_index),
+            pl.BlockSpec((None, plan.bq, plan.bk),
+                         lambda b_, h, s, tiles: (b_, tiles[qrow, s],
+                                                  tiles[krow, s])))
 
 
 def _gqa_fwd_call(q, k, v, window, block, interpret, mask=None):
     b, hq, t, dh = q.shape
-    group = hq // k.shape[1]
-    bq = bk = block or _pick_gqa_block(t)
-    scale = 1.0 / (dh ** 0.5)
+    hkv = k.shape[1]
+    group = hq // hkv
+    plan = gqa_plan(t, hq, hkv, dh, window, block, q.dtype.itemsize)
     masked = mask is not None
-    # a kernel without a mask is built from the arguments it always had
-    opts = {"masked": True} if masked else {}
 
-    qspec, kvspec, vec, mspec = _gqa_specs(bq, bk, dh, group, window)
+    qspec, kvspec, vec, mspec = _gqa_specs(plan, group, dh)
+    rows = (group, plan.bq)
     return _gqa_call(
-        functools.partial(_gqa_fwd_kernel, bq=bq, bk=bk, window=window,
-                          scale=scale, **opts),
-        (b, hq, t // bq, _band_blocks(t, window, bq, bk)),
+        functools.partial(_gqa_fwd_kernel, bq=plan.bq, bk=plan.bk,
+                          heads=plan.heads, window=window,
+                          scale=1.0 / (dh ** 0.5), masked=masked),
+        plan, _tile_pairs(t, plan.bq, plan.bk, window), b, hkv,
         [qspec, kvspec, kvspec] + [mspec] * masked, (qspec, vec),
         (jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct((b, hq, t, 1), jnp.float32)),
-        [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
-         pltpu.VMEM((bq, dh), jnp.float32)],
+        [pltpu.VMEM(rows + (1,), jnp.float32),
+         pltpu.VMEM(rows + (1,), jnp.float32),
+         pltpu.VMEM(rows + (dh,), jnp.float32)],
         (q, k, v) + (mask,) * masked, interpret)
 
 
@@ -469,8 +553,8 @@ def gqa_flash_attention(q, k, v, window=None, block=None, interpret=False):
     (B, Hkv, T, Dh) with Hq a multiple of Hkv; any float dtype, float32
     accumulation. ``window``: key j is seen from query i only if
     ``i - j < window`` (None: every earlier key). ``block``: the query and
-    key block size (None: the largest of 512..8 that divides T). Returns
-    (B, Hq, T, Dh) in q's dtype."""
+    key block size (None: ``gqa_plan``'s). Returns (B, Hq, T, Dh) in q's
+    dtype."""
     return _gqa_fwd_call(q, k, v, window, block, interpret)[0]
 
 
@@ -487,47 +571,35 @@ def _gqa_bwd(window, block, interpret, res, do, mask=None):
     b, hq, t, dh = q.shape
     hkv = k.shape[1]
     group = hq // hkv
-    bq = bk = block or _pick_gqa_block(t)
-    nq = t // bq
-    scale = 1.0 / (dh ** 0.5)
+    plan = gqa_plan(t, hq, hkv, dh, window, block, q.dtype.itemsize)
     masked = mask is not None
-    opts = {"masked": True} if masked else {}
+    opts = dict(bq=plan.bq, bk=plan.bk, heads=plan.heads, window=window,
+                scale=1.0 / (dh ** 0.5), masked=masked)
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
         axis=-1, keepdims=True)                      # (B, Hq, T, 1)
+    args = (q, k, v) + (mask,) * masked + (do, lse, delta)
 
-    qspec, kvspec, vec, mspec = _gqa_specs(bq, bk, dh, group, window)
+    def specs(by_key):
+        qspec, kvspec, vec, mspec = _gqa_specs(plan, group, dh, by_key)
+        return ([qspec, kvspec, kvspec] + [mspec] * masked
+                + [qspec, vec, vec], qspec, kvspec)
+
+    in_specs, qspec, _ = specs(by_key=False)
     dq = _gqa_call(
-        functools.partial(_gqa_dq_kernel, bq=bq, bk=bk, window=window,
-                          scale=scale, **opts),
-        (b, hq, nq, _band_blocks(t, window, bq, bk)),
-        [qspec, kvspec, kvspec] + [mspec] * masked + [qspec, vec, vec], qspec,
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [pltpu.VMEM((bq, dh), jnp.float32)],
-        (q, k, v) + (mask,) * masked + (do, lse, delta), interpret)
+        functools.partial(_gqa_dq_kernel, **opts),
+        plan, _tile_pairs(t, plan.bq, plan.bk, window), b, hkv,
+        in_specs, qspec, jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((group, plan.bq, dh), jnp.float32)], args, interpret)
 
-    def q_index(b_, h, jk, g, i):
-        lo, hi = _q_range(jk, bq, bk, window, nq)
-        return b_, h * group + g, jnp.minimum(lo + i, hi), 0
-
-    def mask_index(b_, h, jk, g, i):
-        lo, hi = _q_range(jk, bq, bk, window, nq)
-        return b_, jnp.minimum(lo + i, hi), jk
-
-    qspec2 = pl.BlockSpec((None, None, bq, dh), q_index)
-    vec2 = pl.BlockSpec((None, None, bq, 1), q_index)
-    kvspec2 = pl.BlockSpec((None, None, bk, dh),
-                           lambda b_, h, jk, g, i: (b_, h, jk, 0))
-    mspec2 = pl.BlockSpec((None, bq, bk), mask_index)
+    in_specs, _, kvspec = specs(by_key=True)
     dk, dv = _gqa_call(
-        functools.partial(_gqa_dkv_kernel, bq=bq, bk=bk, window=window,
-                          scale=scale, nq=nq, **opts),
-        (b, hkv, t // bk, group, _band_blocks(t, window, bk, bq)),
-        [qspec2, kvspec2, kvspec2] + [mspec2] * masked + [qspec2, vec2, vec2],
-        (kvspec2, kvspec2),
+        functools.partial(_gqa_dkv_kernel, **opts),
+        plan, _tile_pairs(t, plan.bq, plan.bk, window, by_key=True), b, hkv,
+        in_specs, (kvspec, kvspec),
         (jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        [pltpu.VMEM((bk, dh), jnp.float32), pltpu.VMEM((bk, dh), jnp.float32)],
-        (q, k, v) + (mask,) * masked + (do, lse, delta), interpret)
+        [pltpu.VMEM((plan.bk, dh), jnp.float32),
+         pltpu.VMEM((plan.bk, dh), jnp.float32)], args, interpret)
     return dq, dk, dv
 
 

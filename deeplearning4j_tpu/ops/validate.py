@@ -28,9 +28,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.ops import lstm_pallas
-from deeplearning4j_tpu.ops.flash_attention import (flash_attention,
+from deeplearning4j_tpu.ops.flash_attention import (_gqa_bwd, _gqa_fwd_call,
+                                                    flash_attention,
                                                     gqa_flash_attention,
-                                                    gqa_supported,
+                                                    gqa_plan, gqa_supported,
                                                     supported as fa_supported)
 
 
@@ -69,11 +70,11 @@ def _attn_reference(q, k, v, causal):
 
 # ------------------------------------------------------------------- timing
 
-def _time(fn, *args):
+def _time(fn, *args, **options):
     """Per-execution op time; see util/timing.py for why one dispatch of a
     short op cannot be timed by itself (launch cost exceeds the op)."""
     from deeplearning4j_tpu.util.timing import time_op
-    return time_op(fn, *args)
+    return time_op(fn, *args, **options)
 
 
 _MIN_MEASURABLE_S = 1e-7      # below the timer's resolution → time is noise
@@ -280,14 +281,67 @@ def validate_attention_case(bh, t, dh, causal, rtol=1e-2, atol=1e-3,
     return res
 
 
+def _masked_attention_by_rows(q, k, v, mask, rows, f32):
+    """(output, head-mean weights) of grouped-query attention over the pairs
+    ``mask`` (B, T, T) selects, by a masked softmax over ``rows`` query rows
+    at a time, one chunk's scores alive at a time (the whole (B, H, T, T)
+    score tensor does not fit the chip at the cells' shapes). ``f32``: the
+    operands cast to float32; else the weights rounded to v's dtype, as the
+    layer's plain path has them."""
+    b, hq, t, dh = q.shape
+    hkv = k.shape[1]
+    cast = (lambda a: a.astype(jnp.float32)) if f32 else (lambda a: a)
+
+    def chunk(start, q1, k1, v1, m1):
+        cut = lambda a, axis: lax.dynamic_slice_in_dim(a, start, rows, axis)
+        qc = cast(cut(q1, 1)).reshape(hkv, hq // hkv, rows, dh)
+        s = jnp.einsum("kgqd,ksd->kgqs", qc, cast(k1),
+                       preferred_element_type=jnp.float32) / math.sqrt(dh)
+        p = jax.nn.softmax(jnp.where(
+            (cut(m1, 0) != 0)[None, None], s, -jnp.inf), axis=-1)
+        o = jnp.einsum("kgqs,ksd->kgqd", p if f32 else p.astype(v1.dtype),
+                       cast(v1))
+        return o.reshape(hq, rows, dh), p.mean(axis=(0, 1))
+
+    def sequence(args):
+        return lax.map(jax.checkpoint(lambda s: chunk(s, *args)),
+                       jnp.arange(0, t, rows))
+
+    o, pm = lax.map(sequence, (q, k, v, mask))
+    return (o.transpose(0, 2, 1, 3, 4).reshape(b, hq, t, dh),
+            pm.reshape(b, t, t))
+
+
+def _gqa_pass_times(q, k, v, do, window, mask, interpret):
+    """The tile the grouped-query kernels take at these shapes and the
+    microseconds of each of their three passes: the forward call, and the
+    dq and the dk/dv call of the backward rule on the forward's residuals
+    (a pass whose outputs are dropped is dead code and does not run)."""
+    b, hq, t, dh = q.shape
+    m = () if mask is None else (mask,)
+    fwd = jax.jit(lambda q, k, v, *m: _gqa_fwd_call(
+        q, k, v, window, None, interpret, *m))
+    bwd = lambda q, k, v, o, lse, do, *m: _gqa_bwd(
+        window, None, interpret, (q, k, v, o, lse), do, *m)
+    o, lse = fwd(q, k, v, *m)
+    us = lambda fn, *a: round(_time(fn, *a, pilot_iters=8) * 1e6, 1)
+    return {"plan": gqa_plan(t, hq, k.shape[1], dh, window,
+                             itemsize=q.dtype.itemsize)._asdict(),
+            "fwd_us": us(fwd, q, k, v, *m),
+            "dq_us": us(jax.jit(lambda *a: bwd(*a)[0]),
+                        q, k, v, o, lse, do, *m),
+            "dkv_us": us(jax.jit(lambda *a: bwd(*a)[1:]),
+                         q, k, v, o, lse, do, *m)}
+
+
 def validate_gqa_attention_case(b, hq, hkv, t, dh, window,
                                 dtype="bfloat16", rtol=2e-2, atol=2e-2,
                                 time_it=True):
     """The grouped-query banded kernel against the layer's plain path (the
-    masked softmax over the whole score matrix, ``banded_attention``), outputs
-    and the three gradients, in the training path's dtype. bfloat16 both
-    sides: the tolerance is the rounding of p and of the outputs."""
-    from deeplearning4j_tpu.nn.layers.decoder import banded_attention
+    masked softmax of ``banded_attention``, 256 query rows at a time),
+    outputs and the three gradients, in the training path's dtype. bfloat16
+    both sides: the tolerance is the rounding of p and of the outputs. With
+    the times: the kernels' tile (``gqa_plan``) and each pass by itself."""
     assert gqa_supported(t, dh, hq, hkv), (t, dh, hq, hkv)
     dt = jnp.dtype(dtype)
     rs = np.random.RandomState(t + hq)
@@ -298,8 +352,15 @@ def validate_gqa_attention_case(b, hq, hkv, t, dh, window,
     def total(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * cot)
 
+    def ref(q, k, v):
+        i = jnp.arange(t)[:, None]
+        j = jnp.arange(t)[None, :]
+        ok = (i >= j) if window is None else (i >= j) & (i - j < window)
+        mask = jnp.broadcast_to(ok.astype(jnp.int8), (b, t, t))
+        return _masked_attention_by_rows(q, k, v, mask, min(256, t),
+                                         f32=False)[0]
+
     fa = lambda q, k, v: gqa_flash_attention(q, k, v, window)
-    ref = lambda q, k, v: banded_attention(q, k, v, window)
     fa_fwd, ref_fwd = jax.jit(fa), jax.jit(ref)
     fa_g = jax.jit(jax.grad(total(fa), argnums=(0, 1, 2)))
     ref_g = jax.jit(jax.grad(total(ref), argnums=(0, 1, 2)))
@@ -317,10 +378,13 @@ def validate_gqa_attention_case(b, hq, hkv, t, dh, window,
            "T": t, "Dh": dh, "window": window, "dtype": dtype,
            "max_err": round(max(errs.values()), 6)}
     if time_it:
-        tf, tr = _time(fa_fwd, q, k, v), _time(ref_fwd, q, k, v)
-        tgf, tgr = _time(fa_g, q, k, v), _time(ref_g, q, k, v)
-        res.update(fwd_us=round(tf * 1e6, 1), fwd_ref_us=round(tr * 1e6, 1),
-                   fwd_speedup=_speedup(tr, tf),
+        res.update(_gqa_pass_times(q, k, v, cot.astype(dt), window, None,
+                                   False))
+        tr = _time(ref_fwd, q, k, v, pilot_iters=8)
+        tgf = _time(fa_g, q, k, v, pilot_iters=8)
+        tgr = _time(ref_g, q, k, v, pilot_iters=8)
+        res.update(fwd_ref_us=round(tr * 1e6, 1),
+                   fwd_speedup=_speedup(tr, res["fwd_us"] * 1e-6),
                    grad_us=round(tgf * 1e6, 1), grad_ref_us=round(tgr * 1e6, 1),
                    grad_speedup=_speedup(tgr, tgf))
     return res
@@ -430,7 +494,6 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
     wi = jnp.asarray(rs.randn(b, t, j) / math.sqrt(j * di), jnp.float32)
     cot = jnp.asarray(rs.randn(b, hq, t, dh), jnp.float32)
     rows = min(256, t)
-    group = hq // hkv
 
     def chunks(fn, *full):
         """``fn(start, *full)`` over the chunks of query rows of every
@@ -444,23 +507,7 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
         return jax.lax.dynamic_slice_in_dim(a, start, rows, axis)
 
     def loop(q, k, v, mask, f32=True):
-        """(output, head-mean weights) by a masked softmax, chunk by
-        chunk."""
-        cast = (lambda a: a.astype(jnp.float32)) if f32 else (lambda a: a)
-
-        def rows_of(start, q1, k1, v1, m1):
-            qc = cast(cut(q1, start, 1)).reshape(hkv, group, rows, dh)
-            s = jnp.einsum("kgqd,ksd->kgqs", qc, cast(k1),
-                           preferred_element_type=jnp.float32) / math.sqrt(dh)
-            p = jax.nn.softmax(jnp.where(
-                (cut(m1, start, 0) != 0)[None, None], s, -jnp.inf), axis=-1)
-            o = jnp.einsum("kgqs,ksd->kgqd", p.astype(v1.dtype) if not f32
-                           else p, cast(v1))
-            return o.reshape(hq, rows, dh), p.mean(axis=(0, 1))
-
-        o, pm = chunks(rows_of, q, k, v, mask)
-        return (o.transpose(0, 2, 1, 3, 4).reshape(b, hq, t, dh),
-                pm.reshape(b, t, t))
+        return _masked_attention_by_rows(q, k, v, mask, rows, f32)
 
     def kl_rows(scores, qc, wc, k1, mc, pc):
         """A chunk's KL from ``pc`` to the softmax of ``scores(qc, wc, k1)``
@@ -584,8 +631,9 @@ def validate_selected_attention_case(b, hq, hkv, t, dh, j, di, top_k,
         xla = lambda q, k, v, m: loop(q, k, v, m, f32=False)[0]
         xla_fwd, xla_g = jax.jit(xla), grad_of(xla)
         us = lambda fn, *a: round(_time(fn, *a) * 1e6, 1)
+        res.update(_gqa_pass_times(q, k, v, cot.astype(dt), None, mask,
+                                   interp))
         res.update(select_us=us(select, qi, wi, ki),
-                   fwd_us=us(fa_fwd, q, k, v, mask),
                    grad_us=us(fa_g, q, k, v, mask, cot),
                    head_mean_us=us(probs, q, k, lse, mask),
                    index_loss_grad_us=us(kl, qi, wi, ki, mask, p_ref),
@@ -689,9 +737,13 @@ SELECTED_QUICK = SELECTED_SWEEP[1:]
 EXPERT_SWEEP = [(16384, 3072, 256, 10, 1024, 8), (256, 64, 16, 3, 32, 4)]
 EXPERT_QUICK = EXPERT_SWEEP[1:]
 
-# (B, Hq, Hkv, T, Dh, window): group sizes 6 and 9, the band and the triangle
+# (B, Hq, Hkv, T, Dh, window): group sizes 6 and 9, the band and the triangle;
+# then the attention layers of the benchmark's cells: Laguna's full and
+# sliding layers and Nemotron's one (Keye's is SELECTED_SWEEP's first)
 GQA_SWEEP = [(1, 12, 2, 2048, 128, None), (1, 18, 2, 2048, 128, 512),
-             (2, 6, 1, 1024, 64, 256), (1, 9, 1, 4096, 128, 512)]
+             (2, 6, 1, 1024, 64, 256), (1, 9, 1, 4096, 128, 512),
+             (2, 12, 2, 8192, 128, None), (2, 18, 2, 8192, 128, 512),
+             (1, 16, 1, 8192, 128, None)]
 GQA_QUICK = GQA_SWEEP[:2]
 
 LSTM_SWEEP = [

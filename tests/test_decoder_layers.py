@@ -2,6 +2,7 @@
 reference of the benchmark (perfbench/lib/reference_lm.py), at a small size
 on the CPU with seeded weights."""
 
+import importlib
 import os
 
 import numpy as np
@@ -13,10 +14,16 @@ from deeplearning4j_tpu import ops
 from deeplearning4j_tpu.data.dataset import DataSet
 from deeplearning4j_tpu.nn.layers import (RMSNorm, SwiGLU, RotaryGQAttention,
                                           ExpertLayer)
-from deeplearning4j_tpu.nn.layers.decoder import banded_attention
-from deeplearning4j_tpu.ops.flash_attention import gqa_flash_attention
+from deeplearning4j_tpu.nn.layers.decoder import (banded_attention,
+                                                  selected_attention)
+from deeplearning4j_tpu.ops.flash_attention import (gqa_flash_attention,
+                                                    gqa_selected_attention)
 from perfbench.lib import arch, reference_lm as ref
 from perfbench.jobs import fit_lm
+
+# ops/__init__ re-exports the flash_attention FUNCTION under the module's name
+flash_attention = importlib.import_module(
+    "deeplearning4j_tpu.ops.flash_attention")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 C, HD, KV = 32, 8, 2
@@ -98,20 +105,102 @@ def test_attention_against_reference(cfg, kind, heads, kernel):
             ops.set_helpers_enabled(prev[0], interpret=prev[1])
 
 
-@pytest.mark.parametrize("hq,hkv,window", [(12, 2, None), (12, 2, 24),
-                                           (9, 1, None), (9, 1, 24)])
-def test_gqa_kernel_against_masked_softmax(hq, hkv, window):
-    """The kernel interpreted, group sizes 6 and 9, the triangle and a band
-    that crosses block boundaries (blocks of 16, window 24)."""
+def _small_tiles(monkeypatch, group, narrow):
+    """Blocks of 16 at 64 positions, two heads a product so that the step
+    walks the group in a loop; ``narrow``: the tile rule left to itself with
+    room for ``group * 16`` rows a step, so a query block of 16 stands under
+    a key block of 64. Returns the ``block`` argument."""
+    monkeypatch.setattr(flash_attention, "_GQA_PRODUCT_ROWS", 32)
+    if not narrow:
+        return 16
+    row = 128 * (6 * 4 + 4) + 2 * 2 * 128 * 4       # float32, head dim 8
+    monkeypatch.setattr(flash_attention, "_GQA_STEP_BYTES", group * 16 * row)
+    plan = flash_attention.gqa_plan(64, group, 1, 8, None, itemsize=4)
+    assert (plan.bq, plan.bk, plan.rows) == (16, 64, group * 16)
+    return None
+
+
+@pytest.mark.parametrize("narrow", [False, True])
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (12, 2), (16, 2), (9, 1),
+                                    (16, 1)])
+def test_gqa_kernel_against_masked_softmax(monkeypatch, hq, hkv, window,
+                                           narrow):
+    """The kernel interpreted, group sizes 1, 6, 8, 9 and 16, the triangle
+    and a band that crosses block boundaries (blocks of 16, window 24), on
+    square tiles and with a query block smaller than the key block."""
+    block = _small_tiles(monkeypatch, hq // hkv, narrow)
     q = _rand((2, hq, 64, 8), 4)
     k, v = _rand((2, hkv, 64, 8), 5), _rand((2, hkv, 64, 8), 6)
-    kern = lambda q, k, v: gqa_flash_attention(q, k, v, window, 16, True)
+    kern = lambda q, k, v: gqa_flash_attention(q, k, v, window, block, True)
     plain = lambda q, k, v: banded_attention(q, k, v, window)
     _close(kern(q, k, v), plain(q, k, v), 1e-5)
     gk = jax.grad(lambda *a: (kern(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
     gp = jax.grad(lambda *a: (plain(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
     for a, b in zip(gk, gp):
         _close(a, b, 1e-4)
+
+
+@pytest.mark.parametrize("narrow", [False, True])
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (12, 2), (8, 1)])
+def test_gqa_selected_kernel_against_masked_softmax(monkeypatch, hq, hkv,
+                                                    narrow):
+    """The same kernels under an int8 mask, interpreted: value, log-sum-exp
+    and the three gradients against the plain path. Row 50 selects one key,
+    in its second tile of 16 (its first tile selects none); the whole tile
+    of rows 32-47 and keys 16-31 selects none."""
+    block = _small_tiles(monkeypatch, hq // hkv, narrow)
+    t = 64
+    rs = np.random.RandomState(7)
+    mask = np.tril(rs.rand(2, t, t) < 0.4)
+    mask[:, np.arange(t), 0] = True
+    mask[:, 32:48, 16:32] = False
+    mask[:, 50] = False
+    mask[:, 50, 20] = True
+    mask = jnp.asarray(mask, jnp.int8)
+    q = _rand((2, hq, t, 8), 4)
+    k, v = _rand((2, hkv, t, 8), 5), _rand((2, hkv, t, 8), 6)
+    kern = lambda q, k, v: gqa_selected_attention(q, k, v, mask, block, True)
+    plain = lambda q, k, v: selected_attention(q, k, v, mask)[0]
+    o, lse = kern(q, k, v)
+    _close(o, plain(q, k, v), 1e-5)
+    s = jnp.einsum("bhqd,bhsd->bhqs", q, jnp.repeat(k, hq // hkv, axis=1)) \
+        / np.sqrt(8)
+    _close(lse[..., 0], jax.nn.logsumexp(
+        jnp.where((mask != 0)[:, None], s, -jnp.inf), axis=-1), 1e-5)
+    gk = jax.grad(lambda *a: (kern(*a)[0] ** 2).sum(), (0, 1, 2))(q, k, v)
+    gp = jax.grad(lambda *a: (plain(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(gk, gp):
+        _close(a, b, 1e-4)
+
+
+# the attention layers of the benchmark's three decoder cells: (positions,
+# query heads, kv heads, window) -> (query block, key block, heads a
+# product, rows a step, grid steps a pass, steps a banded grid would add)
+@pytest.mark.parametrize("shape,plan", [
+    ((16384, 32, 4, None), (256, 512, 2, 2048, 4224, 3968)),    # Keye
+    ((8192, 12, 2, None), (256, 512, 2, 1536, 544, 480)),       # Laguna, full
+    ((8192, 18, 2, 512), (128, 512, 3, 1152, 248, 136)),        # ... sliding
+    ((8192, 16, 1, None), (128, 512, 4, 2048, 544, 480)),       # Nemotron
+])
+def test_gqa_plan_of_the_cells(shape, plan):
+    t, hq, hkv, window = shape
+    got = flash_attention.gqa_plan(t, hq, hkv, 128, window)
+    assert tuple(got) == plan
+    bq, bk = got.bq, got.bk
+    # every tile with a visible pair is a step, once, and no other tile is
+    i = np.arange(t // bq)[:, None] * bq
+    j = np.arange(t // bk)[None, :] * bk
+    seen = i + bq - 1 >= j
+    if window is not None:
+        seen &= i - (j + bk - 1) < window
+    for by_key in (False, True):
+        tiles = flash_attention._tile_pairs(t, bq, bk, window, by_key)
+        qb, kb = tiles[1 if by_key else 0], tiles[0 if by_key else 1]
+        assert len(set(zip(qb, kb))) == tiles.shape[1] == seen.sum()
+        assert seen[qb, kb].all()
+        assert (np.diff(tiles[0]) >= 0).all()
+        assert tiles[2].sum() == tiles[3].sum() == len(set(tiles[0]))
 
 
 def _expert_layer(held=None, e=16):

@@ -178,18 +178,21 @@ def test_flash_attention_fwd_and_grad(one_chip, bh, t, dh):
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
-# the benchmark's decoder cell: 2 sequences of 8192, 2 kv heads held, 12
-# query heads on full layers and 18 behind a window of 512 on sliding ones
-@pytest.mark.parametrize("hq,window", [(12, None), (18, 512)])
-def test_gqa_flash_attention_fwd_and_grad(one_chip, hq, window):
+# the benchmark's decoder cells: 2 sequences of 8192, 2 kv heads held, 12
+# query heads on full layers and 18 behind a window of 512 on sliding ones;
+# and the hybrid cell's one attention layer, 16 query heads on one kv head
+@pytest.mark.parametrize("b,hq,hkv,window", [(2, 12, 2, None),
+                                             (2, 18, 2, 512),
+                                             (1, 16, 1, None)])
+def test_gqa_flash_attention_fwd_and_grad(one_chip, b, hq, hkv, window):
     def loss(q, k, v):
         return flash_attention.gqa_flash_attention(
             q, k, v, window).astype(jnp.float32).sum()
 
-    shapes = _shapes(one_chip, ((2, hq, 8192, 128), jnp.bfloat16),
-                     ((2, 2, 8192, 128), jnp.bfloat16),
-                     ((2, 2, 8192, 128), jnp.bfloat16))
-    _agree(flash_attention.gqa_supported(8192, 128, hq, 2),
+    shapes = _shapes(one_chip, ((b, hq, 8192, 128), jnp.bfloat16),
+                     ((b, hkv, 8192, 128), jnp.bfloat16),
+                     ((b, hkv, 8192, 128), jnp.bfloat16))
+    _agree(flash_attention.gqa_supported(8192, 128, hq, hkv),
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
