@@ -95,18 +95,29 @@ def require(cond, msg: str) -> None:
 
 
 class CacheEvents:
-    """Counts the persistent compile cache's own events for this process."""
+    """What the persistent compile cache was asked and what it had, for
+    this process, off the compile ledger's counter
+    (monitor/compile_ledger.py: the one place that listens to JAX)."""
 
     def __init__(self):
-        import jax
-        self.requests = self.hits = 0
-        jax.monitoring.register_event_listener(self._on_event)
+        from deeplearning4j_tpu.monitor import compile_ledger
+        compile_ledger.install()
 
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
+    @staticmethod
+    def _count(*results):
+        from deeplearning4j_tpu.monitor import get_registry
+        family = get_registry().get("dl4jtpu_compile_requests_total")
+        return int(sum(child.value for (_, result), child
+                       in (family.children() if family else ())
+                       if result in results))
+
+    @property
+    def requests(self):
+        return self._count("hit", "miss")
+
+    @property
+    def hits(self):
+        return self._count("hit")
 
 
 def program_keys():

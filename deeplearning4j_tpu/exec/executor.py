@@ -365,7 +365,7 @@ class Executor:
 
     def register_program(self, caller, key, fn, args, compile_seconds=None,
                          scopes=False, remat_kept_bytes=None,
-                         index_scores_calls=None):
+                         index_scores_calls=None, build=None):
         """Record a program built by :meth:`jit` (single-device ``jax.jit``
         results and mesh wrappers both work); see
         ``programs.ProgramRegistry.record``."""
@@ -373,7 +373,8 @@ class Executor:
                                     compile_seconds=compile_seconds,
                                     scopes=scopes,
                                     remat_kept_bytes=remat_kept_bytes,
-                                    index_scores_calls=index_scores_calls)
+                                    index_scores_calls=index_scores_calls,
+                                    build=build)
 
 
 # ------------------------------------------------------- process default

@@ -27,6 +27,13 @@ What this buys:
   ``kernel``, the Pallas kernel of ops/index_scores.py, or ``xla``, the
   einsum), and the ``dl4jtpu_index_scores_calls`` gauge labelled
   ``{caller,key,form}``.
+- ``trace_seconds``, ``lower_seconds``, ``backend_seconds``,
+  ``cache_load_seconds`` and ``cache`` (``hit`` | ``miss`` | ``uncached``)
+  of a step program's record: what the call that built it spent tracing,
+  lowering and in the backend, how much of that loading from the
+  persistent cache, and whether it loaded or compiled (the compile ledger,
+  monitor/compile_ledger.py). The registration's own second pass is not in
+  them: it is ``aot_seconds``, and the ledger's ``register`` phase.
 - ``bench.py`` MFU rows read flops from here instead of re-deriving them
   with a private lowering helper.
 
@@ -48,6 +55,10 @@ __all__ = ["ProgramRegistry", "get_programs", "is_registering",
 
 
 _REGISTERING = threading.local()
+
+# how a step program came to be (``record``'s ``build``), where nobody says
+_NO_BUILD = dict.fromkeys(("trace_seconds", "lower_seconds", "backend_seconds",
+                           "cache_load_seconds", "cache"))
 
 
 def is_registering() -> bool:
@@ -254,7 +265,8 @@ class ProgramRegistry:
                compile_seconds: Optional[float] = None,
                scopes: bool = False,
                remat_kept_bytes: Optional[dict] = None,
-               index_scores_calls: Optional[dict] = None) -> Optional[dict]:
+               index_scores_calls: Optional[dict] = None,
+               build: Optional[dict] = None) -> Optional[dict]:
         """Register program ``(caller, key)``; re-registration of a known
         key is a no-op (returns the existing record). Analysis failures
         degrade to a record with None fields rather than raising into
@@ -263,7 +275,12 @@ class ProgramRegistry:
         has no reader for one). ``remat_kept_bytes``: what the caller
         counted while it traced the program (a graph's step under
         ``remat="blocks"``), kept as the record's field of that name;
-        ``index_scores_calls`` likewise (a step with an indexer)."""
+        ``index_scores_calls`` likewise (a step with an indexer).
+        ``build``: how the program came to be, for the call that built it
+        (``monitor/compile_ledger.since``: ``trace_seconds``,
+        ``lower_seconds``, ``backend_seconds``, ``cache_load_seconds`` and
+        ``cache``: ``hit`` | ``miss`` | ``uncached``); the containers' step
+        programs bring it, and a record without it keeps the five None."""
         caller, key = str(caller), str(key)
         with self._lock:
             existing = self._programs.get((caller, key))
@@ -275,8 +292,10 @@ class ProgramRegistry:
         fields = {"flops": None, "bytes": None, "memory_bytes": None,
                   "aot_seconds": None, "mosaic_calls": None,
                   "all_reduces": None, "op_scopes": None}
+        from deeplearning4j_tpu.monitor import compile_ledger
         try:
-            with _Registering():
+            # the second lowering and compile are the registry's own cost
+            with _Registering(), compile_ledger.phase("register"):
                 fields = _analyze(jitted, args, scopes)
         except Exception:
             pass
@@ -286,6 +305,7 @@ class ProgramRegistry:
             **fields,
             "compile_seconds": (compile_seconds if compile_seconds is not None
                                 else fields["aot_seconds"]),
+            **(build or _NO_BUILD),
             "remat_kept_bytes": (dict(remat_kept_bytes)
                                  if remat_kept_bytes is not None else None),
             "index_scores_calls": (dict(index_scores_calls)

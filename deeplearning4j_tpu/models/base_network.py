@@ -14,6 +14,8 @@ arrays: to ``jax.jit`` both are pytrees. So this module treats ``inputs``,
 index; ``ComputationGraph``: a DAG, trees that are dicts by node name)
 supplies what differs by nature:
 
+- ``_init_leaves(rng, dtype) -> (params, state)``: its layers' parameters
+  and state, leaf by leaf, in its own tree form;
 - ``_forward`` and ``_loss(params, state, inputs, labels, rng, masks,
   label_masks, carries=None) -> (loss, (new_state, new_carries))``, and
   ``_dp_loss``, which folds a pad mask into the label masks;
@@ -36,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from deeplearning4j_tpu.monitor import compile_ledger
 from deeplearning4j_tpu.monitor.tracing import trace
 from deeplearning4j_tpu.nn.layers.special import FrozenLayer
 from deeplearning4j_tpu.nn.updaters import make_gradient_transform
@@ -102,6 +105,26 @@ class BaseNetwork:
             from deeplearning4j_tpu.exec import get_executor
             self._exec = get_executor()
         return self._exec
+
+    def init(self, rng=None):
+        """Initialize parameters, state and the updater's state (parity:
+        MultiLayerNetwork.init :541, ComputationGraph.init :370). Leaf by
+        leaf, every ``jax.random`` and ``jnp`` call on a new shape a program
+        of its own: the compile ledger counts them under ``init``, the
+        wall seconds and the leaves made go to ``dl4jtpu_init_*``."""
+        t0 = time.perf_counter()
+        with compile_ledger.phase("init"), trace.span("init"):
+            gc = self.conf.global_conf
+            if rng is None:
+                rng = jax.random.PRNGKey(gc.seed)
+            self.params, self.state = self._init_leaves(
+                rng, _dtype_of(gc.dtype))
+            self._build_optimizer()
+        self._mon.record_init(
+            time.perf_counter() - t0,
+            len(jax.tree_util.tree_leaves(
+                (self.params, self.state, self.opt_state))))
+        return self
 
     def _build_optimizer(self):
         from deeplearning4j_tpu.nn.fused_update import (build_fused_update,
@@ -330,6 +353,10 @@ class BaseNetwork:
         no equivalent (its fit loop dispatches per minibatch,
         MultiLayerNetwork.java:1204); this is the XLA-idiomatic fast path
         with identical per-step math."""
+        with compile_ledger.phase("fit"):
+            return self._fit_scan_impl(xs, ys)
+
+    def _fit_scan_impl(self, xs, ys):
         if self.conf.backprop_type == "tbptt":
             raise ValueError(
                 "fit_scan runs full-sequence backprop; a net configured for "
@@ -381,6 +408,7 @@ class BaseNetwork:
                 out_specs=out_specs,
                 donate_argnums=(0, 1, 2))
         c0, t0 = self._compile_count, time.perf_counter()
+        m0 = compile_ledger.mark()
         out = self._scan_fit(
             self.params, self.state, self.opt_state, xs, ys,
             jnp.asarray(self.iteration, jnp.int32))
@@ -408,7 +436,8 @@ class BaseNetwork:
                  jnp.asarray(self.iteration, jnp.int32)),
                 compile_seconds=time.perf_counter() - t0, scopes=True,
                 remat_kept_bytes=self._remat_kept,
-                index_scores_calls=self._index_calls)
+                index_scores_calls=self._index_calls,
+                build=compile_ledger.since(m0))
         if self.listeners:
             with trace.span("callback"):
                 for lst in self.listeners:
@@ -453,7 +482,7 @@ class BaseNetwork:
 
         # DL4JTPU_PROFILE=<dir> wraps the whole call in jax.profiler.trace
         # (docs/OBSERVABILITY.md); unset, this is a plain passthrough
-        with profile_scope():
+        with profile_scope(), compile_ledger.phase("fit"):
             return self._fit_impl(data, labels, epochs, prefetch,
                                   checkpoint, resume_from)
 
@@ -716,6 +745,7 @@ class BaseNetwork:
                                       # Listener)
         first = jax.tree_util.tree_leaves(inputs)[0]
         c0, t0 = self._compile_count, time.perf_counter()
+        m0 = compile_ledger.mark()
         if self.conf.backprop_type == "tbptt" and first.ndim == 3:
             self._fit_tbptt(inputs, labels, masks, label_masks)
             self._last_fit_time = time.perf_counter() - t0
@@ -747,7 +777,8 @@ class BaseNetwork:
                      label_masks),
                     compile_seconds=self._last_fit_time, scopes=True,
                     remat_kept_bytes=self._remat_kept,
-                    index_scores_calls=self._index_calls)
+                    index_scores_calls=self._index_calls,
+                    build=compile_ledger.since(m0))
         self.iteration += 1
         self._epoch_batch += 1
         self._mon.record(seconds=self._last_fit_time, steps=1,

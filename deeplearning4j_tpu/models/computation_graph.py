@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.models.base_network import BaseNetwork, _dtype_of
+from deeplearning4j_tpu.monitor import compile_ledger
 from deeplearning4j_tpu.util.scopes import layer_scope
 from deeplearning4j_tpu.util.remat import (BLOCK_KEPT, block_checkpoint,
                                            counting_kept, remat_segments)
@@ -39,21 +40,16 @@ class ComputationGraph(BaseNetwork):
     _prog_prefix = "cg"
 
     # ------------------------------------------------------------------ init
-    def init(self, rng=None):
-        gc = self.conf.global_conf
-        dtype = _dtype_of(gc.dtype)
-        if rng is None:
-            rng = jax.random.PRNGKey(gc.seed)
-        self.params, self.state = {}, {}
+    def _init_leaves(self, rng, dtype):
+        params, state = {}, {}
         layer_nodes = [n for n in self.conf.topological_order
                        if self.conf.nodes[n].kind == "layer"]
         keys = jax.random.split(rng, max(len(layer_nodes), 1))
         for name, k in zip(layer_nodes, keys):
             l = self.conf.nodes[name].layer
-            self.params[name] = l.init(k, dtype)
-            self.state[name] = l.init_state(dtype)
-        self._build_optimizer()
-        return self
+            params[name] = l.init(k, dtype)
+            state[name] = l.init_state(dtype)
+        return params, state
 
     def _layer(self, key):
         return self.conf.nodes[key].layer
@@ -271,7 +267,8 @@ class ComputationGraph(BaseNetwork):
             self._output_fn = self._executor.jit(
                 fwd, in_specs=(ex.PARAMS, ex.STATE, ex.BATCH),
                 out_specs=(ex.BATCH,))
-        outs = self._output_fn(self.params, self.state, inputs)
+        with compile_ledger.phase("output"):
+            outs = self._output_fn(self.params, self.state, inputs)
         return outs[0] if len(outs) == 1 else outs
 
     def score(self, mds=None, inputs=None, labels=None):
@@ -327,8 +324,9 @@ class ComputationGraph(BaseNetwork):
         of the externalEpsilons contract). Updates params, updater state and
         layer state (e.g. batchnorm running stats) like fit(). The update
         runs through the standalone donated program, not an eager loop."""
-        grads, new_state = self.backprop_external(inputs, epsilons)
-        self.apply_external_updates(grads)
+        with compile_ledger.phase("fit"):
+            grads, new_state = self.backprop_external(inputs, epsilons)
+            self.apply_external_updates(grads)
         self.state = new_state
         self.iteration += 1
         return self

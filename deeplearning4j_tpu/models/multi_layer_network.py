@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.models.base_network import BaseNetwork, _dtype_of
+from deeplearning4j_tpu.monitor import compile_ledger
 from deeplearning4j_tpu.nn.conf.configuration import MultiLayerConfiguration
 from deeplearning4j_tpu.util.scopes import layer_scope
 from deeplearning4j_tpu.util.dtypes import (cast_floats as _cast_floats,
@@ -40,17 +41,10 @@ class MultiLayerNetwork(BaseNetwork):
         self.layers = conf.layers   # params, state, opt_state: lists by index
 
     # ------------------------------------------------------------------ init
-    def init(self, rng=None):
-        """Initialize parameters (parity: MultiLayerNetwork.init :541)."""
-        gc = self.conf.global_conf
-        dtype = _dtype_of(gc.dtype)
-        if rng is None:
-            rng = jax.random.PRNGKey(gc.seed)
+    def _init_leaves(self, rng, dtype):
         keys = jax.random.split(rng, max(len(self.layers), 1))
-        self.params = [l.init(k, dtype) for l, k in zip(self.layers, keys)]
-        self.state = [l.init_state(dtype) for l in self.layers]
-        self._build_optimizer()
-        return self
+        return ([l.init(k, dtype) for l, k in zip(self.layers, keys)],
+                [l.init_state(dtype) for l in self.layers])
 
     def _layer(self, key):
         return self.layers[key]
@@ -238,8 +232,10 @@ class MultiLayerNetwork(BaseNetwork):
             self._output_fn = self._executor.jit(
                 fwd, in_specs=(ex.PARAMS, ex.STATE, ex.BATCH, ex.BATCH),
                 out_specs=(ex.BATCH,))
-        return self._output_fn(self.params, self.state, x,
-                               None if mask is None else jnp.asarray(mask))
+        with compile_ledger.phase("output"):
+            return self._output_fn(
+                self.params, self.state, x,
+                None if mask is None else jnp.asarray(mask))
 
     def feed_forward(self, x, train=False):
         """All layer activations (parity: feedForward :852)."""

@@ -70,6 +70,22 @@ class TrainMonitor:
         self._hist = {"batch": hist.labels(model=model_kind, path="batch"),
                       "scan": hist.labels(model=model_kind, path="scan")}
 
+    def record_init(self, seconds: float, leaves: int) -> None:
+        """One ``init()``: its wall seconds (the leaves' programs traced,
+        compiled or loaded, and run) and the arrays it made: parameters,
+        state and the updater's state."""
+        reg, lab = get_registry(), {"model": self._kind}
+        reg.counter(
+            "dl4jtpu_init_seconds_total",
+            "Wall seconds inside the containers' init().",
+            ("model",)).labels(**lab).inc(seconds)
+        reg.counter(
+            "dl4jtpu_init_leaves_total",
+            "Arrays init() made: parameters, state, the updater's state. "
+            "Beside dl4jtpu_compile_requests_total{phase=\"init\"} it says "
+            "how many programs a leaf costs.",
+            ("model",)).labels(**lab).inc(leaves)
+
     def record(self, *, seconds: float, steps: int, examples: int,
                score, compiled: int, path: str) -> None:
         """One train call: ``steps`` steps over ``examples`` rows took

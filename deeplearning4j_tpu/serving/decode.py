@@ -45,7 +45,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.monitor import get_registry, trace
+from deeplearning4j_tpu.monitor import compile_ledger, get_registry, trace
 from deeplearning4j_tpu.monitor.reqlog import RequestLog, new_record
 from deeplearning4j_tpu.monitor.tracing import get_context
 from deeplearning4j_tpu.resilience.errors import (
@@ -713,9 +713,15 @@ class DecodeEngine:
         self._ensure_dstate()
         if self._thread is None or not self._thread.is_alive():
             self._stop.clear()
-            self._thread = threading.Thread(target=self._loop, daemon=True)
+            self._thread = threading.Thread(target=self._serve, daemon=True)
             self._thread.start()
         return self
+
+    def _serve(self):
+        # a program this thread builds (a request before any warm-up, a new
+        # shape) is the compile ledger's ``serve``
+        with compile_ledger.phase("serve"):
+            self._loop()
 
     def stop(self) -> None:
         self._stop.set()
@@ -815,6 +821,10 @@ class DecodeEngine:
         return self.warmup_seconds
 
     def _warmup_run(self):
+        with compile_ledger.phase("serve"):
+            return self._warmup_programs()
+
+    def _warmup_programs(self):
         S = self.slots
         z = np.zeros(S, np.int32)
         f = np.zeros(S, bool)

@@ -35,7 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.monitor import get_registry, trace
+from deeplearning4j_tpu.monitor import compile_ledger, get_registry, trace
 from deeplearning4j_tpu.quant import (dequantize_tree, record_weight_bytes,
                                       resolve_precision, tree_bytes)
 from deeplearning4j_tpu.resilience.errors import WeightSwapError
@@ -455,7 +455,7 @@ class InferenceEngine:
             t = time.perf_counter()
             phases["pad"] = phases.get("pad", 0.0) + (t - tp)
             tp = t
-        with trace.span("device", bucket=b):
+        with trace.span("device", bucket=b), compile_ledger.phase("serve"):
             params, state = self._weights()
             prog = self._aot.get((b, mask_p is not None))
             if prog is not None:
