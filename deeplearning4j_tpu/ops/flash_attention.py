@@ -639,56 +639,138 @@ def _gqa_sel_bwd(block, interpret, res, ct):
 gqa_selected_attention.defvjp(_gqa_sel_fwd, _gqa_sel_bwd)
 
 
-def _head_mean_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, acc_s, *, scale,
-                      heads):
-    i, j, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+# The head-mean weights the indexer's loss reads: the same scores again,
+# ``exp(score - lse)`` summed over the query heads tile by tile. A grid step
+# holds every query head (or as many kv heads' groups as fit) on one tile
+# that holds a visible pair, so q and the log-sum-exp are fetched once a
+# query block; the mask is decoded once a tile; the tiles above the
+# diagonal are a step each that writes zeros and fetches nothing new.
 
-    @pl.when(h == 0)
-    def _():
-        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+class HeadMeanPlan(NamedTuple):
+    """How ``gqa_head_mean_probs`` tiles one sequence
+    (``head_mean_plan``)."""
+    bq: int         # query rows of a tile
+    bk: int         # keys of a tile
+    heads: int      # query heads stacked as the rows of one product
+    kv_heads: int   # kv heads whose query groups a step holds
+    steps: int      # grid steps: visible tiles x kv blocks, + zero tiles
 
-    @pl.when(j <= i)
-    def _():
-        s = lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        acc_s[...] += jnp.where(mask_ref[...].astype(jnp.int32) != 0,
-                                jnp.exp(s - lse_ref[...]), 0.0)
 
-    @pl.when(h == heads - 1)
+def _head_mean_steps(t, bq, bk, kv_blocks):
+    """The grid as int32 rows (query block, key block, kv block, output key
+    block, first, last, zero): each tile that holds a visible pair,
+    query-major, once for every one of ``kv_blocks`` blocks of kv heads
+    (first and last mark its first and last step), and after a query
+    block's last such tile each of its tiles above the diagonal once, as a
+    ``zero`` step that names the blocks of the step before it (nothing is
+    fetched) and writes zeros."""
+    rows = []
+    for i, j, _, last in _tile_pairs(t, bq, bk, None).T:
+        rows += [(i, j, h, j, h == 0, h == kv_blocks - 1, 0)
+                 for h in range(kv_blocks)]
+        if last:
+            rows += [(i, j, kv_blocks - 1, z, 0, 0, 1)
+                     for z in range(j + 1, t // bk)]
+    return np.asarray(rows, np.int32).T
+
+
+def head_mean_plan(t, n_heads, n_kv_heads, dh, block=None, itemsize=2):
+    """The tile of ``gqa_head_mean_probs`` at ``t`` positions, from the
+    shapes alone. The key block is ``gqa_plan``'s; a step holds the query
+    groups of the most kv heads (a divisor of ``n_kv_heads``) whose blocks
+    fit ``_GQA_STEP_BYTES`` at a query block of 8 or more: q and the
+    log-sum-exp column (which pads to 128 lanes) of every head held and the
+    kv heads' key block, each double-buffered; the query block is the
+    largest of the key block's halvings that fits. Heads are stacked to
+    ``_GQA_PRODUCT_ROWS`` rows a product as ``gqa_plan`` stacks them.
+    ``block`` sets both blocks and holds every kv head."""
+    group = n_heads // n_kv_heads
+    bk = block or _pick_gqa_block(t)
+    lanes = -(-dh // 128) * 128
+
+    def fits(kvs, bq):
+        return 2 * kvs * (group * bq * (lanes * itemsize + 128 * 4)
+                          + bk * lanes * itemsize) <= _GQA_STEP_BYTES
+
+    for kvs in (d for d in range(n_kv_heads, 0, -1) if n_kv_heads % d == 0):
+        bq = bk
+        while not block and bq > 8 and not fits(kvs, bq):
+            bq //= 2
+        if block or fits(kvs, bq):
+            break
+    heads = max(h for h in range(1, group + 1)
+                if group % h == 0 and (h == 1 or h * bq <= _GQA_PRODUCT_ROWS))
+    tiles = _tile_pairs(t, bq, bk, None).shape[1]
+    return HeadMeanPlan(bq, bk, heads, kvs, tiles * (n_kv_heads // kvs)
+                        + (t // bq) * (t // bk) - tiles)
+
+
+def _head_mean_kernel(steps_ref, q_ref, k_ref, lse_ref, mask_ref, p_ref, *,
+                      heads, group, scale, inv_hq):
+    step = pl.program_id(1)
+
+    @pl.when(steps_ref[4, step] == 1)
     def _():
-        p_ref[...] = acc_s[...] * (1.0 / heads)
+        p_ref[...] = jnp.zeros(p_ref.shape, jnp.float32)
+
+    # the sub-groups unrolled: a product overlaps the last one's ``exp``
+    # (a rolled loop ran 24 % slower on the chip, PERF.md §6, PR 39)
+    @pl.when(steps_ref[6, step] == 0)
+    def _():
+        for c in range(q_ref.shape[0] // heads):
+            rows = pl.ds(c * heads, heads)
+            s = lax.dot_general(_stacked(q_ref, rows),
+                                k_ref[c * heads // group],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            p_ref[...] += jnp.exp(s.reshape(heads, *p_ref.shape)
+                                  - lse_ref[rows]).sum(axis=0)
+
+    # the mask is decoded and applied once a tile, to the heads' sum: a
+    # hidden pair reads exactly 0 whatever its score (``exp`` may overflow
+    # there to inf, never to NaN)
+    @pl.when(steps_ref[5, step] == 1)
+    def _():
+        p_ref[...] = jnp.where(mask_ref[...].astype(jnp.int32) != 0,
+                               p_ref[...] * inv_hq, 0.0)
+
+    @pl.when(steps_ref[6, step] == 1)
+    def _():
+        p_ref[...] = jnp.zeros(p_ref.shape, jnp.float32)
 
 
 def gqa_head_mean_probs(q, k, lse, mask, block=None, interpret=False):
     """The mean over the query heads of the attention weights of
-    ``gqa_selected_attention``: (B, T, T) float32, zero where ``mask`` is.
-    q: (B, Hq, T, Dh), k: (B, Hkv, T, Dh), lse: (B, Hq, T, 1) as that call
-    returned it. One (query block, key block) tile is summed over the heads
-    in VMEM, so no (Hq, T, T) tensor exists. Takes no gradient."""
+    ``gqa_selected_attention``: (B, T, T) float32, zero where ``mask`` is,
+    above the diagonal included. q: (B, Hq, T, Dh), k: (B, Hkv, T, Dh),
+    lse: (B, Hq, T, 1) as that call returned it. Tiled by
+    ``head_mean_plan``: a grid step sums ``exp(score - lse)`` over the
+    query heads it holds on one tile that holds a visible pair, in VMEM,
+    so no (Hq, T, T) tensor exists. Takes no gradient."""
     b, hq, t, dh = q.shape
-    group = hq // k.shape[1]
-    blk = block or _pick_gqa_block(t)
-    low = lambda j, i: jnp.minimum(j, i)       # above the diagonal: no fetch
+    hkv = k.shape[1]
+    plan = head_mean_plan(t, hq, hkv, dh, block, q.dtype.itemsize)
+    n = plan.kv_heads * (hq // hkv)            # query heads a step holds
+    steps = _head_mean_steps(t, plan.bq, plan.bk, hkv // plan.kv_heads)
+    qmap = lambda b_, s, st: (b_, st[2, s], st[0, s], 0)
     return pl.pallas_call(
-        functools.partial(_head_mean_kernel, scale=1.0 / (dh ** 0.5),
-                          heads=hq),
+        functools.partial(_head_mean_kernel, heads=plan.heads,
+                          group=hq // hkv, scale=1.0 / (dh ** 0.5),
+                          inv_hq=1.0 / hq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=(b, t // blk, t // blk, hq),
+            num_scalar_prefetch=1, grid=(b, steps.shape[1]),
             in_specs=[
-                pl.BlockSpec((None, None, blk, dh),
-                             lambda b_, i, j, h: (b_, h, i, 0)),
-                pl.BlockSpec((None, None, blk, dh),
-                             lambda b_, i, j, h: (b_, h // group, low(j, i),
-                                                  0)),
-                pl.BlockSpec((None, None, blk, 1),
-                             lambda b_, i, j, h: (b_, h, i, 0)),
-                pl.BlockSpec((None, blk, blk),
-                             lambda b_, i, j, h: (b_, i, low(j, i)))],
-            out_specs=pl.BlockSpec((None, blk, blk),
-                                   lambda b_, i, j, h: (b_, i, j)),
-            scratch_shapes=[pltpu.VMEM((blk, blk), jnp.float32)]),
+                pl.BlockSpec((None, n, plan.bq, dh), qmap),
+                pl.BlockSpec((None, plan.kv_heads, plan.bk, dh),
+                             lambda b_, s, st: (b_, st[2, s], st[1, s], 0)),
+                pl.BlockSpec((None, n, plan.bq, 1), qmap),
+                pl.BlockSpec((None, plan.bq, plan.bk),
+                             lambda b_, s, st: (b_, st[0, s], st[1, s]))],
+            out_specs=pl.BlockSpec((None, plan.bq, plan.bk),
+                                   lambda b_, s, st: (b_, st[0, s],
+                                                      st[3, s]))),
         out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, lse, mask)
+    )(jnp.asarray(steps), q, k, lse, mask)

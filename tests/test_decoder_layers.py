@@ -174,6 +174,62 @@ def test_gqa_selected_kernel_against_masked_softmax(monkeypatch, hq, hkv,
         _close(a, b, 1e-4)
 
 
+def _head_mean_tiles(monkeypatch, hq, hkv, narrow):
+    """The head-mean pass at 64 positions, float32, head dim 8, two heads a
+    product: square tiles of 16 holding every kv head a step, or
+    (``narrow``) a step budget that leaves one kv head's group a step on
+    (16, 64) tiles. Returns the ``block`` argument."""
+    monkeypatch.setattr(flash_attention, "_GQA_PRODUCT_ROWS", 32)
+    group = hq // hkv
+    if narrow:
+        monkeypatch.setattr(flash_attention, "_GQA_STEP_BYTES",
+                            2 * (group * 16 * (128 * 4 + 128 * 4)
+                                 + 64 * 128 * 4))
+    block = None if narrow else 16
+    plan = flash_attention.head_mean_plan(64, hq, hkv, 8, block, 4)
+    assert plan[:4] == ((16, 64, min(group, 2), 1) if narrow
+                        else (16, 16, min(group, 2), hkv))
+    return block
+
+
+@pytest.mark.parametrize("narrow", [False, True])
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2), (8, 1), (16, 2)])
+def test_gqa_head_mean_against_the_plain_head_mean(monkeypatch, hq, hkv,
+                                                   narrow):
+    """``gqa_head_mean_probs`` interpreted against the plain path's
+    head-mean weights over the whole (B, T, T) array, zeros above the
+    diagonal included: groups of 1, 2 and 8 query heads on a kv head,
+    every kv head a step on square tiles and one a step on tiles wider
+    than tall. Row 50 selects one key; the tile of rows 32-47 and keys
+    16-31 selects none; key 10 of row 40 is not selected and its score
+    sits over 100 above the row's log-sum-exp in every head, where
+    ``exp`` overflows: it reads exactly 0."""
+    block = _head_mean_tiles(monkeypatch, hq, hkv, narrow)
+    t = 64
+    rs = np.random.RandomState(8)
+    mask = np.tril(rs.rand(2, t, t) < 0.4)
+    mask[:, np.arange(t), 0] = True
+    mask[:, 32:48, 16:32] = False
+    mask[:, 50] = False
+    mask[:, 50, 20] = True
+    mask[:, 40, 10] = False
+    mask = jnp.asarray(mask, jnp.int8)
+    q = _rand((2, hq, t, 8), 4).at[:, :, 40].set(1.0)
+    k = _rand((2, hkv, t, 8), 5).at[:, :, 10].set(40.0)
+    _, lse = gqa_selected_attention(q, k, k, mask, block, True)
+    score = jnp.einsum("bhd,bhd->bh", q[:, :, 40],
+                       jnp.repeat(k, hq // hkv, axis=1)[:, :, 10])
+    assert float((score / np.sqrt(8) - lse[:, :, 40, 0]).min()) > 100
+    got = flash_attention.gqa_head_mean_probs(q, k, lse, mask, block, True)
+    want = selected_attention(q, k, k, mask)[1]
+    assert np.isfinite(np.asarray(got)).all()
+    assert not np.asarray(got)[:, np.triu_indices(t, 1)[0],
+                               np.triu_indices(t, 1)[1]].any()
+    assert float(got[:, 40, 10].max()) == 0.0
+    assert float(got[:, 32:48, 16:32].max()) == 0.0
+    _close(got, want, 1e-6)
+
+
 # the attention layers of the benchmark's three decoder cells: (positions,
 # query heads, kv heads, window) -> (query block, key block, heads a
 # product, rows a step, grid steps a pass, steps a banded grid would add)
@@ -201,6 +257,45 @@ def test_gqa_plan_of_the_cells(shape, plan):
         assert seen[qb, kb].all()
         assert (np.diff(tiles[0]) >= 0).all()
         assert tiles[2].sum() == tiles[3].sum() == len(set(tiles[0]))
+
+
+# the head-mean pass at the shapes of the decoder cells' attention (only
+# Keye's layers have an indexer and run it): (positions, query heads, kv
+# heads) -> (query block, key block, heads a product, kv heads a step, grid
+# steps); the parent's grid was (T/512)^2 x Hq steps, 32,768 at Keye's
+@pytest.mark.parametrize("shape,plan", [
+    ((16384, 32, 4), (128, 512, 4, 4, 4096)),       # Keye
+    ((8192, 12, 2), (256, 512, 2, 2, 512)),         # Laguna's full layers
+    ((8192, 16, 1), (256, 512, 2, 1, 512)),         # Nemotron
+])
+def test_head_mean_plan_of_the_cells(shape, plan):
+    t, hq, hkv = shape
+    got = flash_attention.head_mean_plan(t, hq, hkv, 128)
+    assert tuple(got) == plan
+    bq, bk, kv_blocks = got.bq, got.bk, hkv // got.kv_heads
+    steps = flash_attention._head_mean_steps(t, bq, bk, kv_blocks)
+    assert steps.shape[1] == got.steps
+    qb, kb, h, ob, first, last, zero = steps
+    i = np.arange(t // bq)[:, None] * bq
+    j = np.arange(t // bk)[None, :] * bk
+    seen = i + bq - 1 >= j
+    # the steps that compute are the tiles with a visible pair, once for
+    # each block of kv heads, and none other
+    work = zero == 0
+    assert seen[qb[work], kb[work]].all() and (kb[work] == ob[work]).all()
+    assert len(set(zip(qb[work], kb[work], h[work]))) == work.sum() \
+        == seen.sum() * kv_blocks
+    assert first.sum() == last.sum() == seen.sum()
+    # every other step writes one tile above them, which it names, once,
+    # and names the blocks of the step before it, so nothing is fetched
+    assert not seen[qb[~work], ob[~work]].any()
+    assert len(set(zip(qb[~work], ob[~work]))) == (~work).sum() \
+        == (~seen).sum()
+    idle = np.flatnonzero(~work)
+    assert (steps[:3, idle] == steps[:3, idle - 1]).all()
+    # query-major, so q and the log-sum-exp are fetched once a query block
+    # where a step holds every kv head
+    assert (np.diff(qb) >= 0).all()
 
 
 def _expert_layer(held=None, e=16):

@@ -19,6 +19,8 @@ from deeplearning4j_tpu.nn.layers import (DenseLayer, ExpertLayer,
 from deeplearning4j_tpu.nn.layers import decoder
 from deeplearning4j_tpu.nn.updaters import Sgd
 from deeplearning4j_tpu.ops import index_scores as index_kernel
+from deeplearning4j_tpu.ops.flash_attention import (gqa_head_mean_probs,
+                                                    gqa_selected_attention)
 from perfbench.lib import arch, reference_lm, reference_sparse_lm as ref
 from perfbench.jobs import fit_lm, fit_sparse_lm as job
 
@@ -246,6 +248,36 @@ def test_the_indexers_loss_over_chunks_past_the_first_in_both_forms():
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7),
         got[True], got[False])
+
+
+@pytest.mark.parametrize("hq,hkv", [(4, 1), (8, 2)])
+def test_the_indexers_loss_reads_the_kernels_head_mean_as_the_plain_one(
+        hq, hkv):
+    """``index_loss`` and its gradients by qi, wi and ki with ``p`` from
+    ``gqa_head_mean_probs`` (interpreted, the kv heads' groups a step on
+    tiles of 16) equal them with ``p`` from the plain path within float32
+    rounding, at 128 positions in chunks of 32 rows: the chunks read ``p``
+    past the diagonal inside their row group, where the kernel's zero
+    tiles are."""
+    rs = np.random.default_rng(12)
+    t, di, dh = 128, 8, 8
+    qi, wi, ki = (jnp.asarray(rs.normal(size=s).astype(np.float32))
+                  for s in ((1, t, J, di), (1, t, J), (1, t, di)))
+    q = jnp.asarray(rs.normal(size=(1, hq, t, dh)).astype(np.float32))
+    k = jnp.asarray(rs.normal(size=(1, hkv, t, dh)).astype(np.float32))
+    with jax.default_matmul_precision("highest"):
+        mask = decoder.selected_keys_mask(qi, wi, ki, 2 * TOP, rows=32)
+        lse = gqa_selected_attention(q, k, k, mask, 16, True)[1]
+        ps = {"kernel": gqa_head_mean_probs(q, k, lse, mask, 16, True),
+              "plain": decoder.selected_attention(q, k, k, mask)[1]}
+        got = {n: jax.value_and_grad(
+            lambda *a: decoder.index_loss(*a, mask, p, 32),
+            argnums=(0, 1, 2))(qi, wi, ki) for n, p in ps.items()}
+    np.testing.assert_allclose(ps["kernel"], ps["plain"], rtol=1e-6,
+                               atol=1e-7)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-8),
+        got["kernel"], got["plain"])
 
 
 # --------------------------------------------------- the layer-loss door

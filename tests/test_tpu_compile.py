@@ -214,12 +214,19 @@ def test_gqa_selected_attention_fwd_and_grad(one_chip):
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
-def test_gqa_head_mean_probs(one_chip):
-    q, kv = SELECTED
-    shapes = _shapes(one_chip, (q, jnp.bfloat16), (kv, jnp.bfloat16),
-                     ((1, 32, 16384, 1), jnp.float32),
-                     ((1, 16384, 16384), jnp.int8))
-    _agree(flash_attention.gqa_supported(16384, 128, 32, 4),
+# Keye's layer, every kv head a step (HeadMeanPlan 128, 512, 4, 4); and 64
+# heads of 256 on as many kv heads, where the step's blocks hold 8 of them
+# (256, 512, 1, 8): the plan's budget holds on the compiler with no VMEM
+# limit asked
+@pytest.mark.parametrize("b,hq,hkv,t,dh", [(1, 32, 4, 16384, 128),
+                                           (1, 64, 64, 4096, 256)])
+def test_gqa_head_mean_probs(one_chip, b, hq, hkv, t, dh):
+    shapes = _shapes(one_chip, ((b, hq, t, dh), jnp.bfloat16),
+                     ((b, hkv, t, dh), jnp.bfloat16),
+                     ((b, hq, t, 1), jnp.float32), ((b, t, t), jnp.int8))
+    assert flash_attention.head_mean_plan(t, hq, hkv, dh).kv_heads \
+        == min(hkv, 8)
+    _agree(flash_attention.gqa_supported(t, dh, hq, hkv),
            flash_attention.gqa_head_mean_probs, shapes)
 
 
