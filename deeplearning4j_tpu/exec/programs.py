@@ -27,6 +27,10 @@ What this buys:
   ``kernel``, the Pallas kernel of ops/index_scores.py, or ``xla``, the
   einsum), and the ``dl4jtpu_index_scores_calls`` gauge labelled
   ``{caller,key,form}``.
+- ``hyper_connection_calls``, likewise the hyper-connection passes (the
+  maps, a mix-in, a mix-out: nn/layers/latent.py:hyper_kernels) by form,
+  ``kernel`` (ops/hyper_connection.py) or ``xla``, and the
+  ``dl4jtpu_hyper_connection_calls`` gauge.
 - ``trace_seconds``, ``lower_seconds``, ``backend_seconds``,
   ``cache_load_seconds`` and ``cache`` (``hit`` | ``miss`` | ``uncached``)
   of a step program's record: what the call that built it spent tracing,
@@ -215,7 +219,7 @@ class ProgramRegistry:
         self._programs = {}        # (caller, key) -> record dict
         self._gauges = None
         self._kept = None          # the remat_kept_bytes gauge family
-        self._index_calls = None   # the index_scores_calls gauge family
+        self._calls = None         # the *_calls gauge families, by field
 
     def _metric(self, record):
         if self._gauges is None:
@@ -246,11 +250,18 @@ class ProgramRegistry:
                 "bytes a step program's blocks keep for the backward pass "
                 "beside their inputs under remat='blocks', by name",
                 labelnames=("caller", "key", "name"))
-            self._index_calls = reg.gauge(
-                "dl4jtpu_index_scores_calls",
-                "index-score calls a step program traced, by the form each "
-                "took (kernel: Pallas, tile by tile in VMEM; xla: einsum)",
-                labelnames=("caller", "key", "form"))
+            self._calls = {
+                "index_scores_calls": reg.gauge(
+                    "dl4jtpu_index_scores_calls",
+                    "index-score calls a step program traced, by the form "
+                    "each took (kernel: Pallas, tile by tile in VMEM; xla: "
+                    "einsum)", labelnames=("caller", "key", "form")),
+                "hyper_connection_calls": reg.gauge(
+                    "dl4jtpu_hyper_connection_calls",
+                    "hyper-connection passes a step program traced, by the "
+                    "form each took (kernel: Pallas, the state read once a "
+                    "pass; xla: XLA's float32 form)",
+                    labelnames=("caller", "key", "form"))}
         lbl = {"caller": record["caller"], "key": record["key"]}
         for field, fam in self._gauges.items():
             v = record.get(field)
@@ -258,14 +269,16 @@ class ProgramRegistry:
                 fam.labels(**lbl).set(v)
         for name, v in (record.get("remat_kept_bytes") or {}).items():
             self._kept.labels(name=name, **lbl).set(v)
-        for form, v in (record.get("index_scores_calls") or {}).items():
-            self._index_calls.labels(form=form, **lbl).set(v)
+        for field, fam in self._calls.items():
+            for form, v in (record.get(field) or {}).items():
+                fam.labels(form=form, **lbl).set(v)
 
     def record(self, caller: str, key: str, fn, args,
                compile_seconds: Optional[float] = None,
                scopes: bool = False,
                remat_kept_bytes: Optional[dict] = None,
                index_scores_calls: Optional[dict] = None,
+               hyper_connection_calls: Optional[dict] = None,
                build: Optional[dict] = None) -> Optional[dict]:
         """Register program ``(caller, key)``; re-registration of a known
         key is a no-op (returns the existing record). Analysis failures
@@ -275,7 +288,8 @@ class ProgramRegistry:
         has no reader for one). ``remat_kept_bytes``: what the caller
         counted while it traced the program (a graph's step under
         ``remat="blocks"``), kept as the record's field of that name;
-        ``index_scores_calls`` likewise (a step with an indexer).
+        ``index_scores_calls`` likewise (a step with an indexer), and
+        ``hyper_connection_calls`` (a step with hyper-connections).
         ``build``: how the program came to be, for the call that built it
         (``monitor/compile_ledger.since``: ``trace_seconds``,
         ``lower_seconds``, ``backend_seconds``, ``cache_load_seconds`` and
@@ -308,10 +322,10 @@ class ProgramRegistry:
             **(build or _NO_BUILD),
             "remat_kept_bytes": (dict(remat_kept_bytes)
                                  if remat_kept_bytes is not None else None),
-            "index_scores_calls": (dict(index_scores_calls)
-                                   if index_scores_calls
-                                   and any(index_scores_calls.values())
-                                   else None),
+            **{field: dict(calls) if calls and any(calls.values()) else None
+               for field, calls in (
+                   ("index_scores_calls", index_scores_calls),
+                   ("hyper_connection_calls", hyper_connection_calls))},
         }
         with self._lock:
             # lost a race: keep the first registration

@@ -27,6 +27,7 @@ supplies what differs by nature:
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
@@ -90,6 +91,7 @@ class BaseNetwork:
         self._compile_count = 0       # train programs traced (see _note_compile)
         self._remat_kept = None       # remat='blocks': bytes kept, by name
         self._index_calls = None      # index-score calls traced, by form
+        self._hyper_calls = None      # hyper-connection passes, by form
         self._flight = None           # FlightRecorder (monitor/flight.py)
         self._train_mon = None        # lazy TrainMonitor (metric children)
         self._exec = None             # execution core (lazy; exec/executor.py)
@@ -282,13 +284,17 @@ class BaseNetwork:
         return self._train_mon
 
     # ----------------------------------------------------------- train step
-    def _counting_index_calls(self):
+    @contextlib.contextmanager
+    def _counting_kernel_calls(self):
         """Open around the trace of a step program's loss and gradient: the
-        index-score calls it traces are counted by form, for the program's
-        registry record."""
-        from deeplearning4j_tpu.ops.index_scores import FORMS, counting_calls
-        self._index_calls = dict.fromkeys(FORMS, 0)
-        return counting_calls(self._index_calls)
+        index-score calls and the hyper-connection passes it traces are
+        counted by form, for the program's registry record."""
+        from deeplearning4j_tpu.ops import hyper_connection, index_scores
+        self._index_calls = dict.fromkeys(index_scores.FORMS, 0)
+        self._hyper_calls = dict.fromkeys(hyper_connection.FORMS, 0)
+        with index_scores.counting_calls(self._index_calls), \
+                hyper_connection.counting_calls(self._hyper_calls):
+            yield
 
     def _loss_for_grad(self):
         """The differentiated loss: jax.checkpoint-wrapped when remat is
@@ -312,7 +318,7 @@ class BaseNetwork:
             self._note_compile()
             rng = jax.random.fold_in(
                 jax.random.PRNGKey(self.conf.global_conf.seed), it)
-            with self._counting_index_calls():
+            with self._counting_kernel_calls():
                 (loss, (new_state, new_carries)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, state, inputs, labels, rng,
                                            masks, label_masks, carries)
@@ -375,7 +381,7 @@ class BaseNetwork:
                     x, y = inp
                     rng = jax.random.fold_in(
                         jax.random.PRNGKey(self.conf.global_conf.seed), it)
-                    with self._counting_index_calls():
+                    with self._counting_kernel_calls():
                         (loss, (new_state, _)), grads = jax.value_and_grad(
                             loss_fn, has_aux=True)(params, state, x, y, rng,
                                                    None, None)
@@ -437,6 +443,7 @@ class BaseNetwork:
                 compile_seconds=time.perf_counter() - t0, scopes=True,
                 remat_kept_bytes=self._remat_kept,
                 index_scores_calls=self._index_calls,
+                hyper_connection_calls=self._hyper_calls,
                 build=compile_ledger.since(m0))
         if self.listeners:
             with trace.span("callback"):
@@ -779,6 +786,7 @@ class BaseNetwork:
                     compile_seconds=self._last_fit_time, scopes=True,
                     remat_kept_bytes=self._remat_kept,
                     index_scores_calls=self._index_calls,
+                    hyper_connection_calls=self._hyper_calls,
                     build=compile_ledger.since(m0))
         self.iteration += 1
         self._epoch_batch += 1

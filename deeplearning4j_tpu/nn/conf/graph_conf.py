@@ -192,6 +192,22 @@ def _hyper_maps(maps, n):
     return _maps_split(maps, n)
 
 
+def _hyper_kernel(x, n):
+    """(ops/hyper_connection.py, the interpreter flag) where
+    ``latent.hyper_kernels`` routes this pass over ``x`` to its kernels,
+    else None (XLA's form)."""
+    from deeplearning4j_tpu import ops
+    from deeplearning4j_tpu.nn.layers.latent import hyper_kernels
+    from deeplearning4j_tpu.ops import hyper_connection
+    if hyper_kernels(x, n):
+        return hyper_connection, ops.interpret_mode()
+    return None
+
+
+def _rows(a):
+    return a.reshape(-1, a.shape[-1])
+
+
 @register_vertex
 @dataclass
 class StreamExpand(GraphVertex):
@@ -239,6 +255,11 @@ class HyperIn(GraphVertex):
         n = self.streams
         with jax.named_scope("hc"), jax.named_scope("hc_mix"):
             pre = _hyper_maps(maps, n)[0]
+            kernel = _hyper_kernel(x, n)
+            if kernel:
+                hc, interpret = kernel
+                return hc.mix_in(_rows(x), _rows(pre), interpret).reshape(
+                    *x.shape[:-1], -1)
             s = x.reshape(*x.shape[:-1], n, -1).astype(jnp.float32)
             return (pre[..., None] * s).sum(axis=-2).astype(x.dtype)
 
@@ -259,6 +280,12 @@ class HyperOut(GraphVertex):
         n = self.streams
         with jax.named_scope("hc"), jax.named_scope("hc_mix"):
             _, post, res = _hyper_maps(maps, n)
+            kernel = _hyper_kernel(x, n)
+            if kernel:
+                hc, interpret = kernel
+                return hc.mix_out(
+                    _rows(x), _rows(f), res.reshape(-1, n * n), _rows(post),
+                    interpret).reshape(x.shape)
             s = x.reshape(*x.shape[:-1], n, -1).astype(jnp.float32)
             out = jnp.einsum("...ij,...jc->...ic", res, s) \
                 + post[..., None] * f.astype(jnp.float32)[..., None, :]

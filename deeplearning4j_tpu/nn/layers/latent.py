@@ -178,6 +178,20 @@ def stochastic_gap(h):
                        jnp.abs(h.sum(axis=-2) - 1).max())
 
 
+def hyper_kernels(x, streams, fits=True):
+    """Whether a hyper-connection's pass over the residual state ``x`` (...,
+    T, n*C) takes the kernels of ops/hyper_connection.py: the decoder's
+    rule for kernels at T positions (``_kernels_on``), a stream width the
+    kernels' screen accepts and the caller's own condition ``fits``; else
+    XLA's form. The pass is counted by the form it takes
+    (``hyper_connection.counting_calls``)."""
+    from deeplearning4j_tpu.ops import hyper_connection as kernel
+    on = fits and _kernels_on(x.shape[-2]) and kernel.supported(
+        x.shape[-1] // streams, streams)
+    kernel.note_call("kernel" if on else "xla")
+    return on
+
+
 def _maps_split(maps, n):
     """(H_pre (..., n), H_post (..., n), H_res (..., n, n)) of the (...,
     2n + n^2) float32 maps ``HyperConnection`` makes."""
@@ -226,16 +240,29 @@ class HyperConnection(Layer):
         return {"sinkhorn_gap": jnp.zeros((), jnp.float32),
                 "res_diag_mean": jnp.zeros((), jnp.float32)}
 
+    def _project(self, phi, x):
+        """RMSNorm(vec X) phi, (..., 2n + n^2) float32: one pass of the
+        kernel where ``hyper_kernels`` routes it (the norm applied after
+        the product), else XLA's form (the normalised state rounded to
+        X's dtype before the product)."""
+        if hyper_kernels(x, self.streams, phi.dtype == x.dtype):
+            from deeplearning4j_tpu import ops
+            from deeplearning4j_tpu.ops.hyper_connection import (
+                maps_projection)
+            return maps_projection(
+                x.reshape(-1, x.shape[-1]), phi, self.norm_eps, self.streams,
+                ops.interpret_mode()).reshape(*x.shape[:-1], -1)
+        x32 = x.astype(jnp.float32)
+        xt = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.norm_eps)
+        return jnp.dot(xt.astype(x.dtype), phi,
+                       preferred_element_type=jnp.float32)
+
     def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
         n = self.streams
         with jax.named_scope("hc"):
             with jax.named_scope("hc_maps"):
-                x32 = x.astype(jnp.float32)
-                xt = x32 * jax.lax.rsqrt(
-                    jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                    + self.norm_eps)
-                z = jnp.dot(xt.astype(x.dtype), params["phi"],
-                            preferred_element_type=jnp.float32)
+                z = self._project(params["phi"], x)
                 a = params["alpha"].astype(jnp.float32)
                 z = z * jnp.repeat(a, jnp.asarray([n, n, n * n]),
                                    total_repeat_length=self.width) \

@@ -21,7 +21,9 @@ from deeplearning4j-cuda/src/test (SURVEY.md §4).
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from typing import Optional
 
 _FORCED: Optional[bool] = None      # set_helpers_enabled override
@@ -60,6 +62,31 @@ def interpret_mode() -> bool:
     return _INTERPRET
 
 
+class CallForms:
+    """The calls of one op that a trace makes, by the form each took
+    (one of ``FORMS``): ``note`` one, into the dict that ``counting``
+    holds open on this thread, if any. A step program's registry record
+    keeps the count (``models/base_network.py``)."""
+    FORMS = ("kernel", "xla")
+
+    def __init__(self):
+        self._local = threading.local()
+
+    def note(self, form):
+        into = getattr(self._local, "into", None)
+        if into is not None:
+            into[form] = into.get(form, 0) + 1
+
+    @contextlib.contextmanager
+    def counting(self, into):
+        prev = getattr(self._local, "into", None)
+        self._local.into = into
+        try:
+            yield into
+        finally:
+            self._local.into = prev
+
+
 from deeplearning4j_tpu.ops.lstm_pallas import (fused_lstm_sequence,  # noqa: E402
                                                 fused_lstm2_sequence)
 from deeplearning4j_tpu.ops.flash_attention import flash_attention  # noqa: E402
@@ -67,7 +94,7 @@ from deeplearning4j_tpu.ops.flash_decode import (flash_decode_step,  # noqa: E40
                                                  flash_decode_step_paged)
 
 __all__ = [
-    "helpers_enabled", "set_helpers_enabled", "interpret_mode",
+    "helpers_enabled", "set_helpers_enabled", "interpret_mode", "CallForms",
     "fused_lstm_sequence", "fused_lstm2_sequence", "flash_attention",
     "flash_decode_step", "flash_decode_step_paged",
 ]

@@ -22,15 +22,15 @@ dtype for the two gradient products, as the flash kernels round theirs.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops import CallForms
 
 # the VMEM the kernels ask Mosaic for (its default scope on v5e is 16 MiB,
 # which the benchmark's chunk of 512 rows of 16 heads fits; XLA sets HBM
@@ -188,25 +188,7 @@ index_scores.defvjp(_fwd, _bwd)
 
 
 # ------------------------------------------------- which form a step traced
-FORMS = ("kernel", "xla")
-_counting = threading.local()
-
-
-def note_call(form):
-    """One call of the index scores in ``form`` (one of ``FORMS``), into
-    the count ``counting_calls`` holds open on this thread, if any."""
-    into = getattr(_counting, "into", None)
-    if into is not None:
-        into[form] = into.get(form, 0) + 1
-
-
-@contextlib.contextmanager
-def counting_calls(into):
-    """While open on this thread, the index-score calls that are traced are
-    counted into the dict ``into``, by form."""
-    prev = getattr(_counting, "into", None)
-    _counting.into = into
-    try:
-        yield into
-    finally:
-        _counting.into = prev
+# ``note_call(form)`` counts one index-score call into the dict that
+# ``counting_calls(into)`` holds open on this thread
+_calls = CallForms()
+FORMS, note_call, counting_calls = _calls.FORMS, _calls.note, _calls.counting

@@ -20,6 +20,7 @@ import argparse
 import functools
 import json
 import math
+import time
 
 import numpy as np
 
@@ -720,6 +721,141 @@ def validate_ssd_scan_case(b, t, h, p, g, n, chunk, dtype="bfloat16", tol=3e-2,
     return res
 
 
+def _hyper_passes(streams, c, norm_eps=1e-6):
+    """{pass: (the layer's form of it, a float32 loop over the streams,
+    its arguments' names)}: the maps' projection, the mix-in and the
+    mix-out of one hyper-connection over (B, T, streams*c); the small
+    argument first."""
+    from deeplearning4j_tpu.nn.conf.graph_conf import HyperIn, HyperOut
+    from deeplearning4j_tpu.nn.layers.latent import HyperConnection
+    n = streams
+    layer = HyperConnection(n_in=n * c, streams=n, norm_eps=norm_eps)
+    f32 = lambda v: v.astype(jnp.float32)
+    part = lambda v, i: f32(v[..., i * c:(i + 1) * c])
+
+    def maps_loop(phi, x):
+        ss = sum(jnp.sum(part(x, i) ** 2, -1, keepdims=True)
+                 for i in range(n))
+        xt = [part(x, i) * lax.rsqrt(ss / (n * c) + norm_eps)
+              for i in range(n)]
+        return sum(jnp.dot(xt[i], f32(phi[i * c:(i + 1) * c]),
+                           precision="highest") for i in range(n))
+
+    def mix_in_loop(maps, x):
+        return sum(maps[..., i:i + 1] * part(x, i) for i in range(n))
+
+    def mix_out_loop(maps, x, f):
+        return jnp.concatenate([
+            maps[..., n + i:n + i + 1] * f32(f) + sum(
+                maps[..., 2 * n + i * n + j:2 * n + i * n + j + 1]
+                * part(x, j) for j in range(n)) for i in range(n)], -1)
+
+    return {"maps": (lambda phi, x: layer._project(phi, x), maps_loop,
+                     ("phi", "x")),
+            "mix_in": (lambda m, x: HyperIn(streams=n).apply([m, x]),
+                       mix_in_loop, ("maps", "x")),
+            "mix_out": (lambda m, x, f: HyperOut(streams=n).apply([f, m, x]),
+                        mix_out_loop, ("maps", "x", "f"))}
+
+
+def _per_call_s(compiled, args, calls=20):
+    """Seconds a call of ``compiled`` over ``calls`` back-to-back calls
+    (dispatched ahead, so the device runs them one after another), each
+    output materialised whole. Not ``_time``: its chained loop adds a pass
+    over the first argument, here a 117 MB state, and reads one element of
+    the first output, which lets XLA drop the rest of its form's work."""
+    jax.block_until_ready(compiled(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = compiled(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls
+
+
+def validate_hyper_connection_case(t, streams, c, dtype="bfloat16", tol=2e-2,
+                                   time_it=True):
+    """The three passes of a hyper-connection over one sequence of ``t``
+    tokens, ``streams`` x ``c`` (ops/hyper_connection.py's kernels, where
+    the layers' rule routes them at this shape) against a float32 loop over
+    the streams on the same inputs: the output and every cotangent (a
+    random cotangent of the output) by relative norm gap, for the kernels
+    and for XLA's form; and the time of each form's forward pass and of its
+    gradient (the forward residuals it needs and the backward pass), with
+    the bytes the pass has to move at the least over that time. Maps as
+    ``HyperConnection`` makes them (H_pre in (0, 1), H_post in (0, 2), H_res
+    with rows near 1)."""
+    from deeplearning4j_tpu import ops
+    from deeplearning4j_tpu.nn.layers.latent import hyper_kernels
+    dt_, n = jnp.dtype(dtype), streams
+    rs = np.random.RandomState(t + c)
+    width = 2 * n + n * n
+    u = rs.rand(1, t, width)
+    u[..., n:2 * n] *= 2
+    u[..., 2 * n:] /= n / 2
+    args = {"x": jnp.asarray(rs.randn(1, t, n * c), dt_),
+            "f": jnp.asarray(rs.randn(1, t, c), dt_),
+            "maps": jnp.asarray(u, jnp.float32),
+            "phi": jnp.asarray(rs.randn(n * c, width) / np.sqrt(n * c), dt_)}
+    b = dt_.itemsize
+    state, half = t * n * c * b, t * c * b
+    # the least each form has to move: the forward pass; its gradient (the
+    # maps' gradient reads X once more for its forward residuals)
+    moved = {"maps": (state, 2 * state + state),
+             "mix_in": (state + half, 2 * state + half),
+             "mix_out": (2 * state + half, 3 * state + 2 * half)}
+    res = {"kernel": "hyper_connection", "T": t, "streams": n, "C": c,
+           "dtype": dtype, "gaps": {}}
+    prev = ops.set_helpers_enabled(True, interpret=ops.interpret_mode())
+    try:
+        res["routed"] = hyper_kernels(args["x"], n)
+        for name, (fn, loop, names) in _hyper_passes(n, c).items():
+            a = tuple(args[k] for k in names)
+            cot = jnp.asarray(rs.randn(*jax.eval_shape(fn, *a).shape),
+                              jnp.float32)
+            loss = lambda g: (lambda *v: jnp.sum(g(*v).astype(jnp.float32)
+                                                 * cot))
+            grad = lambda g: jax.grad(loss(g), tuple(range(len(a))))
+            want = jax.jit(loop)(*(v.astype(jnp.float32) for v in a))
+            want_g = jax.jit(grad(loop))(*(v.astype(jnp.float32)
+                                           for v in a))
+            for form, on in (("kernel", True), ("xla", False)):
+                ops.set_helpers_enabled(on, interpret=ops.interpret_mode())
+                # a fresh function each form: jit's trace cache does not
+                # know the helpers' switch
+                fwd = jax.jit(lambda *v: fn(*v)).lower(*a).compile()
+                bwd = jax.jit(grad(fn)).lower(*a).compile()
+                gaps = {"out": _norm_gap(fwd(*a), want)}
+                for k, u_, v_ in zip(names, bwd(*a), want_g):
+                    gaps[f"d{k}"] = _norm_gap(u_, v_)
+                res["gaps"][f"{name}_{form}"] = {
+                    k: float(f"{v:.3g}") for k, v in gaps.items()}
+                if form == "kernel":
+                    for k, v in gaps.items():
+                        assert v <= tol, f"hyper {name} T={t} C={c}: {k} {v}"
+                if time_it:
+                    for phase, comp, nbytes in (("fwd", fwd, moved[name][0]),
+                                                ("grad", bwd, moved[name][1])):
+                        sec = _per_call_s(comp, a)
+                        res[f"{name}_{phase}_{form}_us"] = round(sec * 1e6, 1)
+                        res[f"{name}_{phase}_{form}_GBps"] = round(
+                            nbytes / sec / 1e9, 1)
+    finally:
+        ops.set_helpers_enabled(prev[0], interpret=prev[1])
+    res["max_err"] = max(v for k, g in res["gaps"].items()
+                         if k.endswith("kernel") for v in g.values())
+    return res
+
+
+def _norm_gap(u, v):
+    u, v = jnp.asarray(u, jnp.float32), jnp.asarray(v, jnp.float32)
+    return float(jnp.linalg.norm(u - v) / (jnp.linalg.norm(v) + 1e-30))
+
+
+# (T, streams, C): one sublayer of the benchmark's latent-attention decoder
+# (one sequence of 4096 tokens, 4 streams of 3584), and a small case
+HYPER_SWEEP = [(4096, 4, 3584), (1100, 4, 256)]
+HYPER_QUICK = HYPER_SWEEP[1:]
+
 # (B, T, H, P, G, N, chunk): one chip's share of a Mamba-2 mixer of the
 # benchmark's hybrid decoder at its step's 8192 positions, and a small case
 SSD_SWEEP = [(1, 8192, 16, 64, 1, 128, 128), (2, 256, 4, 16, 2, 16, 32)]
@@ -843,6 +979,15 @@ def run(quick=False, time_it=True):
             print(json.dumps(r))
         except Exception as e:  # noqa: BLE001
             failures.append({"kernel": "ssd_scan", "case": case,
+                             "error": f"{type(e).__name__}: {e}"[:300]})
+            print(json.dumps(failures[-1]))
+    for case in (HYPER_QUICK if quick else HYPER_SWEEP):
+        try:
+            r = validate_hyper_connection_case(*case, time_it=time_it)
+            results.append(r)
+            print(json.dumps(r))
+        except Exception as e:  # noqa: BLE001
+            failures.append({"kernel": "hyper_connection", "case": case,
                              "error": f"{type(e).__name__}: {e}"[:300]})
             print(json.dumps(failures[-1]))
     summary = {"backend": jax.default_backend(),

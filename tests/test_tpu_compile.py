@@ -36,6 +36,8 @@ flash_attention = importlib.import_module(
     "deeplearning4j_tpu.ops.flash_attention")
 flash_decode = importlib.import_module("deeplearning4j_tpu.ops.flash_decode")
 index_scores = importlib.import_module("deeplearning4j_tpu.ops.index_scores")
+hyper_connection = importlib.import_module(
+    "deeplearning4j_tpu.ops.hyper_connection")
 
 
 @pytest.fixture(scope="module")
@@ -264,6 +266,35 @@ def test_index_scores_fwd_and_grad(one_chip, r, s, dtype):
     _agree(index_scores.supported(r, 16, 64, s,
                                   jnp.dtype(dtype).itemsize),
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=2)
+
+
+# the latent-attention cell's residual state: one sequence of 4096 tokens, 4
+# streams of 3584 in bfloat16 (the maps float32), each pass forward and with
+# its gradient
+HC_ROWS, HC_C = 4096, 3584
+HC_STATE = ((HC_ROWS, 4 * HC_C), jnp.bfloat16)
+HC_PASSES = {
+    "maps": (lambda x, phi: hyper_connection.maps_projection(
+        x, phi, 1e-6, 4), (HC_STATE, ((4 * HC_C, 24), jnp.bfloat16)), 2),
+    "mix_in": (lambda x, pre: hyper_connection.mix_in(x, pre).astype(
+        jnp.float32), (HC_STATE, ((HC_ROWS, 4), jnp.float32)), 1),
+    "mix_out": (lambda x, f, res, post: hyper_connection.mix_out(
+        x, f, res, post).astype(jnp.float32),
+        (HC_STATE, ((HC_ROWS, HC_C), jnp.bfloat16),
+         ((HC_ROWS, 16), jnp.float32), ((HC_ROWS, 4), jnp.float32)), 2),
+}
+
+
+@pytest.mark.parametrize("which", sorted(HC_PASSES))
+def test_hyper_connection_pass_fwd_and_grad(one_chip, which):
+    fn, specs, kernels = HC_PASSES[which]
+
+    def loss(*a):
+        return fn(*a).sum()
+
+    _agree(hyper_connection.supported(HC_C, 4),
+           jax.value_and_grad(loss, argnums=tuple(range(len(specs)))),
+           _shapes(one_chip, *specs), kernels=kernels)
 
 
 def test_ragged_dot_is_one_grouped_product_on_the_chip(one_chip):
