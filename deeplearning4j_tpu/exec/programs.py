@@ -31,6 +31,11 @@ What this buys:
   maps, a mix-in, a mix-out: nn/layers/latent.py:hyper_kernels) by form,
   ``kernel`` (ops/hyper_connection.py) or ``xla``, and the
   ``dl4jtpu_hyper_connection_calls`` gauge.
+- ``latent_attention_calls``, likewise the latent-attention calls
+  (nn/layers/latent.py:LatentAttention) by the form their attention took,
+  ``kernel`` (the grouped-query flash kernels) or ``xla``
+  (``banded_attention``), and the ``dl4jtpu_latent_attention_calls``
+  gauge. The three are ``CALL_FIELDS``.
 - ``trace_seconds``, ``lower_seconds``, ``backend_seconds``,
   ``cache_load_seconds`` and ``cache`` (``hit`` | ``miss`` | ``uncached``)
   of a step program's record: what the call that built it spent tracing,
@@ -59,6 +64,25 @@ __all__ = ["ProgramRegistry", "get_programs", "is_registering",
 
 
 _REGISTERING = threading.local()
+
+# what a step program's record counts of the calls of an op that routes
+# between a kernel and XLA's form, by the form each took: the record's
+# field -> (its gauge, the gauge's help)
+CALL_FIELDS = {
+    "index_scores_calls": (
+        "dl4jtpu_index_scores_calls",
+        "index-score calls a step program traced, by the form each took "
+        "(kernel: Pallas, tile by tile in VMEM; xla: einsum)"),
+    "hyper_connection_calls": (
+        "dl4jtpu_hyper_connection_calls",
+        "hyper-connection passes a step program traced, by the form each "
+        "took (kernel: Pallas, the state read once a pass; xla: XLA's "
+        "float32 form)"),
+    "latent_attention_calls": (
+        "dl4jtpu_latent_attention_calls",
+        "latent-attention calls a step program traced, by the form their "
+        "attention took (kernel: the grouped-query flash kernels; xla: "
+        "banded_attention)")}
 
 # how a step program came to be (``record``'s ``build``), where nobody says
 _NO_BUILD = dict.fromkeys(("trace_seconds", "lower_seconds", "backend_seconds",
@@ -251,17 +275,9 @@ class ProgramRegistry:
                 "beside their inputs under remat='blocks', by name",
                 labelnames=("caller", "key", "name"))
             self._calls = {
-                "index_scores_calls": reg.gauge(
-                    "dl4jtpu_index_scores_calls",
-                    "index-score calls a step program traced, by the form "
-                    "each took (kernel: Pallas, tile by tile in VMEM; xla: "
-                    "einsum)", labelnames=("caller", "key", "form")),
-                "hyper_connection_calls": reg.gauge(
-                    "dl4jtpu_hyper_connection_calls",
-                    "hyper-connection passes a step program traced, by the "
-                    "form each took (kernel: Pallas, the state read once a "
-                    "pass; xla: XLA's float32 form)",
-                    labelnames=("caller", "key", "form"))}
+                field: reg.gauge(name, help_,
+                                 labelnames=("caller", "key", "form"))
+                for field, (name, help_) in CALL_FIELDS.items()}
         lbl = {"caller": record["caller"], "key": record["key"]}
         for field, fam in self._gauges.items():
             v = record.get(field)
@@ -277,8 +293,7 @@ class ProgramRegistry:
                compile_seconds: Optional[float] = None,
                scopes: bool = False,
                remat_kept_bytes: Optional[dict] = None,
-               index_scores_calls: Optional[dict] = None,
-               hyper_connection_calls: Optional[dict] = None,
+               kernel_calls: Optional[dict] = None,
                build: Optional[dict] = None) -> Optional[dict]:
         """Register program ``(caller, key)``; re-registration of a known
         key is a no-op (returns the existing record). Analysis failures
@@ -288,8 +303,8 @@ class ProgramRegistry:
         has no reader for one). ``remat_kept_bytes``: what the caller
         counted while it traced the program (a graph's step under
         ``remat="blocks"``), kept as the record's field of that name;
-        ``index_scores_calls`` likewise (a step with an indexer), and
-        ``hyper_connection_calls`` (a step with hyper-connections).
+        ``kernel_calls`` likewise, {field of ``CALL_FIELDS``: {form:
+        count}}: a field no call of which was traced reads None.
         ``build``: how the program came to be, for the call that built it
         (``monitor/compile_ledger.since``: ``trace_seconds``,
         ``lower_seconds``, ``backend_seconds``, ``cache_load_seconds`` and
@@ -313,6 +328,7 @@ class ProgramRegistry:
                 fields = _analyze(jitted, args, scopes)
         except Exception:
             pass
+        calls = kernel_calls or {}
         record = {
             "caller": caller,
             "key": key,
@@ -322,10 +338,9 @@ class ProgramRegistry:
             **(build or _NO_BUILD),
             "remat_kept_bytes": (dict(remat_kept_bytes)
                                  if remat_kept_bytes is not None else None),
-            **{field: dict(calls) if calls and any(calls.values()) else None
-               for field, calls in (
-                   ("index_scores_calls", index_scores_calls),
-                   ("hyper_connection_calls", hyper_connection_calls))},
+            **{field: dict(calls[field])
+               if any((calls.get(field) or {}).values()) else None
+               for field in CALL_FIELDS},
         }
         with self._lock:
             # lost a race: keep the first registration

@@ -90,8 +90,7 @@ class BaseNetwork:
         self._update_step = None      # standalone donated update program
         self._compile_count = 0       # train programs traced (see _note_compile)
         self._remat_kept = None       # remat='blocks': bytes kept, by name
-        self._index_calls = None      # index-score calls traced, by form
-        self._hyper_calls = None      # hyper-connection passes, by form
+        self._kernel_calls = None     # calls of routed ops traced, by form
         self._flight = None           # FlightRecorder (monitor/flight.py)
         self._train_mon = None        # lazy TrainMonitor (metric children)
         self._exec = None             # execution core (lazy; exec/executor.py)
@@ -287,13 +286,19 @@ class BaseNetwork:
     @contextlib.contextmanager
     def _counting_kernel_calls(self):
         """Open around the trace of a step program's loss and gradient: the
-        index-score calls and the hyper-connection passes it traces are
-        counted by form, for the program's registry record."""
+        index-score calls, the hyper-connection passes and the
+        latent-attention calls it traces are counted by form, for the
+        program's registry record (a field each, ``programs.CALL_FIELDS``)."""
+        from deeplearning4j_tpu.nn.layers import latent
         from deeplearning4j_tpu.ops import hyper_connection, index_scores
-        self._index_calls = dict.fromkeys(index_scores.FORMS, 0)
-        self._hyper_calls = dict.fromkeys(hyper_connection.FORMS, 0)
-        with index_scores.counting_calls(self._index_calls), \
-                hyper_connection.counting_calls(self._hyper_calls):
+        counted = {"index_scores_calls": index_scores,
+                   "hyper_connection_calls": hyper_connection,
+                   "latent_attention_calls": latent}
+        self._kernel_calls = {f: dict.fromkeys(m.FORMS, 0)
+                              for f, m in counted.items()}
+        with contextlib.ExitStack() as stack:
+            for f, m in counted.items():
+                stack.enter_context(m.counting_calls(self._kernel_calls[f]))
             yield
 
     def _loss_for_grad(self):
@@ -442,8 +447,7 @@ class BaseNetwork:
                  jnp.asarray(self.iteration, jnp.int32)),
                 compile_seconds=time.perf_counter() - t0, scopes=True,
                 remat_kept_bytes=self._remat_kept,
-                index_scores_calls=self._index_calls,
-                hyper_connection_calls=self._hyper_calls,
+                kernel_calls=self._kernel_calls,
                 build=compile_ledger.since(m0))
         if self.listeners:
             with trace.span("callback"):
@@ -785,8 +789,7 @@ class BaseNetwork:
                      label_masks),
                     compile_seconds=self._last_fit_time, scopes=True,
                     remat_kept_bytes=self._remat_kept,
-                    index_scores_calls=self._index_calls,
-                    hyper_connection_calls=self._hyper_calls,
+                    kernel_calls=self._kernel_calls,
                     build=compile_ledger.since(m0))
         self.iteration += 1
         self._epoch_batch += 1

@@ -132,7 +132,8 @@ class ComputationGraph(BaseNetwork):
         else:
             # bytes the blocks keep beside their inputs, by name, of the
             # step being traced: the program's registry record carries them
-            kept = self._remat_kept = dict.fromkeys(BLOCK_KEPT, 0)
+            kept_names = self.conf.global_conf.remat_keep or BLOCK_KEPT
+            kept = dict.fromkeys(BLOCK_KEPT, 0)
             for names, outs in remat_segments(self.conf):
                 if outs is None:
                     run(names, params, state, acts, new_state)
@@ -146,12 +147,15 @@ class ComputationGraph(BaseNetwork):
                     return {o: a[o] for o in outs}, ns
 
                 with counting_kept(kept):
-                    got, ns = block_checkpoint(block)(
+                    got, ns = block_checkpoint(block, kept_names)(
                         {n: params[n] for n in names if n in params},
                         {n: state[n] for n in names if n in state},
                         {i: acts[i] for i in needs})
                 acts.update(got)
                 new_state.update(ns)
+            # a name the policy leaves out is replayed: it keeps 0 bytes
+            self._remat_kept = {n: v if n in kept_names else 0
+                                for n, v in kept.items()}
         if cdt is not None:
             # persistent state (BN stats) keeps its storage dtype
             new_state = {

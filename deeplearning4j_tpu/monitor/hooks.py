@@ -106,8 +106,10 @@ class TrainMonitor:
         ``dl4jtpu_moe_*`` labelled by layer. ``layers``: key -> layer,
         ``state``: the container's state under the same keys; ``tokens``:
         the tokens a layer saw in the last step (0: not known, and the
-        rounds are not published). One host read of four scalars a layer;
-        a model without expert layers reads nothing."""
+        rounds are not published). A layer with a sequence-wise balance
+        loss also publishes its last step's term,
+        ``dl4jtpu_moe_balance_loss``. One host read of four or five scalars
+        a layer; a model without expert layers reads nothing."""
         keys = [k for k in layers if state[k] and "pairs_total" in state[k]]
         if not keys:
             return
@@ -138,9 +140,15 @@ class TrainMonitor:
                     "Rounds of the sorted-pair buffer that the last step's "
                     "routing needed: 1 where the first round held every "
                     "pair, above 1 where the step paid for later rounds.",
-                    lab)}
+                    lab),
+                "balance_loss": reg.gauge(
+                    "dl4jtpu_moe_balance_loss",
+                    "The layer's sequence-wise balance loss in the last "
+                    "step (alpha sum_i f_i P_i, the mean over the step's "
+                    "sequences), its term of the step's loss.", lab)}
             self._moe_seen = {}
-        got = jax.device_get({k: {n: state[k][n] for n in names}
+        got = jax.device_get({k: {n: state[k][n] for n in
+                                  names + ("balance_loss",) if n in state[k]}
                               for k in keys})
         for k in keys:
             lab = {"model": self._kind, "layer": str(layers[k].name or k)}
@@ -156,6 +164,9 @@ class TrainMonitor:
                 rows, _ = layers[k].round_rows(tokens)
                 self._moe["rounds_last"].labels(**lab).set(
                     max(1, -(-int(got[k]["pairs"]) // rows)))
+            if "balance_loss" in got[k]:
+                self._moe["balance_loss"].labels(**lab).set(
+                    float(got[k]["balance_loss"]))
 
     def publish_selection_counters(self, layers, state) -> None:
         """At the end of a streamed fit call: what the attention layers

@@ -68,6 +68,10 @@ class GlobalConf:
     # it, the values its layers name through util/remat.py:keep (the
     # decoder's: BLOCK_KEPT there); the rest is replayed
     remat: object = False   # False|True|'full'|'save_convs'|'selective'|'blocks'
+    # under 'blocks', the names of BLOCK_KEPT that are kept (None: all of
+    # them), as a model's builder chooses them (zoo/decoder.py:
+    # LATENT_PAIR_KEPT); a name left out is replayed: less memory, more work
+    remat_keep: Optional[tuple] = None
     weight_noise: Optional[object] = None  # IWeightNoise (DropConnect/...)
 
     def defaults_dict(self):
@@ -103,6 +107,8 @@ class GlobalConf:
         if isinstance(d.get("dropout"), dict):
             from deeplearning4j_tpu.nn.dropout import IDropout
             d["dropout"] = IDropout.from_dict(d["dropout"])
+        if d.get("remat_keep") is not None:
+            d["remat_keep"] = tuple(d["remat_keep"])
         return GlobalConf(**d)
 
 

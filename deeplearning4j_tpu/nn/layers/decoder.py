@@ -717,14 +717,15 @@ _top_k.defvjp(_top_k_fwd, _top_k_bwd)
 
 
 def route_top_k(x2, wr, k, norm_topk, routed_scale, score="softmax",
-                bias=None):
+                bias=None, with_scores=False):
     """Scores over all experts, the k chosen, their weights. x2: (N, C).
     ``score``: ``"softmax"`` over the experts, the k largest chosen and
     weighed by their scores; or ``"sigmoid"``, a score an expert, the k
     largest of score + ``bias`` chosen (a selection bias (E,), None: zero)
     and weighed by the score alone: the bias chooses and does not weigh,
     and no gradient reaches it. Returns (idx (N, k) int32, p (N, k)
-    float32). The router's product is kept across a block's replay
+    float32), and with ``with_scores`` the scores over all experts (N, E)
+    float32 after them. The router's product is kept across a block's replay
     (util/remat.py), named before the softmax or sigmoid because their
     derivatives read their own results, not a name put on them; the top k
     are kept by ``_top_k``, or, where nothing differentiates the choice
@@ -732,7 +733,8 @@ def route_top_k(x2, wr, k, norm_topk, routed_scale, score="softmax",
     logits = keep(jnp.dot(x2, wr, preferred_element_type=jnp.float32),
                   "routing")
     if score == "softmax":
-        val, idx = _top_k(jax.nn.softmax(logits, axis=-1), k)
+        s = jax.nn.softmax(logits, axis=-1)
+        val, idx = _top_k(s, k)
     else:
         s = jax.nn.sigmoid(logits)
         pick = s if bias is None else s + bias.astype(jnp.float32)
@@ -741,7 +743,26 @@ def route_top_k(x2, wr, k, norm_topk, routed_scale, score="softmax",
         val = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
         val = val / val.sum(axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), val * routed_scale
+    out = idx.astype(jnp.int32), val * routed_scale
+    return out + (s,) if with_scores else out
+
+
+def sequence_balance_loss(s, idx, batch, alpha):
+    """DeepSeek's sequence-wise balance loss (arXiv:2405.04434): for each
+    of ``batch`` sequences of T tokens, with E experts scored and k chosen,
+    P_i = mean_t s_{i,t} and f_i = E / (k T) #{t : i in top-k(t)}; the
+    loss is ``alpha`` sum_i f_i P_i, the mean over the sequences. ``s``:
+    the scores over all experts (batch*T, E) float32; ``idx`` (batch*T, k):
+    the experts chosen. f is a count and carries no gradient; P carries
+    one, into the router's logits."""
+    n, e = s.shape
+    k = idx.shape[-1]
+    t = n // batch
+    p = s.reshape(batch, t, e).mean(axis=1)
+    hits = (idx.reshape(batch, t * k)[..., None]
+            == jnp.arange(e, dtype=idx.dtype)).sum(axis=1, dtype=jnp.int32)
+    f = hits.astype(jnp.float32) * (e / (k * t))
+    return alpha * (f * p).sum(axis=-1).mean()
 
 
 # a derivative taken inside a derivative rule wraps the first scope it meets
@@ -906,7 +927,12 @@ class ExpertLayer(Layer):
     that chooses and does not weigh (``route_top_k``); ``latent_width``
     (0: none): the routed experts act inside a latent of that width, x
     projected down before the dispatch and the combined result up after it,
-    while the router and the shared expert read the full width.
+    while the router and the shared expert read the full width;
+    ``balance_alpha`` (0: none), DeepSeek's sequence-wise balance loss at
+    that weight (``sequence_balance_loss`` over a softmax router's scores
+    of all ``n_experts``, per sequence of the (B, T, C) input), handed back
+    in the state under ``loss_state`` for the container to add to the
+    step's loss.
 
     Param keys: Wr (n_in, n_experts); Eg, Eu (count, C, expert_width), Ed
     (count, expert_width, C) or, for ``relu2``, E1, E2 of those shapes,
@@ -917,7 +943,8 @@ class ExpertLayer(Layer):
     is the pairs routed here minus the pairs the rounds that ran counted as
     computed), ``pairs`` and ``load_max`` of the last step; with
     ``sigmoid`` also ``select_bias`` (n_experts,), which no step changes
-    and the updater never sees: it is state, not a parameter."""
+    and the updater never sees: it is state, not a parameter; with
+    ``balance_alpha`` also ``balance_loss``, the last step's term."""
     n_in: int = 0
     n_experts: int = 8
     experts_per_token: int = 2
@@ -929,6 +956,11 @@ class ExpertLayer(Layer):
     expert_form: str = "swiglu"
     score: str = "softmax"
     latent_width: int = 0
+    balance_alpha: float = 0.0
+
+    @property
+    def loss_state(self):
+        return "balance_loss" if self.balance_alpha else None
 
     def set_n_in(self, input_type):
         if self.n_in == 0:
@@ -958,6 +990,9 @@ class ExpertLayer(Layer):
             raise ValueError(f"expert_form={self.expert_form!r} (swiglu | "
                              f"relu2), score={self.score!r} (softmax | "
                              "sigmoid)")
+        if self.balance_alpha and self.score != "softmax":
+            raise ValueError("the sequence-wise balance loss reads a softmax "
+                             f"router's scores; score={self.score!r}")
         count, first = self.held
         if not (0 <= first and first + count <= self.n_experts and count > 0):
             raise ValueError(f"experts_held={self.experts_held} lies outside "
@@ -992,6 +1027,8 @@ class ExpertLayer(Layer):
                  ("pairs_total", "pairs_dropped_total", "pairs", "load_max")}
         if self.score == "sigmoid":
             state["select_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
+        if self.balance_alpha:
+            state["balance_loss"] = jnp.zeros((), jnp.float32)
         return state
 
     # -- the routed part ---------------------------------------------------
@@ -1004,20 +1041,31 @@ class ExpertLayer(Layer):
         rows = min(worst, -(-int(math.ceil(_ROUND_SLACK * even)) // 8) * 8)
         return rows, -(-worst // rows)
 
-    def routed(self, params, x2, first=None, bias=None):
+    def routed(self, params, x2, first=None, bias=None, batch=0):
         """Sum over the held experts of weight * expert(x) for the pairs
         routed to them. x2: (N, C). ``first``: index of the first expert
         held (a traced value under ``shard_map``; None: the layer's own).
         ``bias``: the selection bias of a ``sigmoid`` router (None: zero).
-        Returns (y (N, C) float32, counters dict)."""
+        ``batch``: how many sequences x2 holds, one after the other (0: the
+        balance loss is not taken). Returns (y (N, C) float32, counters
+        dict; with ``balance_alpha`` and ``batch`` its ``balance_loss``)."""
         n, c = x2.shape
         count = self.held[0]
         first = self.held[1] if first is None else first
         k = self.experts_per_token
         rows, rounds = self.round_rows(n)
+        balance = {}
         with jax.named_scope("route"):
-            idx, p = route_top_k(x2, params["Wr"], k, self.norm_topk,
-                                 self.routed_scale, self.score, bias)
+            if self.balance_alpha and batch:
+                idx, p, s = route_top_k(x2, params["Wr"], k, self.norm_topk,
+                                        self.routed_scale, self.score, bias,
+                                        with_scores=True)
+                with jax.named_scope("balance"):
+                    balance["balance_loss"] = sequence_balance_loss(
+                        s, idx, batch, self.balance_alpha)
+            else:
+                idx, p = route_top_k(x2, params["Wr"], k, self.norm_topk,
+                                     self.routed_scale, self.score, bias)
         if self.latent_width:
             with jax.named_scope("latent_down"):
                 x2 = x2 @ params["Wdown"]
@@ -1042,7 +1090,7 @@ class ExpertLayer(Layer):
         # ``done`` is counted by the rounds that ran, so a round left out
         # reads as pairs dropped
         return y, {"pairs": total, "pairs_dropped": total - done,
-                   "load_max": counts.max()}
+                   "load_max": counts.max(), **balance}
 
     def shared(self, params, x2):
         with jax.named_scope("shared"):
@@ -1056,7 +1104,8 @@ class ExpertLayer(Layer):
         b, t, c = x.shape
         x2 = x.reshape(b * t, c)
         bias = state.get("select_bias") if state else None
-        y, seen = self.routed(params, x2, bias=bias)
+        y, seen = self.routed(params, x2, bias=bias,
+                              batch=b if train and state else 0)
         if self.shared_width:
             y = y + self.shared(params, x2)
         if train and state:
@@ -1067,5 +1116,7 @@ class ExpertLayer(Layer):
                 "pairs": seen["pairs"], "load_max": seen["load_max"]}
             if bias is not None:
                 new["select_bias"] = bias
+            if "balance_loss" in seen:
+                new["balance_loss"] = seen["balance_loss"]
             state = new
         return y.astype(x.dtype).reshape(b, t, c), state

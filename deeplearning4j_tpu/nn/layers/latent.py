@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.ops import CallForms
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, require_dims
 from deeplearning4j_tpu.nn.layers.decoder import (
     _kernels_on, _seq_n_in, _w, apply_rotary, banded_attention, rms_norm)
@@ -49,20 +50,26 @@ from deeplearning4j_tpu.util.remat import keep
 @dataclass
 class LatentAttention(Layer):
     """Causal multi-head latent attention over (B, T, C). Queries through a
-    rank ``q_rank`` bottleneck, c_q = RMSNorm(x W_qa), q = c_q W_qb: per head
-    ``nope_dim`` dims without position and ``rope_dim`` rotated. Keys and
+    rank ``q_rank`` bottleneck, c_q = RMSNorm(x W_qa), q = c_q W_qb, or, with
+    ``q_rank`` 0 (a file's ``q_lora_rank: null``), straight from the input,
+    q = x W_q: per head ``nope_dim`` dims without position and ``rope_dim``
+    rotated. Keys and
     values through a rank ``kv_rank`` latent and one rotated key that every
     head shares: [c_kv | k_rope] = x W_kva, c_kv = RMSNorm(c_kv),
     [k_nope | v] = c_kv W_kvb per head. Scores q.k over nope_dim + rope_dim
     times ``rotary["score_scale"]`` / sqrt(nope_dim + rope_dim) (YaRN's
     mscale^2 in DeepSeek's form, 1 without); the values are ``v_dim`` wide.
-    ``n_heads`` is what this layer holds (a chip's share: W_qb, W_kvb and Wo
-    sliced by head; W_qa, W_kva and the norms whole). Rotary in halves, as
-    ``apply_rotary`` does, on the ``rope_dim`` dims alone.
+    ``n_heads`` is what this layer holds (a chip's share: W_qb or W_q, W_kvb
+    and Wo sliced by head; W_qa, W_kva and the norms whole). Rotary in
+    halves, as ``apply_rotary`` does, on the ``rope_dim`` dims alone. Each
+    call counts the form its attention took (``counting_calls``):
+    ``kernel``, the grouped-query flash kernels, or ``xla``,
+    ``banded_attention``.
 
     Param keys: Wqa (n_in, q_rank), q_gamma (q_rank,), Wqb (q_rank,
-    H*(nope+rope)), Wkva (n_in, kv_rank+rope), kv_gamma (kv_rank,), Wkvb
-    (kv_rank, H*(nope+v)), Wo (H*v, n_out)."""
+    H*(nope+rope)), or without the bottleneck Wq (n_in, H*(nope+rope));
+    Wkva (n_in, kv_rank+rope), kv_gamma (kv_rank,), Wkvb (kv_rank,
+    H*(nope+v)), Wo (H*v, n_out)."""
     n_in: int = 0
     n_out: int = 0
     n_heads: int = 0
@@ -91,14 +98,18 @@ class LatentAttention(Layer):
     def init(self, rng, dtype=jnp.float32):
         self.n_out = self.n_out or self.n_in
         require_dims(self, n_in=self.n_in, n_heads=self.n_heads,
-                     q_rank=self.q_rank, kv_rank=self.kv_rank,
-                     rope_dim=self.rope_dim, v_dim=self.v_dim)
+                     kv_rank=self.kv_rank, rope_dim=self.rope_dim,
+                     v_dim=self.v_dim)
         k = jax.random.split(rng, 5)
         h = self.n_heads
+        if self.q_rank:
+            q = {"Wqa": _w(self, k[0], (self.n_in, self.q_rank), dtype),
+                 "q_gamma": jnp.ones((self.q_rank,), dtype),
+                 "Wqb": _w(self, k[1], (self.q_rank, h * self.qk_dim), dtype)}
+        else:
+            q = {"Wq": _w(self, k[1], (self.n_in, h * self.qk_dim), dtype)}
         return {
-            "Wqa": _w(self, k[0], (self.n_in, self.q_rank), dtype),
-            "q_gamma": jnp.ones((self.q_rank,), dtype),
-            "Wqb": _w(self, k[1], (self.q_rank, h * self.qk_dim), dtype),
+            **q,
             "Wkva": _w(self, k[2], (self.n_in, self.kv_rank + self.rope_dim),
                        dtype),
             "kv_gamma": jnp.ones((self.kv_rank,), dtype),
@@ -116,25 +127,32 @@ class LatentAttention(Layer):
         scale = float((self.rotary or {}).get("score_scale") or 1.0) \
             / math.sqrt(self.qk_dim)
         t = q.shape[2]
-        if _kernels_on(t) and gqa_supported(t, self.qk_dim, self.n_heads,
-                                            self.n_heads, self.v_dim):
+        on = _kernels_on(t) and gqa_supported(t, self.qk_dim, self.n_heads,
+                                              self.n_heads, self.v_dim)
+        note_call("kernel" if on else "xla")
+        if on:
             return gqa_flash_attention(q, k, v, None, None,
                                        ops.interpret_mode(), scale)
         return banded_attention(q, k, v, scale=scale)
 
     def apply(self, params, x, state=None, *, train=False, rng=None, mask=None):
         if mask is not None:
-            raise ValueError("LatentAttention takes no padding mask: pack "
-                             "sequences to full length")
+            raise ValueError(
+                "LatentAttention takes no padding mask: pack whole sequences "
+                "to full length; documents packed into one sequence need a "
+                "segment mask, which the layer does not take yet (ROADMAP "
+                "R12)")
         b, t, _ = x.shape
         h, nope, rope = self.n_heads, self.nope_dim, self.rope_dim
         rot = self._rotary()
         with jax.named_scope("mla"):
             with jax.named_scope("latent_q"):
-                cq = rms_norm(x @ params["Wqa"], params["q_gamma"],
-                              self.norm_eps)
-                q = (cq @ params["Wqb"]).reshape(b, t, h, nope + rope) \
-                    .transpose(0, 2, 1, 3)
+                if "Wq" in params:
+                    q = x @ params["Wq"]
+                else:
+                    q = rms_norm(x @ params["Wqa"], params["q_gamma"],
+                                 self.norm_eps) @ params["Wqb"]
+                q = q.reshape(b, t, h, nope + rope).transpose(0, 2, 1, 3)
                 q = jnp.concatenate(
                     [q[..., :nope], apply_rotary(q[..., nope:], rot)], -1)
             with jax.named_scope("latent_kv"):
@@ -159,6 +177,13 @@ class LatentAttention(Layer):
         raise NotImplementedError(
             "LatentAttention trains through fit(); decoding it needs a paged "
             "cache of the latent and the shared rotary key (ROADMAP, Reach)")
+
+
+# ``note_call(form)`` counts one latent-attention call, by the form its
+# attention took, into the dict that ``counting_calls(into)`` holds open on
+# this thread
+_calls = CallForms()
+FORMS, note_call, counting_calls = _calls.FORMS, _calls.note, _calls.counting
 
 
 # --------------------------------------------------------- hyper-connections

@@ -95,12 +95,13 @@ def counting_kept(into):
         _counting.into = prev
 
 
-def block_checkpoint(block):
+def block_checkpoint(block, kept=None):
     """``block`` as one replay unit of 'blocks': its arguments and the
-    ``BLOCK_KEPT`` values inside it are kept for the backward pass."""
+    values inside it named ``kept`` (None: ``BLOCK_KEPT``) are kept for the
+    backward pass; the rest is replayed."""
     return jax.checkpoint(
-        block,
-        policy=jax.checkpoint_policies.save_only_these_names(*BLOCK_KEPT))
+        block, policy=jax.checkpoint_policies.save_only_these_names(
+            *(BLOCK_KEPT if kept is None else kept)))
 
 
 def block_of(name):

@@ -7,9 +7,10 @@ models). The graph is ``EmbeddingSequenceLayer`` in, the layers, a final
 layer is an attention + MLP pair, ``RMSNorm -> RotaryGQAttention -> add ->
 RMSNorm -> SwiGLU | ExpertLayer -> add``, or, in a hybrid stack, one mixer
 alone, ``RMSNorm -> Mamba2Mixer | ExpertLayer | RotaryGQAttention -> add``,
-or a latent-attention + MLP pair over hyper-connected residual streams
-(``SparseDecoder._latent_stack``). Nodes of layer i are named ``b<i>.<node>``, so that ``remat='blocks'``
-replays one layer at a time.
+or a latent-attention + MLP pair, over hyper-connected residual streams
+or on the plain residual path (``SparseDecoder._latent_stack``). Nodes of
+layer i are named ``b<i>.<node>``, so that ``remat='blocks'`` replays one
+layer at a time.
 """
 
 from __future__ import annotations
@@ -20,6 +21,16 @@ from deeplearning4j_tpu.nn.conf.configuration import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.updaters import Adam
 from deeplearning4j_tpu.zoo.zoo_model import ZooModel
+
+
+#: what ``remat='blocks'`` keeps of a latent-attention layer on the plain
+#: residual path (``util/remat.py:BLOCK_KEPT`` names): the attention's output
+#: and log-sum-exp, the routing and the routed experts' products. Its q, k,
+#: v and the SwiGLUs' gate and up products are made again in the replay:
+#: the family trains at long context, where they are the bulk of a layer
+#: (512 MB and 1.37 GB in the dense layer at 32,768 positions of
+#: DeepSeek-V2-Lite), for about 7 % more products a step
+LATENT_PAIR_KEPT = ("attn_out", "routing", "expert_gate_up")
 
 
 def _yarn_mscale(factor, mscale):
@@ -91,22 +102,26 @@ class SparseDecoder(ZooModel):
     ``n_shared_experts`` shared ones of
     ``moe_shared_expert_intermediate_size``; ``*`` attention without
     positional rotation), with ``layer_norm_epsilon``.
-    A fourth, latent attention over hyper-connected residual streams
-    (``kv_lora_rank`` present): ``num_hidden_layers`` layers of
-    ``LatentAttention`` (``q_lora_rank``, ``kv_lora_rank``,
+    A fourth, latent attention (``kv_lora_rank`` present):
+    ``num_hidden_layers`` layers of ``LatentAttention`` (``q_lora_rank``,
+    null for queries without a bottleneck, ``kv_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
     ``num_attention_heads``) and an MLP, ``SwiGLU`` of ``intermediate_size``
     for the first ``first_k_dense_replace`` and else an expert layer of
     ``n_routed_experts`` SwiGLU experts of ``moe_intermediate_size``
     (``scoring_func``, top ``num_experts_per_tok`` times
     ``routed_scaling_factor``, ``n_shared_experts`` shared ones of the same
-    width); each sublayer wrapped in a hyper-connection over ``hc_mult``
-    residual streams (``hc_sinkhorn_iters``, ``hc_eps``,
-    ``mhc_h_res_clamp_min`` / ``_max``); with ``num_nextn_predict_layers``
-    1, one multi-token-prediction module (an expert layer of the same kind
-    over the trunk's last state joined to the next token's embedding)
-    whose cross entropy on the token after next enters the loss at
-    ``mtp_loss_weight`` (absent: 0.3) through the one head both use.
+    width; ``topk_method`` ``greedy`` or ``noaux_tc`` with ``n_group`` 1;
+    with ``seq_aux`` the sequence-wise balance loss at ``aux_loss_alpha``).
+    With ``hc_mult``, each sublayer is wrapped in a hyper-connection over
+    that many residual streams (``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min`` / ``_max``), and with
+    ``num_nextn_predict_layers`` 1 one multi-token-prediction module (an
+    expert layer of the same kind over the trunk's last state joined to
+    the next token's embedding) follows, whose cross entropy on the token
+    after next enters the loss at ``mtp_loss_weight`` (absent: 0.3)
+    through the one head both use. Without ``hc_mult`` a layer is the
+    pre-norm pair on the plain residual path.
     ``experts_held=(count, first)`` gives the expert layers a share of the
     experts (None: all): ``first`` picks the router's columns. A share of
     the heads of attention or of the Mamba mixers (whole groups) is the
@@ -137,20 +152,26 @@ class SparseDecoder(ZooModel):
              .set_input_types(InputType.recurrent(vocab)))
         g.add_layer("embed", EmbeddingSequenceLayer(
             n_in=vocab, n_out=hidden, activation="identity"), "tokens")
-        if "kv_lora_rank" in c:
+        latent = "kv_lora_rank" in c
+        if latent and c.get("hc_mult"):
             return self._latent_stack(g, eps).set_outputs("head").build()
-        layers = self._hybrid_layers if hybrid else self._paired_layers
+        layers = (self._hybrid_layers if hybrid else self._latent_pairs
+                  if latent else self._paired_layers)
         g.add_layer("final_norm", RMSNorm(eps=eps), layers(g, "embed", eps))
         g.add_layer("head", RnnOutputLayer(
             n_out=vocab, activation="softmax", loss="mcxent",
             has_bias=False), "final_norm")
-        return g.set_outputs("head").build()
+        conf = g.set_outputs("head").build()
+        if latent:
+            conf.global_conf.remat_keep = LATENT_PAIR_KEPT
+        return conf
 
     def _latent_stack(self, g, eps):
-        """The trunk of hyper-connected latent-attention layers, ``final_norm``
-        and ``head``, and the multi-token-prediction module (nodes
-        ``mtp.*``, one replay unit) where the file has one. The residual
-        state is ``hc_mult`` streams from ``hc_expand`` to ``hc_collapse``."""
+        """The trunk of latent-attention layers over ``hc_mult``
+        hyper-connected residual streams (``hc_expand`` to
+        ``hc_collapse``), ``final_norm`` and ``head``, and the
+        multi-token-prediction module (nodes ``mtp.*``, one replay unit)
+        where the file has one."""
         from deeplearning4j_tpu.nn.conf.graph_conf import (
             MergeVertex, NextTokenJoin, StreamCollapse, StreamExpand)
         from deeplearning4j_tpu.nn.layers import (
@@ -158,8 +179,9 @@ class SparseDecoder(ZooModel):
         c = self.config
         n, depth = c["hc_mult"], c.get("num_nextn_predict_layers", 0)
         if depth not in (0, 1):
-            raise ValueError(f"num_nextn_predict_layers={depth}: one module "
-                             "or none")
+            raise ValueError(
+                f"num_nextn_predict_layers={depth!r} in the file: one "
+                "multi-token-prediction module is built, or none")
         g.add_vertex("hc_expand", StreamExpand(streams=n), "embed")
         prev = "hc_expand"
         for i in range(c["num_hidden_layers"]):
@@ -185,33 +207,67 @@ class SparseDecoder(ZooModel):
             "head_in")
         return g
 
+    def _latent_pairs(self, g, prev, eps):
+        """Latent-attention layers on the plain residual path, each the
+        pre-norm pair of ``_pair``; returns the last node."""
+        c = self.config
+        depth = c.get("num_nextn_predict_layers", 0)
+        if depth:
+            raise ValueError(
+                f"num_nextn_predict_layers={depth!r} in the file without "
+                "hc_mult: the multi-token-prediction module is built over "
+                "hyper-connected residual streams only")
+        for i in range(c["num_hidden_layers"]):
+            prev = self._pair(g, f"b{i}", prev, eps, *self._latent_mixers(
+                eps, dense=i < c["first_k_dense_replace"]))
+        return prev
+
+    def _latent_mixers(self, eps, dense):
+        """(the ``LatentAttention``, the MLP) of one latent-attention layer:
+        ``SwiGLU`` where ``dense``, else the expert layer. Refuses a routing
+        the expert layer does not implement rather than ignore its keys."""
+        from deeplearning4j_tpu.nn.layers import (
+            ExpertLayer, LatentAttention, SwiGLU)
+        c = self.config
+        attn = LatentAttention(
+            n_heads=c["num_attention_heads"], q_rank=c["q_lora_rank"] or 0,
+            kv_rank=c["kv_lora_rank"], nope_dim=c["qk_nope_head_dim"],
+            rope_dim=c["qk_rope_head_dim"], v_dim=c["v_head_dim"],
+            rotary=rotary_settings(c, "full_attention"), norm_eps=eps)
+        if dense:
+            return attn, SwiGLU(width=c["intermediate_size"])
+        method = c.get("topk_method", "greedy")
+        if method not in ("greedy", "noaux_tc"):
+            raise ValueError(
+                f"topk_method={method!r}: the expert layer chooses its top "
+                "k over all experts (greedy, or noaux_tc with a selection "
+                "bias)")
+        if (c.get("n_group") or 1) > 1 or (c.get("topk_group") or 1) > 1:
+            raise ValueError(
+                f"n_group={c.get('n_group')!r}, topk_group="
+                f"{c.get('topk_group')!r}: device-limited routing (the top k "
+                "inside the best groups of experts) is not built")
+        return attn, ExpertLayer(
+            n_experts=c["n_routed_experts"],
+            experts_per_token=c["num_experts_per_tok"],
+            expert_width=c["moe_intermediate_size"],
+            shared_width=(c["moe_intermediate_size"]
+                          * c.get("n_shared_experts", 0)),
+            routed_scale=c.get("routed_scaling_factor", 1.0),
+            norm_topk=c.get("norm_topk_prob", True),
+            experts_held=self.experts_held,
+            score=c.get("scoring_func", "softmax"),
+            balance_alpha=c["aux_loss_alpha"] if c.get("seq_aux") else 0.0)
+
     def _latent_layer(self, g, b, prev, eps, dense):
         """``b.attn`` then ``b.mlp``, each as ``b.hc<j> -> b.in<j> ->
         b.norm<j> -> mixer -> b.out<j>`` on the residual state ``prev``;
         returns the last node."""
         from deeplearning4j_tpu.nn.conf.graph_conf import HyperIn, HyperOut
-        from deeplearning4j_tpu.nn.layers import (
-            ExpertLayer, HyperConnection, LatentAttention, RMSNorm, SwiGLU)
+        from deeplearning4j_tpu.nn.layers import HyperConnection, RMSNorm
         c = self.config
         n = c["hc_mult"]
-        attn = LatentAttention(
-            n_heads=c["num_attention_heads"], q_rank=c["q_lora_rank"],
-            kv_rank=c["kv_lora_rank"], nope_dim=c["qk_nope_head_dim"],
-            rope_dim=c["qk_rope_head_dim"], v_dim=c["v_head_dim"],
-            rotary=rotary_settings(c, "full_attention"), norm_eps=eps)
-        if dense:
-            mlp = SwiGLU(width=c["intermediate_size"])
-        else:
-            mlp = ExpertLayer(
-                n_experts=c["n_routed_experts"],
-                experts_per_token=c["num_experts_per_tok"],
-                expert_width=c["moe_intermediate_size"],
-                shared_width=(c["moe_intermediate_size"]
-                              * c.get("n_shared_experts", 0)),
-                routed_scale=c.get("routed_scaling_factor", 1.0),
-                norm_topk=c.get("norm_topk_prob", True),
-                experts_held=self.experts_held,
-                score=c.get("scoring_func", "softmax"))
+        attn, mlp = self._latent_mixers(eps, dense)
         for j, (name, mixer) in enumerate((("attn", attn), ("mlp", mlp)), 1):
             g.add_layer(f"{b}.hc{j}", HyperConnection(
                 streams=n, sinkhorn_iters=c["hc_sinkhorn_iters"],
@@ -274,9 +330,8 @@ class SparseDecoder(ZooModel):
 
     def _paired_layers(self, g, prev, eps):
         """An attention + MLP pair a layer; returns the last node."""
-        from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
         from deeplearning4j_tpu.nn.layers import (
-            RMSNorm, SwiGLU, RotaryGQAttention, ExpertLayer)
+            SwiGLU, RotaryGQAttention, ExpertLayer)
         c = self.config
         kinds = c.get("layer_types") \
             or ["full_attention"] * c["num_hidden_layers"]
@@ -293,19 +348,13 @@ class SparseDecoder(ZooModel):
                                 "head_dim": sa["indexer_head_dim"],
                                 "top_k": sa["topk"]}
         for i, kind in enumerate(kinds):
-            b = f"b{i}"
-            g.add_layer(f"{b}.norm1", RMSNorm(eps=eps), prev)
-            g.add_layer(f"{b}.attn", RotaryGQAttention(
+            attn = RotaryGQAttention(
                 n_heads=heads[i],
                 n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
                 window=(c["sliding_window"] if kind == "sliding_attention"
                         else None),
                 rotary=rotary_settings(c, kind),
-                head_gate=c.get("gating") == "per-head", **extra),
-                f"{b}.norm1")
-            g.add_vertex(f"{b}.add1", ElementWiseVertex(op="add"),
-                         f"{b}.attn", prev)
-            g.add_layer(f"{b}.norm2", RMSNorm(eps=eps), f"{b}.add1")
+                head_gate=c.get("gating") == "per-head", **extra)
             if mlps[i] == "dense":
                 mlp = SwiGLU(width=c["intermediate_size"])
             else:
@@ -317,8 +366,20 @@ class SparseDecoder(ZooModel):
                     routed_scale=c.get("moe_routed_scaling_factor", 1.0),
                     norm_topk=c.get("norm_topk_prob", True),
                     experts_held=self.experts_held)
-            g.add_layer(f"{b}.mlp", mlp, f"{b}.norm2")
-            g.add_vertex(f"{b}.add2", ElementWiseVertex(op="add"),
-                         f"{b}.mlp", f"{b}.add1")
-            prev = f"{b}.add2"
+            prev = self._pair(g, f"b{i}", prev, eps, attn, mlp)
         return prev
+
+    @staticmethod
+    def _pair(g, b, prev, eps, attn, mlp):
+        """``b.norm1 -> b.attn -> b.add1 -> b.norm2 -> b.mlp -> b.add2``:
+        the pre-norm pair on the plain residual path from ``prev``; returns
+        ``b.add2``."""
+        from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
+        from deeplearning4j_tpu.nn.layers import RMSNorm
+        for j, (name, mixer, add_to) in enumerate(
+                (("attn", attn, prev), ("mlp", mlp, f"{b}.add1")), 1):
+            g.add_layer(f"{b}.norm{j}", RMSNorm(eps=eps), add_to)
+            g.add_layer(f"{b}.{name}", mixer, f"{b}.norm{j}")
+            g.add_vertex(f"{b}.add{j}", ElementWiseVertex(op="add"),
+                         f"{b}.{name}", add_to)
+        return f"{b}.add2"

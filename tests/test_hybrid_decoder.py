@@ -443,9 +443,17 @@ def test_bfloat16_compute_stays_near_float32(cfg):
 # sha256 of the step program's lowered text (no debug info) at the
 # rehearsal size, as it stood before the expert layer gained its second
 # form, the sigmoid router and the latent, and the decoder builder its
-# hybrid family (taken at commit 72fcf92). A change that means to alter
-# these steps takes new readings of the cells and puts its own hashes here.
+# hybrid family (taken at commit 72fcf92); the latent-attention cell's as
+# it stood before its attention lost the need of a query bottleneck and its
+# stack the need of hyper-connections (taken at commit a51e5e2); the
+# long-context latent-attention cell's as its limits were read. A change
+# that means to alter these steps takes new readings of the cells and puts
+# its own hashes here.
 LOWERED = {
+    ("xing4.0-29b-a4b.fit-s4k-b1", None):
+        "3119be7f71ee73930c44a617a05d6f1533b1c5ba08562e67ac9dd53b5755ccc0",
+    ("xing4.0-29b-a4b.fit-s4k-b1", "bfloat16"):
+        "189f25de619c324a6d70d623a1018f906cb6f9e88281d95173642feb1ee38624",
     ("laguna-s-2.1.fit-s8k-b2", None):
         "59d8d5734fb116b00d82f7f245130a2534008d7c28d7b229be367e2673a2a8c6",
     ("laguna-s-2.1.fit-s8k-b2", "bfloat16"):
@@ -454,6 +462,10 @@ LOWERED = {
         "7d62f44e98bd4e3fb0ab5cf4e02dbdd62b5922ad97501b95a16f479864efa65e",
     ("keye-vl-2.0-30b-a3b.fit-s16k-b1", "bfloat16"):
         "e846da69950ebad0e1d47c5e0b1f6e2026ce6c5b73c95b6ac36fe71e1a34a87c",
+    ("deepseek-v2-lite.fit-s32k-b1", None):
+        "135483cabbcb47a301e944e29977260f0763903404f540caef6fb9d743dbbcf7",
+    ("deepseek-v2-lite.fit-s32k-b1", "bfloat16"):
+        "efd7690c4c541624d64ddff3dda3e473f5951e30442a33e45e676dbb5e5c1a9e",
 }
 
 

@@ -149,7 +149,8 @@ def test_the_rule_routes_each_pass(case, monkeypatch):
 STEP_PASSES = {"xing4.0-29b-a4b.fit-s4k-b1": 36,
                "laguna-s-2.1.fit-s8k-b2": 0,
                "keye-vl-2.0-30b-a3b.fit-s16k-b1": 0,
-               "nemotron-3-super-120b-a12b.fit-s8k-b1": 0}
+               "nemotron-3-super-120b-a12b.fit-s8k-b1": 0,
+               "deepseek-v2-lite.fit-s32k-b1": 0}
 
 
 @pytest.mark.parametrize("cell", sorted(STEP_PASSES))
@@ -170,4 +171,5 @@ def test_the_forms_a_decoder_step_takes(cell):
                 jnp.asarray(0, jnp.int32), None, None)
     finally:
         set_default_mesh(None)
-    assert net._hyper_calls == {"kernel": STEP_PASSES[cell], "xla": 0}
+    assert net._kernel_calls["hyper_connection_calls"] == {
+        "kernel": STEP_PASSES[cell], "xla": 0}

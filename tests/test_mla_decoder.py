@@ -213,7 +213,8 @@ def test_a_latent_block_is_two_hyper_connected_sublayers(cfg, trained):
     assert attn.rotary["score_scale"] == pytest.approx(
         (0.1 * math.log(64) + 1) ** 2)
     assert attn.rotary["attention_factor"] == 1.0
-    with pytest.raises(ValueError, match="one module or none"):
+    with pytest.raises(ValueError,
+                       match="num_nextn_predict_layers=2 in the file"):
         SparseDecoder(dict(cfg, **cfg["rehearsal"]["model"],
                            num_nextn_predict_layers=2)).conf()
 

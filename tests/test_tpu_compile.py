@@ -198,17 +198,19 @@ def test_gqa_flash_attention_fwd_and_grad(one_chip, b, hq, hkv, window):
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
-# the latent-attention cell: one sequence of 4096, the 8 heads held, q.k over
-# 128 + 64 dims and values of 128, YaRN's mscale^2 in the scores' scale
-def test_gqa_flash_attention_with_value_dim_of_its_own(one_chip):
+# the latent-attention cells, q.k over 128 + 64 dims and values of 128,
+# YaRN's mscale^2 in the scores' scale: one sequence of 4096 with the 8 heads
+# held (Xing) and one of 32,768 with all 16 (DeepSeek-V2-Lite)
+@pytest.mark.parametrize("h,t,scale", [(8, 4096, 0.1447), (16, 32768, 0.1147)])
+def test_gqa_flash_attention_with_value_dim_of_its_own(one_chip, h, t, scale):
     def loss(q, k, v):
         return flash_attention.gqa_flash_attention(
-            q, k, v, None, None, False, 0.1447).astype(jnp.float32).sum()
+            q, k, v, None, None, False, scale).astype(jnp.float32).sum()
 
-    shapes = _shapes(one_chip, ((1, 8, 4096, 192), jnp.bfloat16),
-                     ((1, 8, 4096, 192), jnp.bfloat16),
-                     ((1, 8, 4096, 128), jnp.bfloat16))
-    _agree(flash_attention.gqa_supported(4096, 192, 8, 8, 128),
+    shapes = _shapes(one_chip, ((1, h, t, 192), jnp.bfloat16),
+                     ((1, h, t, 192), jnp.bfloat16),
+                     ((1, h, t, 128), jnp.bfloat16))
+    _agree(flash_attention.gqa_supported(t, 192, h, h, 128),
            jax.value_and_grad(loss, argnums=(0, 1, 2)), shapes, kernels=3)
 
 
